@@ -1,447 +1,182 @@
 """Pallas-TPU backward kernel for multi-scale deformable attention.
 
-Paper mapping (xMSDA §4.2 → TPU):
+Paper mapping (xMSDA §4.2 -> TPU):
 
-* Phase 1 (grad w.r.t. sampling locations + attention weights) is pure
-  element-wise vector math over the bilinear corners.  In train mode the
-  corners were **saved by the forward kernel** (paper §4.1) so phase 1
-  issues no gathers at all; otherwise it re-gathers (fused, like fwd).
+* Phase 1 (grad w.r.t. the bilinear corner weights, hence sampling
+  locations and attention weights) is vector math over the corners: per
+  corner and head, ``<gout, corner>``.  In train mode the corners were
+  **saved by the forward kernel** (paper §4.1), so phase 1 issues no
+  gathers; it runs as one MXU segmented reduction per query block.  The
+  chain rule from the weights to locations and attention weights is
+  JAX's own autodiff of the element-wise weight computation.
 * Phase 2 (grad w.r.t. value) is the scatter-add hotspot.  The paper
   staggers vector-core phases to reduce GM write contention; on TPU the
-  Pallas grid is *sequential per TensorCore*, so we instead keep the
-  whole level's ``grad_value`` slab **resident in VMEM** and scatter-add
-  into it across query blocks — contention-free by construction, with a
-  single UB→GM (VMEM→HBM) writeback when the (batch, head) block
-  retires.  Cross-core/chip parallelism gets per-shard partial slabs
-  reduced by ``psum`` at the distribution layer (see
-  ``core/msda.py``) — the TPU-idiomatic equivalent of staggered writes.
-* **Scatter fusion**: all four corners × P points of a query block are
-  scattered with ONE batched ``.at[idx].add`` (duplicate indices
-  accumulate); the ablation flag ``fuse_scatter=False`` issues four
-  per-corner scatters (the paper's "-Scatter Fusion" column).
+  Pallas grid is *sequential per TensorCore*, so the whole slab's
+  ``grad_value`` stays **resident in VMEM** and every corner contribution
+  is a scalar-addressed read-modify-write row update — sequential, so
+  duplicate corners accumulate exactly, with a single VMEM->HBM
+  writeback when the (batch, head group) block retires.  Cross-chip
+  parallelism reduces per-shard partial slabs at the distribution layer
+  (:func:`ring_allreduce`).
+* The corner rows and weights arrive in SMEM exactly as for the forward
+  (``msda_fwd``), heads on lanes.
+* Inference plans keep no saved corners: their backward **regathers**
+  the corners from the slab in the same launch.
 
-Outputs per level: grad_value slab (``accum_dtype``, fp32 by default,
-padded layout), grad_loc, grad_attn.  Grid ``(B, H, num_q_blocks)`` with
-the grad slab revisited (accumulated in VMEM) across the innermost ``q``
-dimension.
-
-Mixed precision: when the plan commits a bf16 value slab, the *inputs*
-(slab / saved corners) arrive narrow but the resident grad slab is a
-genuine **widened accumulator** — allocated and scatter-added in
-``accum_dtype`` inside the kernel, not a bf16 slab cast afterwards —
-so Q-many scatter contributions never round through bf16.
-
-**Fused whole-pyramid variant** (``msda_bwd_fused``): under the
-planner's fusion rung the whole pyramid's grad slab is the residency
-unit — one ``pallas_call`` streams ``gout`` once, scatter-adds every
-level into a single packed grad super-slab (disjoint row ranges per
-level, so the merged scatter is contention-free), and writes it to HBM
-exactly once, instead of re-streaming ``gout`` and re-launching per
-level.
+The grad slab is an fp32 accumulator whatever the slab dtype, so Q-many
+contributions never round through bf16.  ``fuse_scatter=False`` (the
+paper's "-Scatter Fusion" ablation) walks the corners corner-major.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import msda_fwd
-from repro.kernels.msda_fwd import _CompilerParams, corner_indices
+from repro.kernels.msda_fwd import (
+    LevelGeom, compiler_params, corner_offset, corner_walk, fetch_row,
+    idx_slot, keep_corner, lane_heads, resident, saved_slot, static_loop,
+    table_specs, w_slot)
 
-Shapes = Tuple[Tuple[int, int], ...]
 
+def _scatter_kernel(idx_ref, w_ref, gout_ref, src_ref, gval_ref, gw_ref,
+                    corner_scratch, *, levels: Tuple[LevelGeom, ...], P: int,
+                    G: int, D: int, qb: int, fuse_scatter: bool,
+                    regather: bool, unroll: bool):
+    """One (batch, head group, query block) step of the backward.
 
-def _bwd_kernel(
-    value_ref,  # (1, 1, HWp, D) VMEM-resident level slab (None if saved)
-    loc_ref,    # (1, 1, Qb, P, 2)
-    attn_ref,   # (1, 1, Qb, P)
-    gout_ref,   # (1, 1, Qb, D)
-    saved_ref,  # (1, 1, Qb, 4P, D) corners saved by fwd (None if regather)
-    gval_ref,   # out: (1, 1, HWp, D) fp32, accumulated across q blocks
-    gloc_ref,   # out: (1, 1, Qb, P, 2)
-    gattn_ref,  # out: (1, 1, Qb, P)
-    *,
-    H: int,
-    W: int,
-    Wp: int,
-    fuse_scatter: bool,
-    onehot_scatter: bool = False,
-):
-    q_idx = pl.program_id(2)
+    Phase 2: scatter-add weight x gout into the resident grad slab, one
+    read-modify-write row update per corner.  Phase 1: the grad of every
+    corner weight, ``<gout, corner>`` over each head's lanes, as one MXU
+    segmented reduction per saved slot over the whole block.  The
+    corners (``corner_scratch``, fp32, each query's L*4P rows) come from
+    the forward's saved block ``src_ref`` or, when ``regather``, from the
+    slab ``src_ref`` during the scatter walk.
+    """
+    L = len(levels)
+    K = L * 4 * P
+    heads = lane_heads(G, D)
 
-    loc = loc_ref[0, 0].astype(jnp.float32)  # (Qb, P, 2)
-    attn = attn_ref[0, 0].astype(jnp.float32)  # (Qb, P)
-    gout = gout_ref[0, 0].astype(jnp.float32)  # (Qb, D)
-    Qb, P, _ = loc.shape
-    D = gout.shape[-1]
-
-    idx00, lx, ly, (m00, m10, m01, m11) = corner_indices(loc, H, W, Wp)
-    i00 = idx00.reshape(-1)  # (Qb*P,)
-
-    # ---- corners: saved by fwd (no gather) or re-gathered (fused) --------
-    if saved_ref is not None:
-        corners = saved_ref[0, 0].astype(jnp.float32)  # (Qb, 4P, D)
-        v00, v10, v01, v11 = jnp.split(corners, 4, axis=1)
-    else:
-        all_idx = jnp.concatenate([i00, i00 + 1, i00 + Wp, i00 + Wp + 1])
-        g = jnp.take(value_ref[0, 0], all_idx, axis=0).astype(jnp.float32)
-        v00, v10, v01, v11 = (x.reshape(Qb, P, D) for x in jnp.split(g, 4, axis=0))
-    v00 = v00.reshape(Qb, P, D) * m00[..., None]
-    v10 = v10.reshape(Qb, P, D) * m10[..., None]
-    v01 = v01.reshape(Qb, P, D) * m01[..., None]
-    v11 = v11.reshape(Qb, P, D) * m11[..., None]
-
-    w00 = ((1 - lx) * (1 - ly))[..., None]  # (Qb,P,1)
-    w10 = (lx * (1 - ly))[..., None]
-    w01 = ((1 - lx) * ly)[..., None]
-    w11 = (lx * ly)[..., None]
-
-    # ---- phase 1: vector-only grads (paper: element-wise vector ops) -----
-    sampled = v00 * w00 + v10 * w10 + v01 * w01 + v11 * w11  # (Qb,P,D)
-    gattn_ref[0, 0] = jnp.einsum("qd,qpd->qp", gout, sampled).astype(gattn_ref.dtype)
-
-    g_s = attn[..., None] * gout[:, None, :]  # (Qb,P,D): dL/d(sampled)
-    # d sampled / d px = (v10 - v00)(1-ly) + (v11 - v01) ly   (masked corners
-    # are zeroed, matching grid_sample zero-padding gradients)
-    dpx = ((v10 - v00) * (1 - ly)[..., None] + (v11 - v01) * ly[..., None])
-    dpy = ((v01 - v00) * (1 - lx)[..., None] + (v11 - v10) * lx[..., None])
-    glx = jnp.einsum("qpd,qpd->qp", g_s, dpx) * W
-    gly = jnp.einsum("qpd,qpd->qp", g_s, dpy) * H
-    gloc_ref[0, 0] = jnp.stack([glx, gly], axis=-1).astype(gloc_ref.dtype)
-
-    # ---- phase 2: scatter-add grad_value into the resident slab ----------
-    @pl.when(q_idx == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        gval_ref[0, 0] = jnp.zeros_like(gval_ref[0, 0])
+        gval_ref[...] = jnp.zeros_like(gval_ref)
 
-    c00 = (g_s * w00 * m00[..., None]).reshape(-1, D)
-    c10 = (g_s * w10 * m10[..., None]).reshape(-1, D)
-    c01 = (g_s * w01 * m01[..., None]).reshape(-1, D)
-    c11 = (g_s * w11 * m11[..., None]).reshape(-1, D)
-    slab = gval_ref[0, 0]
-    if onehot_scatter:
-        # Beyond-paper MXU path: scatter-add as a transposed one-hot
-        # matmul (HWp, 4QbP) @ (4QbP, D) — contention-free by algebra
-        # (duplicate indices sum inside the dot), no serialized scatter.
-        all_idx = jnp.concatenate([i00, i00 + 1, i00 + Wp, i00 + Wp + 1])
-        contrib = jnp.concatenate([c00, c10, c01, c11], axis=0)
-        onehot = (jnp.arange(slab.shape[0])[:, None] == all_idx[None, :]).astype(
-            jnp.float32
-        )
-        gval_ref[0, 0] = slab + (onehot @ contrib).astype(slab.dtype)
-    elif fuse_scatter:
-        all_idx = jnp.concatenate([i00, i00 + 1, i00 + Wp, i00 + Wp + 1])
-        contrib = jnp.concatenate([c00, c10, c01, c11], axis=0)
-        gval_ref[0, 0] = slab.at[all_idx].add(contrib.astype(slab.dtype))
-    else:
-        # ablation: four separate per-corner scatters
-        slab = slab.at[i00].add(c00.astype(slab.dtype))
-        slab = slab.at[i00 + 1].add(c10.astype(slab.dtype))
-        slab = slab.at[i00 + Wp].add(c01.astype(slab.dtype))
-        slab = slab.at[i00 + Wp + 1].add(c11.astype(slab.dtype))
-        gval_ref[0, 0] = slab
+    if not regather:
+        corner_scratch[...] = src_ref[...].astype(jnp.float32)
+
+    def body(q, carry):
+        g = gout_ref[pl.ds(q, 1), :]  # (1, G*D) fp32
+        for l, geom in enumerate(levels):
+            off, wp, nrows, onehot = geom
+
+            def corner(p, c, carry, l=l, geom=geom):
+                def head(h, carry):
+                    mine = heads == h
+                    i = (idx_ref[idx_slot(h, q, l, p, qb, L, P)]
+                         + corner_offset(c, wp))
+                    w = w_ref[w_slot(h, q, l, c, p, qb, L, P)]
+                    contrib = jnp.where(mine, w * g, 0.0)
+                    if onehot:
+                        # MXU route: the update as a one-hot outer product
+                        hot = (jax.lax.broadcasted_iota(
+                            jnp.int32, (nrows, 1), 0) == i - off).astype(
+                                jnp.float32)
+                        gval_ref[off:off + nrows, :] += hot * contrib
+                    else:
+                        gval_ref[pl.ds(i, 1), :] += contrib
+                    if regather:
+                        keep_corner(corner_scratch,
+                                    q * K + saved_slot(l, c, p, P), mine,
+                                    fetch_row(src_ref, geom, i))
+                    return carry
+
+                return static_loop(G, head, carry, unroll)
+
+            corner_walk(P, fuse_scatter, unroll, corner, 0)
+        return carry
+
+    jax.lax.fori_loop(0, qb, body, 0)
+
+    seg = (lane_heads(G, D) == jax.lax.broadcasted_iota(
+        jnp.int32, (G, G * D), 0)).astype(jnp.float32)  # (G, G*D)
+    g = gout_ref[...]  # (qb, G*D)
+    for k in range(K):
+        # slot k of every query in the block: rows k, k + K, ...
+        prods = corner_scratch[pl.ds(k, qb, stride=K), :] * g
+        # (G, qb): row h sums head h's lanes — the weight table's
+        # (head, slot, query) chunk order (w_slot)
+        gw_ref[:, k, :] = jax.lax.dot_general(
+            seg, prods, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
 
-def msda_bwd_level(
-    value_l: Optional[jax.Array],  # (B, H, HWp, D) or None when saved given
-    loc_l: jax.Array,              # (B, H, Q, P, 2)
-    attn_l: jax.Array,             # (B, H, Q, P)
-    gout: jax.Array,               # (B, H, Q, D)
-    saved_l: Optional[jax.Array],  # (B, H, Q, 4P, D) or None
+def msda_scatter(
+    gout: jax.Array,                # (B, NG, Qp, G*D) fp32
+    idx: jax.Array,                 # flat int32 table (msda_fwd)
+    w: jax.Array,                   # flat fp32 table
+    src: jax.Array,                 # saved (B,NG,Qp*K,G*D) or slab
     *,
-    hw: Tuple[int, int],
-    hwp_rows: int,
+    regather: bool,
+    rows: int,
+    levels: Tuple[LevelGeom, ...],
+    num_points: int,
+    head_dim: int,
     block_q: int,
     fuse_scatter: bool = True,
-    onehot_scatter: bool = False,
-    interpret: bool = False,
-    accum_dtype=jnp.float32,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-level backward.
+    interpret: bool,
+    vmem_limit: int = 0,
+) -> Tuple[jax.Array, jax.Array]:
+    """Backward over one slab: ``(grad_slab, grad_w)``.
 
-    Returns (grad_value_slab in ``accum_dtype``, grad_loc, grad_attn).
+    ``grad_slab`` is (B, NG, rows, G*D) fp32; ``grad_w`` the grads of
+    the weight table ``w``, (B, NG, nq, G, L*4P, block_q) fp32 — the
+    table's chunk layout.  ``src`` holds the corners: the forward's
+    saved block, or (``regather``) the fp32 slab they are re-read from.
     """
-    B, Hh, Q, P, _ = loc_l.shape
-    D = gout.shape[-1]
-    Hl, Wl = hw
-    Wp = Wl + 2
-    assert Q % block_q == 0, (Q, block_q)
-    nq = Q // block_q
-
+    B, NG, qp, GD = gout.shape
+    L, P, D = len(levels), num_points, head_dim
+    G = GD // D
+    assert qp % block_q == 0, (qp, block_q)
+    nq = qp // block_q
+    K = L * 4 * P
     kernel = functools.partial(
-        _bwd_kernel, H=Hl, W=Wl, Wp=Wp, fuse_scatter=fuse_scatter,
-        onehot_scatter=onehot_scatter,
-    )
-
-    in_specs = []
-    operands = []
-    if saved_l is None:
-        assert value_l is not None
-        in_specs.append(pl.BlockSpec((1, 1, hwp_rows, D), lambda b, h, q: (b, h, 0, 0)))
-        operands.append(value_l)
-        kernel_fn = functools.partial(_regather_wrap, kernel)
+        _scatter_kernel, levels=tuple(levels), P=P, G=G, D=D, qb=block_q,
+        fuse_scatter=fuse_scatter, regather=regather, unroll=not interpret)
+    if regather:
+        src_spec = resident((None, None, src.shape[2], GD),
+                            lambda b, g, q: (b, g, 0, 0))
     else:
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_q, 4 * P, D), lambda b, h, q: (b, h, q, 0, 0))
-        )
-        operands.append(saved_l)
-        kernel_fn = functools.partial(_saved_wrap, kernel)
-    in_specs += [
-        pl.BlockSpec((1, 1, block_q, P, 2), lambda b, h, q: (b, h, q, 0, 0)),
-        pl.BlockSpec((1, 1, block_q, P), lambda b, h, q: (b, h, q, 0)),
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, q: (b, h, q, 0)),
-    ]
-    operands += [loc_l, attn_l, gout]
-
-    gval, gloc, gattn = pl.pallas_call(
-        kernel_fn,
-        grid=(B, Hh, nq),
-        in_specs=in_specs,
+        src_spec = pl.BlockSpec((None, None, block_q * K, GD),
+                                lambda b, g, q: (b, g, q, 0))
+    gval, gw = pl.pallas_call(
+        kernel,
+        grid=(B, NG, nq),
+        in_specs=table_specs(block_q, G, L, P, NG, nq) + [
+            pl.BlockSpec((None, None, block_q, GD),
+                         lambda b, g, q: (b, g, q, 0)),
+            src_spec,
+        ],
         out_specs=[
-            # grad slab: revisited/accumulated across q, written back once
-            pl.BlockSpec((1, 1, hwp_rows, D), lambda b, h, q: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, P, 2), lambda b, h, q: (b, h, q, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, P), lambda b, h, q: (b, h, q, 0)),
+            # grad slab: same block for every q -> accumulated in VMEM,
+            # written back once per (batch, head group)
+            resident((None, None, rows, GD), lambda b, g, q: (b, g, 0, 0)),
+            pl.BlockSpec((None, None, None, G, K, block_q),
+                         lambda b, g, q: (b, g, q, 0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hh, hwp_rows, D), jnp.dtype(accum_dtype)),
-            jax.ShapeDtypeStruct((B, Hh, Q, P, 2), loc_l.dtype),
-            jax.ShapeDtypeStruct((B, Hh, Q, P), attn_l.dtype),
+            jax.ShapeDtypeStruct((B, NG, rows, GD), jnp.float32),
+            jax.ShapeDtypeStruct((B, NG, nq, G, K, block_q), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        scratch_shapes=[pltpu.VMEM((block_q * K, GD), jnp.float32)],
+        compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
-    )(*operands)
-    return gval, gloc, gattn
-
-
-# --------------------------------------------------------------------------
-# fused whole-pyramid backward: ONE pallas launch for all L levels
-# --------------------------------------------------------------------------
-
-
-def _bwd_fused_kernel(
-    value_ref,  # (1, 1, R, D) packed super-slab (None when saved given)
-    loc_ref,    # (1, 1, Qb, L, P, 2)
-    attn_ref,   # (1, 1, Qb, L, P)
-    gout_ref,   # (1, 1, Qb, D)
-    saved_ref,  # (1, 1, Qb, L*4P, D) packed corners (None if regather)
-    gval_ref,   # out: (1, 1, R, D) accum dtype, accumulated across q
-    gloc_ref,   # out: (1, 1, Qb, L, P, 2)
-    gattn_ref,  # out: (1, 1, Qb, L, P)
-    *,
-    hws: Shapes,
-    row_offsets: Tuple[int, ...],
-    fuse_scatter: bool,
-    onehot_levels: Tuple[bool, ...] = (),
-    slab_dtypes: Tuple[str, ...] = (),
-    gather_offsets: Tuple[int, ...] = (),
-):
-    """Whole-pyramid backward step.
-
-    Phase 1 (grad loc/attn) is the per-level vector math looped over the
-    packed levels; phase 2 scatter-adds EVERY level's corner
-    contribution into the one resident grad super-slab — for the VPU
-    levels via a single merged ``.at[idx].add`` whose indices are lifted
-    by the static per-level row offsets (levels occupy disjoint row
-    ranges, so the merge is contention-free by construction), for
-    one-hot levels via the MXU matmul against their own sub-slab rows.
-    ``gout`` is streamed ONCE for the whole pyramid instead of once per
-    level, and the grad super-slab goes to HBM exactly once.
-
-    Mixed-dtype super-slabs (``slab_dtypes``) only change the regather
-    side: the value slab is carrier-coded so its row offsets
-    (``gather_offsets``) differ from the grad super-slab's — the grad
-    slab is ALWAYS a uniform accum-dtype array at the plain
-    ``row_offsets`` layout, so phase 2 is untouched.
-    """
-    q_idx = pl.program_id(2)
-
-    loc = loc_ref[0, 0].astype(jnp.float32)  # (Qb, L, P, 2)
-    attn = attn_ref[0, 0].astype(jnp.float32)  # (Qb, L, P)
-    gout = gout_ref[0, 0].astype(jnp.float32)  # (Qb, D)
-    Qb, L, P, _ = loc.shape
-    D = gout.shape[-1]
-
-    cidx, geom = msda_fwd.fused_level_corner_indices(loc, hws)
-    onehot = tuple(onehot_levels) if onehot_levels else (False,) * L
-
-    def _corner_idx(l):
-        return cidx[l]
-
-    # ---- corners: saved by fwd (packed, no gather) or re-gathered --------
-    if saved_ref is not None:
-        packed = saved_ref[0, 0].astype(jnp.float32)  # (Qb, L*4P, D)
-        corners = [
-            [c.reshape(Qb * P, D)
-             for c in jnp.split(packed[:, l * 4 * P:(l + 1) * 4 * P], 4, axis=1)]
-            for l in range(L)
-        ]
-    else:
-        # same routing as the forward: shared helper, directions can't drift
-        corners = msda_fwd.fused_gather_corners(
-            value_ref[0, 0], cidx,
-            tuple(gather_offsets) or row_offsets, onehot,
-            fuse_gather=True, slab_dtypes=slab_dtypes)
-
-    # ---- phase 1 per level + collect phase-2 scatter contributions -------
-    glocs, gattns = [], []
-    contribs = [None] * L  # per level: (c00, c10, c01, c11) each (Qb*P, D)
-    for l, (Hl, Wl) in enumerate(hws):
-        lx, ly, (m00, m10, m01, m11) = geom[l]
-        v00, v10, v01, v11 = (c.reshape(Qb, P, D) for c in corners[l])
-        v00 = v00 * m00[..., None]
-        v10 = v10 * m10[..., None]
-        v01 = v01 * m01[..., None]
-        v11 = v11 * m11[..., None]
-        w00 = ((1 - lx) * (1 - ly))[..., None]
-        w10 = (lx * (1 - ly))[..., None]
-        w01 = ((1 - lx) * ly)[..., None]
-        w11 = (lx * ly)[..., None]
-
-        sampled = v00 * w00 + v10 * w10 + v01 * w01 + v11 * w11
-        gattns.append(jnp.einsum("qd,qpd->qp", gout, sampled))
-
-        g_s = attn[:, l][..., None] * gout[:, None, :]  # (Qb,P,D)
-        dpx = ((v10 - v00) * (1 - ly)[..., None] + (v11 - v01) * ly[..., None])
-        dpy = ((v01 - v00) * (1 - lx)[..., None] + (v11 - v10) * lx[..., None])
-        glx = jnp.einsum("qpd,qpd->qp", g_s, dpx) * Wl
-        gly = jnp.einsum("qpd,qpd->qp", g_s, dpy) * Hl
-        glocs.append(jnp.stack([glx, gly], axis=-1))
-
-        contribs[l] = (
-            (g_s * w00 * m00[..., None]).reshape(-1, D),
-            (g_s * w10 * m10[..., None]).reshape(-1, D),
-            (g_s * w01 * m01[..., None]).reshape(-1, D),
-            (g_s * w11 * m11[..., None]).reshape(-1, D),
-        )
-    gattn_ref[0, 0] = jnp.stack(gattns, axis=1).astype(gattn_ref.dtype)
-    gloc_ref[0, 0] = jnp.stack(glocs, axis=1).astype(gloc_ref.dtype)
-
-    # ---- phase 2: scatter-add into the ONE resident grad super-slab ------
-    @pl.when(q_idx == 0)
-    def _init():
-        gval_ref[0, 0] = jnp.zeros_like(gval_ref[0, 0])
-
-    slab = gval_ref[0, 0]
-    vpu = [l for l in range(L) if not onehot[l]]
-    if vpu:
-        if fuse_scatter:
-            # one merged scatter across corners, points AND levels
-            big = jnp.concatenate(
-                [c + row_offsets[l] for l in vpu for c in _corner_idx(l)])
-            upd = jnp.concatenate([c for l in vpu for c in contribs[l]], axis=0)
-            slab = slab.at[big].add(upd.astype(slab.dtype))
-        else:
-            # ablation: four merged per-corner scatters
-            for c in range(4):
-                big = jnp.concatenate(
-                    [_corner_idx(l)[c] + row_offsets[l] for l in vpu])
-                upd = jnp.concatenate([contribs[l][c] for l in vpu], axis=0)
-                slab = slab.at[big].add(upd.astype(slab.dtype))
-    for l in range(L):
-        if not onehot[l]:
-            continue
-        end = row_offsets[l + 1] if l + 1 < L else slab.shape[0]
-        rows = end - row_offsets[l]
-        all_idx = jnp.concatenate(_corner_idx(l))
-        contrib = jnp.concatenate(contribs[l], axis=0)
-        oh = (jnp.arange(rows)[:, None] == all_idx[None, :]).astype(jnp.float32)
-        slab = slab.at[row_offsets[l]:end].add((oh @ contrib).astype(slab.dtype))
-    gval_ref[0, 0] = slab
-
-
-def msda_bwd_fused(
-    value_p: Optional[jax.Array],  # (B, H, R, D) or None when saved given
-    loc_f: jax.Array,              # (B, H, Q, L, P, 2)
-    attn_f: jax.Array,             # (B, H, Q, L, P)
-    gout: jax.Array,               # (B, H, Q, D)
-    saved_p: Optional[jax.Array],  # (B, H, Q, L*4P, D) or None
-    *,
-    hws: Shapes,
-    row_offsets: Tuple[int, ...],
-    total_rows: int,
-    block_q: int,
-    fuse_scatter: bool = True,
-    onehot_levels: Tuple[bool, ...] = (),
-    interpret: bool = False,
-    accum_dtype=jnp.float32,
-    slab_dtypes: Tuple[str, ...] = (),
-    gather_offsets: Tuple[int, ...] = (),
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Whole-pyramid backward: ONE ``pallas_call`` for all levels.
-
-    Returns ``(grad_value super-slab in accum_dtype, grad_loc,
-    grad_attn)`` — the grad slab covers every level (packed layout,
-    written back to HBM exactly once when the (batch, head) block
-    retires); grad_loc/grad_attn come back ``(B, H, Q, L, P, ...)``.
-    ``row_offsets`` / ``total_rows`` describe the (uniform accum-dtype)
-    grad super-slab; a mixed-dtype value slab passes its own carrier
-    layout via ``slab_dtypes`` + ``gather_offsets`` for the regather.
-    """
-    B, Hh, Q, L, P, _ = loc_f.shape
-    D = gout.shape[-1]
-    assert Q % block_q == 0, (Q, block_q)
-    nq = Q // block_q
-
-    kernel = functools.partial(
-        _bwd_fused_kernel, hws=tuple(hws), row_offsets=tuple(row_offsets),
-        fuse_scatter=fuse_scatter, onehot_levels=tuple(onehot_levels),
-        slab_dtypes=tuple(slab_dtypes), gather_offsets=tuple(gather_offsets),
-    )
-
-    in_specs = []
-    operands = []
-    if saved_p is None:
-        assert value_p is not None
-        # the value slab's own row extent, NOT total_rows: a mixed-dtype
-        # carrier slab holds MORE rows than the plain grad layout
-        in_specs.append(
-            pl.BlockSpec((1, 1, value_p.shape[2], D),
-                         lambda b, h, q: (b, h, 0, 0)))
-        operands.append(value_p)
-        kernel_fn = functools.partial(_regather_wrap, kernel)
-    else:
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_q, L * 4 * P, D),
-                         lambda b, h, q: (b, h, q, 0, 0)))
-        operands.append(saved_p)
-        kernel_fn = functools.partial(_saved_wrap, kernel)
-    in_specs += [
-        pl.BlockSpec((1, 1, block_q, L, P, 2),
-                     lambda b, h, q: (b, h, q, 0, 0, 0)),
-        pl.BlockSpec((1, 1, block_q, L, P), lambda b, h, q: (b, h, q, 0, 0)),
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, q: (b, h, q, 0)),
-    ]
-    operands += [loc_f, attn_f, gout]
-
-    gval, gloc, gattn = pl.pallas_call(
-        kernel_fn,
-        grid=(B, Hh, nq),
-        in_specs=in_specs,
-        out_specs=[
-            # grad super-slab: accumulated across q, written back once
-            pl.BlockSpec((1, 1, total_rows, D), lambda b, h, q: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, L, P, 2),
-                         lambda b, h, q: (b, h, q, 0, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, L, P), lambda b, h, q: (b, h, q, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hh, total_rows, D), jnp.dtype(accum_dtype)),
-            jax.ShapeDtypeStruct((B, Hh, Q, L, P, 2), loc_f.dtype),
-            jax.ShapeDtypeStruct((B, Hh, Q, L, P), attn_f.dtype),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(*operands)
-    return gval, gloc, gattn
+    )(idx, w, gout, src)
+    return gval, gw
 
 
 # --------------------------------------------------------------------------
@@ -510,11 +245,3 @@ def ring_allreduce(x: jax.Array, axis_name: str, axis_size: int,
     if pad:
         out = out[:rows]
     return jnp.moveaxis(out, 0, axis)
-
-
-def _regather_wrap(kernel, value_ref, loc_ref, attn_ref, gout_ref, gval_ref, gloc_ref, gattn_ref):
-    kernel(value_ref, loc_ref, attn_ref, gout_ref, None, gval_ref, gloc_ref, gattn_ref)
-
-
-def _saved_wrap(kernel, saved_ref, loc_ref, attn_ref, gout_ref, gval_ref, gloc_ref, gattn_ref):
-    kernel(None, loc_ref, attn_ref, gout_ref, saved_ref, gval_ref, gloc_ref, gattn_ref)

@@ -1,48 +1,45 @@
 """Pallas-TPU forward kernel for multi-scale deformable attention.
 
-Paper mapping (xMSDA §4.1 → TPU):
+Paper mapping (xMSDA §4.1 -> TPU):
 
-* Per-level processing with the level's padded feature map **resident in
-  VMEM** across all query blocks (the paper's "single-channel feature
-  map fits UB" insight; TPU VMEM holds the whole per-(batch, head) level
-  slab, all channels).
-* **Gather fusion**: all four bilinear corners × P points of a query
-  block are gathered with ONE batched index vector — the TPU analogue of
-  the paper's pixel-pair merged gather (x-adjacent corners are adjacent
-  rows ``idx`` / ``idx+1`` of the row-major ``(HW, D)`` slab and ride the
-  same gather op, maximising effective vector length, the quantity the
-  paper's Fig. 4 shows drives gather throughput).  The ablation flag
-  ``fuse_gather=False`` issues four separate per-corner gathers instead.
+* The level's (or the whole pyramid's) padded feature map is **resident
+  in VMEM** across all query blocks of one (batch, head group): the
+  paper's "feature map fits UB" insight.
+* **Scalar-addressed row gather.**  Mosaic has no general vector gather,
+  so — like the paper's scalar-addressed pixel copies out of the on-chip
+  buffer — the top-left corner row of every (query, head, level, point)
+  and its four bilinear weights arrive in SMEM, computed outside the
+  kernel by cheap element-wise XLA (:func:`corner_indices`).  The kernel
+  walks its query block and issues one dynamic row load per corner;
+  the x-pair partner sits at ``idx + 1`` and the y-pair partner at
+  ``idx + Wp`` of the row-major slab, so the corner walk is branch-free.
+* **Heads on lanes.**  The slab is ``(rows, G*D)``: G heads of one group
+  side by side on the lane axis (G*D = 128 for the DETR head_dim 32), so
+  VMEM and HBM hold no lane padding.  A row load fetches all G heads of a
+  pixel; each head keeps its own lanes through a lane mask.
 * **Padding-based alignment fix**: each level is zero-padded to
-  ``(H+1, W+1)`` so ``x0+1`` / ``y0+1`` never leave the slab and the
-  merged pair load is always legal (paper Fig. 6, re-motivated: TPU has
-  no unaligned-gather erratum, but the same padding makes the corner
-  arithmetic branch-free).  Out-of-bounds corners are masked on the
-  *weights*, reproducing ``grid_sample(padding_mode='zeros')``.
-* **Adaptive vec-len**: the query-block size ``block_q`` is planned per
-  level so (slab + gathered corners + temporaries) fill the VMEM budget
-  (paper Fig. 7). See ``ops.plan_blocks``.
-* **Train mode** (``save_sampled``): the kernel additionally streams the
-  gathered corner values to HBM for the backward pass (paper §4.1 "store
-  the gather result ... additional IO"), trading fwd MTE3 traffic for a
-  gather-free backward phase 1.
-* **Mixed precision**: the value slab may be stored in a narrower dtype
-  (bf16 — half the VMEM residency, so the planner can widen ``block_q``)
-  while the kernel still computes and *emits* its per-level partial
-  output in ``out_dtype`` (fp32 by default) — a widened accumulator, not
-  a cast wrapper: cross-level accumulation never rounds through bf16.
+  ``(H+2, W+2)`` so the corner pair never leaves the slab; out-of-bounds
+  corners carry zero weight (``grid_sample(padding_mode='zeros')``).
+* **Train mode** (``save_sampled``): the kernel also streams the raw
+  gathered corners to HBM, so the backward's phase 1 (grad of the
+  bilinear weights) issues no gathers.
+* **Fused whole pyramid**: one launch gathers every level from one
+  packed super-slab (the corner rows are lifted by static per-level row
+  offsets outside the kernel), accumulating the per-level partials in
+  the same order the per-level launches sum theirs — so every fusion
+  tier is bitwise identical.
 
-Grid: ``(B, H, num_q_blocks)`` — ``q`` innermost so the value slab block
-``(1, 1, HW_pad, D)`` is revisited (stays in VMEM) across query blocks.
+The slab is stored in fp32 whatever the committed slab dtype: the values
+are first rounded to that dtype (so a bf16 plan computes on bf16 data),
+and Mosaic cannot address single rows of a packed 16-bit tile.
 
-**Fused whole-pyramid variant** (``msda_fwd_fused``): when the packed
-slabs of ALL levels fit the VMEM budget (the planner's fusion rung,
-``MsdaSpec.fuse_levels``), the pyramid — not the level — becomes the
-residency unit: one ``pallas_call`` gathers every level from a single
-row-major super-slab (per-level row offsets static), accumulates the
-cross-level sum in-kernel, and writes the output to HBM exactly once.
-The merged gather then spans corners x points x LEVELS — another factor
-of L of effective vector length on top of the pixel-pair merge.
+Grid: ``(B, head_groups, num_q_blocks)`` with ``q`` innermost, so the
+slab block (index independent of ``q``) stays resident.
+
+Ablation variants (they compile for v5e too; ``chip_smoke.py`` checks
+each against the oracle there): ``onehot`` levels fetch each row as a
+one-hot MXU matmul; ``fuse_gather=False`` walks the corners corner-major
+(four per-corner passes) instead of point-major.
 """
 from __future__ import annotations
 
@@ -54,14 +51,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.6); support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# lane width of one vreg: the head group fills it when head_dim allows
+LANES = 128
 
-Shapes = Tuple[Tuple[int, int], ...]
+# (row offset in the slab, padded width Wp, slab rows, one-hot?) per level
+LevelGeom = Tuple[int, int, int, bool]
 
 
 def corner_indices(loc, H: int, W: int, Wp: int):
-    """Bilinear corner bookkeeping shared by fwd/bwd kernels.
+    """Bilinear corner bookkeeping shared by every backend.
 
     loc: (..., 2) fp32 in [0,1] (x, y), grid_sample(align_corners=False).
     Returns (idx00, lx, ly, masks) where ``idx00`` indexes the padded
@@ -93,431 +91,243 @@ def corner_indices(loc, H: int, W: int, Wp: int):
     return idx00, lx, ly, masks
 
 
-def _fwd_kernel(
-    value_ref,  # (1, 1, HWp, D)   VMEM-resident level slab
-    loc_ref,    # (1, 1, Qb, P, 2)
-    attn_ref,   # (1, 1, Qb, P)
-    out_ref,    # (1, 1, Qb, D)
-    saved_ref,  # (1, 1, Qb, P*4, D) or None
-    *,
-    H: int,
-    W: int,
-    Wp: int,
-    fuse_gather: bool,
-    onehot_gather: bool = False,
-):
-    v = value_ref[0, 0]  # (HWp, D)
-    loc = loc_ref[0, 0].astype(jnp.float32)  # (Qb, P, 2)
-    attn = attn_ref[0, 0].astype(jnp.float32)  # (Qb, P)
-    Qb, P, _ = loc.shape
-
-    idx00, lx, ly, (m00, m10, m01, m11) = corner_indices(loc, H, W, Wp)
-    i00 = idx00.reshape(-1)  # (Qb*P,)
-
-    if onehot_gather:
-        # Beyond-paper MXU path (small levels): gather as a one-hot
-        # matmul (4QbP, HWp) @ (HWp, D) — the systolic array does the
-        # "random access".  The Ascend design could not express this
-        # (cube cores cannot address UB); on TPU the MXU sits idle during
-        # VPU gathers, so shifting small-level sampling there overlaps
-        # with the big-level vector path.
-        all_idx = jnp.concatenate([i00, i00 + 1, i00 + Wp, i00 + Wp + 1])
-        onehot = (all_idx[:, None] == jnp.arange(v.shape[0])[None, :]).astype(
-            jnp.float32
-        )
-        g = onehot @ v.astype(jnp.float32)  # (4*Qb*P, D) via MXU
-        v00, v10, v01, v11 = jnp.split(g, 4, axis=0)
-    elif fuse_gather:
-        # ONE batched gather for all corners & points: [x0y0; x1y0; x0y1; x1y1]
-        all_idx = jnp.concatenate([i00, i00 + 1, i00 + Wp, i00 + Wp + 1])
-        g = jnp.take(v, all_idx, axis=0).astype(jnp.float32)  # (4*Qb*P, D)
-        v00, v10, v01, v11 = jnp.split(g, 4, axis=0)
-    else:
-        # ablation: four separate per-corner gathers (halved vec-len twice)
-        v00 = jnp.take(v, i00, axis=0).astype(jnp.float32)
-        v10 = jnp.take(v, i00 + 1, axis=0).astype(jnp.float32)
-        v01 = jnp.take(v, i00 + Wp, axis=0).astype(jnp.float32)
-        v11 = jnp.take(v, i00 + Wp + 1, axis=0).astype(jnp.float32)
-
-    shape = (Qb, P, 1)
-    w00 = ((1 - lx) * (1 - ly) * m00).reshape(shape)
-    w10 = (lx * (1 - ly) * m10).reshape(shape)
-    w01 = ((1 - lx) * ly * m01).reshape(shape)
-    w11 = (lx * ly * m11).reshape(shape)
-
-    D = v.shape[-1]
-    v00 = v00.reshape(Qb, P, D)
-    v10 = v10.reshape(Qb, P, D)
-    v01 = v01.reshape(Qb, P, D)
-    v11 = v11.reshape(Qb, P, D)
-    sampled = v00 * w00 + v10 * w10 + v01 * w01 + v11 * w11  # (Qb,P,D)
-    out = jnp.einsum("qpd,qp->qd", sampled, attn)
-    out_ref[0, 0] = out.astype(out_ref.dtype)
-
-    if saved_ref is not None:
-        # train mode: stream raw corners to HBM for the backward pass
-        corners = jnp.concatenate([v00, v10, v01, v11], axis=1)  # (Qb, 4P, D)
-        saved_ref[0, 0] = corners.astype(saved_ref.dtype)
+def head_group(num_heads: int, head_dim: int) -> int:
+    """Heads per launch: the largest divisor G of ``num_heads`` with
+    ``G * head_dim <= LANES`` (at least 1) — the head group whose slab
+    rows fill one vreg row."""
+    g = 1
+    for c in range(1, num_heads + 1):
+        if num_heads % c == 0 and c * head_dim <= LANES:
+            g = c
+    return g
 
 
-def msda_fwd_level(
-    value_l: jax.Array,  # (B, H, HWp, D) zero-padded level slab
-    loc_l: jax.Array,    # (B, H, Q, P, 2)
-    attn_l: jax.Array,   # (B, H, Q, P)
-    *,
-    hw: Tuple[int, int],
-    block_q: int,
-    fuse_gather: bool = True,
-    save_sampled: bool = False,
-    onehot_gather: bool = False,
-    interpret: bool = False,
-    out_dtype=None,
-) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """One level's contribution: (B,H,Q,D) partial output (+ saved corners).
-
-    ``out_dtype`` is the accumulator dtype the partial output is emitted
-    in (default: the slab dtype).  Saved corners always keep the slab
-    dtype — they are re-read, not accumulated.
+def static_loop(n: int, body, init, unroll: bool):
+    """``fori_loop`` over ``range(n)``, or a Python loop over static ints
+    when ``unroll``.  Mosaic gets the unrolled form (static offsets, one
+    straight-line block to schedule); the interpreter gets the rolled
+    one, which compiles far faster.  Same operations in the same order.
     """
-    B, Hh, HWp, D = value_l.shape
-    out_dtype = value_l.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    _, _, Q, P, _ = loc_l.shape
-    Hl, Wl = hw
-    Wp = Wl + 2  # leading + trailing pad column
-    assert Q % block_q == 0, (Q, block_q)
-    nq = Q // block_q
-
-    kernel = functools.partial(
-        _fwd_kernel, H=Hl, W=Wl, Wp=Wp, fuse_gather=fuse_gather,
-        onehot_gather=onehot_gather,
-    )
-    out_shapes = [jax.ShapeDtypeStruct((B, Hh, Q, D), out_dtype)]
-    out_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, q: (b, h, q, 0))]
-    if save_sampled:
-        out_shapes.append(jax.ShapeDtypeStruct((B, Hh, Q, 4 * P, D), value_l.dtype))
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q, 4 * P, D), lambda b, h, q: (b, h, q, 0, 0))
-        )
-    else:
-        kernel = functools.partial(_nosave_wrap, kernel)
-
-    outs = pl.pallas_call(
-        kernel,
-        grid=(B, Hh, nq),
-        in_specs=[
-            # level slab: revisited across q (resident in VMEM per (b,h))
-            pl.BlockSpec((1, 1, HWp, D), lambda b, h, q: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, P, 2), lambda b, h, q: (b, h, q, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, P), lambda b, h, q: (b, h, q, 0)),
-        ],
-        out_specs=out_specs if save_sampled else out_specs[:1],
-        out_shape=out_shapes if save_sampled else out_shapes[:1],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(value_l, loc_l, attn_l)
-    if save_sampled:
-        return outs[0], outs[1]
-    return outs[0], None
+    if unroll:
+        carry = init
+        for i in range(n):
+            carry = body(i, carry)
+        return carry
+    return jax.lax.fori_loop(0, n, body, init)
 
 
-def _nosave_wrap(kernel, value_ref, loc_ref, attn_ref, out_ref):
-    kernel(value_ref, loc_ref, attn_ref, out_ref, None)
+def corner_walk(P: int, fuse: bool, unroll: bool, body, init):
+    """Visit every (point, corner) of one head and level: point-major for
+    the fused walk, corner-major (four per-corner passes) for the
+    ablation.  ``body(p, c, carry) -> carry``."""
+    if fuse:
+        return static_loop(P, lambda p, a: static_loop(
+            4, lambda c, b: body(p, c, b), a, unroll), init, unroll)
+    return static_loop(4, lambda c, a: static_loop(
+        P, lambda p, b: body(p, c, b), a, unroll), init, unroll)
 
 
-# --------------------------------------------------------------------------
-# fused whole-pyramid forward: ONE pallas launch for all L levels
-# --------------------------------------------------------------------------
+def corner_offset(c, wp: int):
+    """Row offset of corner ``c`` (x0y0, x1y0, x0y1, x1y1) from the
+    top-left corner row."""
+    return (c & 1) + (c >> 1) * wp
 
 
-def fused_level_corner_indices(loc, hws: Shapes):
-    """Per-level corner bookkeeping for the fused kernels.
+def saved_slot(level, corner, point, num_points: int):
+    """Row of corner ``(level, corner, point)`` in a query's saved block."""
+    return (level * 4 + corner) * num_points + point
 
-    ``loc``: (Qb, L, P, 2).  Returns ``(cidx, geom)`` where ``cidx[l]``
-    is the tuple of 4 LOCAL corner index vectors ``(Qb*P,)`` (x-pair
-    partner ``+1``, y-pair partner ``+Wp`` — see :func:`corner_indices`)
-    and ``geom[l] = (lx, ly, masks)``.
+
+def idx_slot(h, q, l, p, qb: int, L: int, P: int):
+    """Entry of (head, query, level, point) in a block's corner-row table
+    chunk, laid out (head, level, point, query): the query is minor, so
+    every XLA-side step that builds the tables transposes large dims."""
+    return ((h * L + l) * P + p) * qb + q
+
+
+def w_slot(h, q, l, c, p, qb: int, L: int, P: int):
+    """Entry of a corner weight in a block's weight table chunk, laid out
+    (head, saved slot, query) — also the layout the backward's weight
+    grads come out in."""
+    return (h * (L * 4 * P) + saved_slot(l, c, p, P)) * qb + q
+
+
+def row_block(q, k: int):
+    """Rows ``[q*k, q*k + k)``: query ``q``'s block of ``k`` saved-corner
+    rows, with the alignment Mosaic needs to prove for a packed store."""
+    return pl.ds(pl.multiple_of(q * k, k & -k), k)
+
+
+def fetch_row(slab_ref, geom: LevelGeom, i):
+    """One (1, lanes) fp32 slab row at dynamic row ``i``."""
+    off, _, rows, onehot = geom
+    if onehot:
+        # MXU route: the row as a one-hot matmul against the level rows
+        hot = (jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+               == i - off).astype(jnp.float32)
+        return jnp.dot(hot, slab_ref[off:off + rows, :],
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    return slab_ref[pl.ds(i, 1), :]
+
+
+def lane_heads(group: int, head_dim: int):
+    """(1, G*D) int32: the head of the group each lane belongs to."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, (1, group * head_dim), 1) // head_dim
+
+
+def keep_corner(row_scratch, k, mine, row):
+    """Write the lanes ``mine`` of ``row`` into saved-corner row ``k``."""
+    row_scratch[pl.ds(k, 1), :] = jnp.where(
+        mine, row, row_scratch[pl.ds(k, 1), :])
+
+
+def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
+                   *, levels: Tuple[LevelGeom, ...], P: int, G: int, D: int,
+                   qb: int, fuse_gather: bool, unroll: bool):
+    """One (batch, head group, query block) step of the forward.
+
+    ``idx_ref`` / ``w_ref``: this block's chunk of the SMEM tables (see
+    :func:`idx_slot` / :func:`w_slot`), corner rows already lifted into
+    this launch's slab, weights with the validity mask and the attention
+    weight folded in.
     """
-    cidx, geom = [], []
-    for l, (Hl, Wl) in enumerate(hws):
-        Wp = Wl + 2
-        idx00, lx, ly, masks = corner_indices(loc[:, l], Hl, Wl, Wp)
-        i00 = idx00.reshape(-1)
-        cidx.append((i00, i00 + 1, i00 + Wp, i00 + Wp + 1))
-        geom.append((lx, ly, masks))
-    return cidx, geom
+    L = len(levels)
+    heads = lane_heads(G, D)
+    zero = jnp.zeros((1, G * D), jnp.float32)
 
+    def body(q, carry):
+        total = zero
+        for l, geom in enumerate(levels):
+            def head(h, part, l=l, geom=geom):
+                mine = heads == h
 
-def packed_ratios(slab_dtypes: Tuple[str, ...], carrier_dtype) -> Tuple[int, ...]:
-    """Per-level carrier-rows-per-slab-row of a mixed-dtype super-slab.
+                def corner(p, c, acc):
+                    i = idx_ref[idx_slot(h, q, l, p, qb, L, P)]
+                    row = fetch_row(slab_ref, geom,
+                                    i + corner_offset(c, geom[1]))
+                    if saved_ref is not None:
+                        keep_corner(row_scratch, saved_slot(l, c, p, P),
+                                    mine, row)
+                    return acc + w_ref[w_slot(h, q, l, c, p, qb, L, P)] * row
 
-    The packed super-slab is stored in the NARROWEST committed dtype
-    (the carrier); a level committed to a wider dtype occupies
-    ``itemsize(level) // itemsize(carrier)`` carrier rows per logical
-    row (its bytes reinterpreted row-major), so row offsets stay
-    sublane-aligned while every level keeps its own dtype — bf16-winner
-    levels keep their residency win under fusion.
-    """
-    ci = jnp.dtype(carrier_dtype).itemsize
-    return tuple(jnp.dtype(d).itemsize // ci for d in slab_dtypes)
+                acc = corner_walk(P, fuse_gather, unroll, corner, zero)
+                return jnp.where(mine, acc, part)
 
-
-def decode_packed_rows(seg: jax.Array, ratio: int, dtype) -> jax.Array:
-    """(n*ratio, D) carrier rows -> (n, D) rows in the level's dtype.
-
-    Inverse of the row-major byte reinterpretation ``ops._pack_pyramid``
-    applies when packing a wide level into a narrow carrier: ``ratio``
-    consecutive carrier rows hold one logical row, consecutive carrier
-    elements pairing into one wide element.
-    """
-    dt = jnp.dtype(dtype)
-    if dt == seg.dtype:
-        return seg
-    if ratio == 1:  # same itemsize, different dtype (e.g. bf16 vs f16)
-        return jax.lax.bitcast_convert_type(seg, dt)
-    n = seg.shape[0] // ratio
-    d = seg.shape[1]
-    return jax.lax.bitcast_convert_type(
-        seg.reshape(n, ratio * d).reshape(n, d, ratio), dt)
-
-
-def fused_gather_corners(v, cidx, row_offsets: Tuple[int, ...],
-                         onehot: Tuple[bool, ...], fuse_gather: bool,
-                         *, slab_dtypes: Tuple[str, ...] = ()):
-    """Gather every level's bilinear corners from the packed super-slab.
-
-    Shared by the fused forward and the fused backward's regather
-    branch — the routing logic must never diverge between directions.
-    VPU levels share ONE merged index vector across corners, points and
-    levels (``row_offsets`` lift local indices into the super-slab;
-    ``fuse_gather=False`` degrades to four merged per-corner gathers);
-    one-hot levels ride the MXU against their own sub-slab rows.
-
-    ``slab_dtypes`` commits a per-level storage dtype inside the packed
-    slab (see :func:`packed_ratios`): ``row_offsets`` are then CARRIER
-    row offsets, each logical corner row widens to ``ratio`` consecutive
-    carrier rows inside the same merged index vector, and the gathered
-    carrier rows are bitcast back to the level dtype before the fp32
-    upcast.  Empty / uniform-carrier ``slab_dtypes`` take the exact
-    legacy path (bitwise-stable).
-    Returns ``corners[l]``: list of 4 ``(Qb*P, D)`` fp32 arrays.
-    """
-    L = len(cidx)
-    n = cidx[0][0].shape[0]  # Qb*P
-    carrier = str(v.dtype)
-    dts = (tuple(str(jnp.dtype(d)) for d in slab_dtypes) if slab_dtypes
-           else (carrier,) * L)
-    ratios = packed_ratios(dts, v.dtype)
-    mixed = any(d != carrier for d in dts)
-    corners = [None] * L
-    vpu = [l for l in range(L) if not onehot[l]]
-    if vpu and not mixed:
-        if fuse_gather:
-            big = jnp.concatenate(
-                [c + row_offsets[l] for l in vpu for c in cidx[l]])
-            g = jnp.take(v, big, axis=0).astype(jnp.float32)
-            for i, l in enumerate(vpu):
-                corners[l] = jnp.split(g[i * 4 * n:(i + 1) * 4 * n], 4, axis=0)
-        else:
-            per_corner = [
-                jnp.take(v, jnp.concatenate(
-                    [cidx[l][c] + row_offsets[l] for l in vpu]),
-                    axis=0).astype(jnp.float32)
-                for c in range(4)
-            ]
-            for i, l in enumerate(vpu):
-                sl = slice(i * n, (i + 1) * n)
-                corners[l] = [pc[sl] for pc in per_corner]
-    elif vpu:
-        # mixed-dtype super-slab: still ONE merged gather over carrier
-        # rows — a ratio-r level contributes r consecutive carrier rows
-        # per corner, decoded back to its dtype after the take
-        def _carrier_idx(l, c):
-            base = c * ratios[l] + row_offsets[l]
-            if ratios[l] == 1:
-                return base
-            return (base[:, None] + jnp.arange(ratios[l])).reshape(-1)
-
-        if fuse_gather:
-            big = jnp.concatenate(
-                [_carrier_idx(l, c) for l in vpu for c in cidx[l]])
-            g = jnp.take(v, big, axis=0)
-            pos = 0
-            for l in vpu:
-                cs = []
-                for _ in range(4):
-                    m = n * ratios[l]
-                    cs.append(decode_packed_rows(
-                        g[pos:pos + m], ratios[l], dts[l]).astype(jnp.float32))
-                    pos += m
-                corners[l] = cs
-        else:
-            for l in vpu:
-                corners[l] = [
-                    decode_packed_rows(
-                        jnp.take(v, _carrier_idx(l, c), axis=0),
-                        ratios[l], dts[l]).astype(jnp.float32)
-                    for c in cidx[l]
-                ]
-    for l in range(L):
-        if not onehot[l]:
-            continue
-        end = row_offsets[l + 1] if l + 1 < L else v.shape[0]
-        sub = v[row_offsets[l]:end]
-        if dts[l] != carrier:
-            sub = decode_packed_rows(sub, ratios[l], dts[l])
-        all_idx = jnp.concatenate(cidx[l])
-        oh = (all_idx[:, None] == jnp.arange(sub.shape[0])[None, :]).astype(
-            jnp.float32)
-        corners[l] = jnp.split(oh @ sub.astype(jnp.float32), 4, axis=0)
-    return corners
-
-
-def _fwd_fused_kernel(
-    value_ref,  # (1, 1, R, D)   VMEM-resident packed pyramid super-slab
-    loc_ref,    # (1, 1, Qb, L, P, 2)
-    attn_ref,   # (1, 1, Qb, L, P)
-    out_ref,    # (1, 1, Qb, D)
-    saved_ref,  # (1, 1, Qb, L*4P, D) or None
-    *,
-    hws: Shapes,
-    row_offsets: Tuple[int, ...],
-    fuse_gather: bool,
-    onehot_levels: Tuple[bool, ...] = (),
-    slab_dtypes: Tuple[str, ...] = (),
-):
-    """Whole-pyramid forward step: cross-level accumulation in-kernel.
-
-    The per-level kernel's math, run over every level of the packed
-    super-slab inside one grid step — the output block is written to HBM
-    exactly once, instead of L fp32 partials round-tripping through HBM
-    and being summed by XLA.  Gather fusion goes one step further than
-    the per-level kernel: all VPU levels' corners ride ONE merged index
-    vector (per-level row offsets lift local indices into the
-    super-slab), so the effective gather vector length grows by another
-    factor of L on top of the paper's pixel-pair merge.  Levels routed
-    to the MXU one-hot path keep it, against their own sub-slab rows.
-    """
-    v = value_ref[0, 0]  # (R, D)
-    loc = loc_ref[0, 0].astype(jnp.float32)  # (Qb, L, P, 2)
-    attn = attn_ref[0, 0].astype(jnp.float32)  # (Qb, L, P)
-    Qb, L, P, _ = loc.shape
-    D = v.shape[-1]
-
-    cidx, geom = fused_level_corner_indices(loc, hws)
-    onehot = tuple(onehot_levels) if onehot_levels else (False,) * L
-    corners = fused_gather_corners(v, cidx, row_offsets, onehot, fuse_gather,
-                                   slab_dtypes=slab_dtypes)
-
-    contribs = []
-    saved_parts = []
-    for l in range(L):
-        lx, ly, (m00, m10, m01, m11) = geom[l]
-        v00, v10, v01, v11 = (c.reshape(Qb, P, D) for c in corners[l])
-        shape = (Qb, P, 1)
-        w00 = ((1 - lx) * (1 - ly) * m00).reshape(shape)
-        w10 = (lx * (1 - ly) * m10).reshape(shape)
-        w01 = ((1 - lx) * ly * m01).reshape(shape)
-        w11 = (lx * ly * m11).reshape(shape)
-        sampled = v00 * w00 + v10 * w10 + v01 * w01 + v11 * w11  # (Qb,P,D)
-        contribs.append(jnp.einsum("qpd,qp->qd", sampled, attn[:, l]))
+            # per-level partials summed in level order: the same adds the
+            # per-level launches' outputs see outside (tier parity)
+            total = total + static_loop(G, head, zero, unroll)
+        out_ref[pl.ds(q, 1), :] = total
         if saved_ref is not None:
-            saved_parts.append(jnp.concatenate([v00, v10, v01, v11], axis=1))
-    # Cross-level accumulation through a fori_loop over MATERIALISED
-    # per-level partials — not a straight-line `out += contrib` chain.
-    # The loop boundary forces each contribution to be rounded to fp32
-    # before its add, exactly like the per-level path's partial outputs
-    # (separate launches round at the HBM write).  Straight-line code
-    # lets XLA:CPU contract a P=1 einsum (which simplifies to a bare
-    # multiply) with the accumulation into one FMA — the product then
-    # reaches the add UNROUNDED and tier parity breaks by 1 ulp; no
-    # optimization_barrier or bitcast survives that contraction pass.
-    stacked = jnp.stack(contribs)  # (L, Qb, D) rounded fp32 partials
-    out = jax.lax.fori_loop(
-        0, L,
-        lambda l, acc: acc + jax.lax.dynamic_index_in_dim(
-            stacked, l, keepdims=False),
-        jnp.zeros((Qb, D), jnp.float32))
-    out_ref[0, 0] = out.astype(out_ref.dtype)
-    if saved_ref is not None:
-        # train mode: corners packed (Qb, L*4P, D), streamed once
-        saved_ref[0, 0] = jnp.concatenate(saved_parts, axis=1).astype(
-            saved_ref.dtype)
+            saved_ref[row_block(q, L * 4 * P), :] = row_scratch[...].astype(
+                saved_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, qb, body, 0)
 
 
-def msda_fwd_fused(
-    value_p: jax.Array,  # (B, H, R, D) packed pyramid super-slab
-    loc_f: jax.Array,    # (B, H, Q, L, P, 2)
-    attn_f: jax.Array,   # (B, H, Q, L, P)
+def compiler_params(vmem_limit: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(vmem_limit) if vmem_limit else None)
+
+
+# Mosaic tiles a 1-D SMEM operand in chunks of this many elements
+SMEM_TILE = 1024
+
+
+def table_block(n: int) -> int:
+    """Padded length of one query block's table chunk of ``n`` entries."""
+    return -(-n // SMEM_TILE) * SMEM_TILE
+
+
+def table_specs(qb: int, G: int, L: int, P: int, NG: int, nq: int):
+    """SMEM BlockSpecs of the corner-row and weight tables.
+
+    The tables are flat: one chunk per (batch, head group, query block),
+    each padded to :func:`table_block`.  Mosaic tiles the last two dims
+    of an SMEM block like VMEM ones, so neither a squeezed (batch,
+    group) prefix nor a chunk off the 1-D tiling would lower.
+    """
+    n = G * qb * L * P
+
+    def index(b, g, q):
+        return ((b * NG + g) * nq + q,)
+
+    return [
+        pl.BlockSpec((table_block(n),), index, memory_space=pltpu.SMEM),
+        pl.BlockSpec((table_block(4 * n),), index, memory_space=pltpu.SMEM),
+    ]
+
+
+def resident(shape, index_map):
+    """BlockSpec of a block every query step shares (the slab, the grad
+    slab): single-buffered, since it is fetched once per (batch, head
+    group) and double buffering would only double its VMEM."""
+    return pl.BlockSpec(shape, index_map, pipeline_mode=pl.Buffered(1))
+
+
+def msda_gather(
+    slab: jax.Array,   # (B, NG, R, G*D) fp32, zero-padded levels packed
+    idx: jax.Array,    # flat int32 table: top-left corner rows
+    w: jax.Array,      # flat fp32 table: corner weights (see table_specs)
     *,
-    hws: Shapes,
-    row_offsets: Tuple[int, ...],
+    levels: Tuple[LevelGeom, ...],
+    num_points: int,
+    head_dim: int,
     block_q: int,
     fuse_gather: bool = True,
-    save_sampled: bool = False,
-    onehot_levels: Tuple[bool, ...] = (),
-    interpret: bool = False,
-    out_dtype=None,
-    slab_dtypes: Tuple[str, ...] = (),
+    save_dtype=None,
+    interpret: bool,
+    vmem_limit: int = 0,
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Whole-pyramid forward: ONE ``pallas_call`` for all levels.
+    """Weighted corner gather over one slab: ``(out, saved)``.
 
-    The packed super-slab stays VMEM-resident across query blocks;
-    loc/attn are streamed once as ``(Qb, L, P, ...)`` blocks with a
-    single shared ``block_q``; the output (and, in train mode, the
-    packed saved corners ``(Qb, L*4P, D)``) are written to HBM exactly
-    once.  ``out_dtype`` is the in-kernel cross-level accumulator dtype.
-
-    ``slab_dtypes`` commits mixed per-level storage dtypes inside the
-    packed slab — ``value_p`` is then CARRIER-coded (narrowest dtype;
-    ``row_offsets`` in carrier rows, see :func:`packed_ratios`) and the
-    train-mode saved corners are emitted in the WIDEST committed dtype
-    so no level's corners round through a narrower type.
+    ``out`` is (B, NG, Qp, G*D) fp32: per query and head the sum over
+    levels, points and corners of weight x corner row.  With
+    ``save_dtype`` the raw corners also come back as (B, NG, Qp*L*4P,
+    G*D) in that dtype: each query's L*4P rows in :func:`saved_slot`
+    order.
     """
-    B, Hh, R, D = value_p.shape
-    out_dtype = value_p.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    _, _, Q, L, P, _ = loc_f.shape
-    assert Q % block_q == 0, (Q, block_q)
-    nq = Q // block_q
-    saved_dtype = value_p.dtype
-    if slab_dtypes:
-        saved_dtype = jnp.dtype(max(slab_dtypes,
-                                    key=lambda d: jnp.dtype(d).itemsize))
-
+    B, NG, R, GD = slab.shape
+    L, P, D = len(levels), num_points, head_dim
+    G = GD // D
+    nq = idx.shape[0] // (B * NG * table_block(block_q * G * L * P))
+    qp = nq * block_q
+    K = L * 4 * P
     kernel = functools.partial(
-        _fwd_fused_kernel, hws=tuple(hws), row_offsets=tuple(row_offsets),
-        fuse_gather=fuse_gather, onehot_levels=tuple(onehot_levels),
-        slab_dtypes=tuple(slab_dtypes),
-    )
-    out_shapes = [jax.ShapeDtypeStruct((B, Hh, Q, D), out_dtype)]
-    out_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, q: (b, h, q, 0))]
-    if save_sampled:
+        _gather_kernel, levels=tuple(levels), P=P, G=G, D=D, qb=block_q,
+        fuse_gather=fuse_gather, unroll=not interpret)
+    out_shapes = [jax.ShapeDtypeStruct((B, NG, qp, GD), jnp.float32)]
+    out_specs = [pl.BlockSpec((None, None, block_q, GD),
+                              lambda b, g, q: (b, g, q, 0))]
+    scratch = []
+    if save_dtype is not None:
         out_shapes.append(
-            jax.ShapeDtypeStruct((B, Hh, Q, L * 4 * P, D), saved_dtype))
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q, L * 4 * P, D),
-                         lambda b, h, q: (b, h, q, 0, 0)))
+            jax.ShapeDtypeStruct((B, NG, qp * K, GD), jnp.dtype(save_dtype)))
+        out_specs.append(pl.BlockSpec((None, None, block_q * K, GD),
+                                      lambda b, g, q: (b, g, q, 0)))
+        scratch = [pltpu.VMEM((K, GD), jnp.float32)]
     else:
         kernel = functools.partial(_nosave_wrap, kernel)
-
     outs = pl.pallas_call(
         kernel,
-        grid=(B, Hh, nq),
-        in_specs=[
-            # packed pyramid: revisited across q (resident per (b, h))
-            pl.BlockSpec((1, 1, R, D), lambda b, h, q: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, L, P, 2),
-                         lambda b, h, q: (b, h, q, 0, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, L, P), lambda b, h, q: (b, h, q, 0, 0)),
+        grid=(B, NG, qp // block_q),
+        in_specs=table_specs(block_q, G, L, P, NG, nq) + [
+            # slab: same block for every q -> resident across the block walk
+            resident((None, None, R, GD), lambda b, g, q: (b, g, 0, 0)),
         ],
-        out_specs=out_specs if save_sampled else out_specs[:1],
-        out_shape=out_shapes if save_sampled else out_shapes[:1],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        out_specs=out_specs,
+        out_shape=out_shapes,
+        scratch_shapes=scratch,
+        compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
-    )(value_p, loc_f, attn_f)
-    if save_sampled:
+    )(idx, w, slab)
+    if save_dtype is not None:
         return outs[0], outs[1]
     return outs[0], None
+
+
+def _nosave_wrap(kernel, idx_ref, w_ref, slab_ref, out_ref):
+    kernel(idx_ref, w_ref, slab_ref, out_ref, None, None)

@@ -4,12 +4,12 @@ The *planning* surface lives in ``repro.kernels.plan`` (``MsdaSpec`` →
 ``msda_plan`` → ``MsdaPlan``) and the backend registry in
 ``repro.kernels.registry``; this module keeps
 
-* the layout/padding contract and the kernel drivers
-  (``_fwd_impl`` / ``_bwd_impl`` / ``build_kernel_op``) the pallas
-  backend builder compiles into an executor — per-level launches, or
-  the fused whole-pyramid pair (``MSDAParams.fuse_levels``: all levels
-  packed into one super-slab via ``_pack_pyramid`` /
-  ``pyramid_row_offsets``, ONE pallas launch per direction),
+* the layout/padding contract and the kernel driver
+  (``build_kernel_op``) the pallas backend builder compiles into an
+  executor.  One launch schedule (:func:`plan_launches`) covers every
+  fusion tier: one launch per level, a fused prefix (levels packed into
+  one super-slab by ``_pack_pyramid`` / ``pyramid_row_offsets``) plus a
+  per-level tail, or the whole pyramid in ONE launch per direction,
 * the heuristic block planner (``plan_blocks`` — the paper's adaptive
   vec-len model, Fig. 7) and the MXU one-hot routing rule
   (``plan_onehot``), both invoked once per plan, and
@@ -22,26 +22,33 @@ The layout/padding contract between the wrapper and the kernels:
 each level is zero-padded from ``(H, W)`` to ``(H+2, W+2)`` (leading +
 trailing pad row/column — the paper's §4.1 padding fix, re-derived for
 branch-free corner pairs) and flattened row-major to a slab of
-``hwp_rows = round_up((H+2) * (W+2), 8)`` rows × ``D`` lanes.
+``hwp_rows = round_up((H+2) * (W+2), 8)`` rows × ``G*D`` lanes: the
+heads of one head group (``msda_fwd.head_group``) side by side.
 """
 from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels import msda_bwd, msda_fwd, ref
+from repro.kernels import msda_bwd, msda_fwd
 
 Shapes = Tuple[Tuple[int, int], ...]
 
-# Legacy default block-planning budget (v5e-class part).  Plans carry an
-# explicit per-device budget on the spec (plan.default_vmem_budget); this
-# constant only backs direct plan_blocks() calls that don't pass one.
+# Default block-planning budget for direct plan_blocks() calls that pass
+# none.  Plans carry an explicit per-device budget on the spec
+# (plan.default_vmem_budget), which also becomes Mosaic's VMEM limit.
 VMEM_BUDGET = 32 * 2**20
+# SMEM the double-buffered corner-row + weight table chunks of one query
+# step may take (of the 1 MiB a v5e TensorCore has; the rest is left to
+# Mosaic's own scalars)
+SMEM_BUDGET = 768 * 1024
 _SUBLANE = 8
+_LANES = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -53,25 +60,50 @@ def slab_rows(hw: Tuple[int, int]) -> int:
     return _round_up((h + 2) * (w + 2), _SUBLANE)
 
 
-def per_query_bytes(num_points: int, head_dim: int, *, train: bool = False,
-                    slab_itemsize: int = 4, levels: int = 1) -> int:
-    """Per-query VMEM working set: 4 corners x P points x D lanes in fp32,
-    ~4 concurrent copies (gathered, weighted, contribs, temporaries).
+def lane_width(heads: int, head_dim: int) -> int:
+    """VMEM lanes of one slab row: a head group's ``heads * head_dim``
+    values, padded to whole vreg rows."""
+    return _round_up(heads * head_dim, _LANES)
 
-    ``train=True`` adds the saved-corner OUTPUT block the forward kernel
-    keeps resident per step (``4P x D`` rows per query in the slab
-    dtype, streamed to HBM for the backward) — omitting it made train
-    plans overshoot the budget.  ``levels > 1`` scales the whole set for
-    the fused whole-pyramid kernels, whose every query step touches all
-    L levels.
+
+def resident_bytes(rows: int, head_dim: int, *, heads: int = 1) -> int:
+    """VMEM a launch keeps resident over ``rows`` slab rows: the fp32
+    value slab in the forward, the fp32 grad slab in the backward (one
+    at a time, single-buffered)."""
+    return rows * lane_width(heads, head_dim) * 4
+
+
+def per_query_bytes(num_points: int, head_dim: int, *, train: bool = False,
+                    slab_itemsize: int = 4, levels: int = 1,
+                    heads: int = 1) -> int:
+    """Per-query VMEM of one launch's pipelined step blocks.
+
+    Inference: the double-buffered fp32 output row.  ``train=True``
+    plans the larger backward step: the double-buffered gout row, saved
+    corners (``levels*4P`` rows in the slab dtype) and weight grads,
+    plus the fp32 copy of the corners that phase 1 reduces.  ``heads``
+    is the launch's head group (its lanes).
 
     Single source of truth for the paper's occupancy model — used by the
     block planner below and by ``MsdaPlan.level_report``.
     """
-    per_level = 4 * num_points * head_dim * 4 * 4 + num_points * 64
-    if train:  # saved-corner output block: (block_q, 4P, D) slab dtype
-        per_level += 4 * num_points * head_dim * slab_itemsize
-    return levels * per_level
+    lanes = lane_width(heads, head_dim)
+    io = lanes * 4
+    if not train:
+        return 2 * io
+    k = levels * 4 * num_points
+    saved = k * lanes * slab_itemsize
+    grads = heads * k * 4
+    return 2 * (io + saved + grads) + k * lanes * 4
+
+
+def smem_block_cap(num_points: int, *, levels: int = 1,
+                   heads: int = 1) -> int:
+    """Largest query block whose double-buffered SMEM table chunks (one
+    int32 corner row and four fp32 weights per head, level and point)
+    fit :data:`SMEM_BUDGET`, less a tiling chunk of slack per table."""
+    per_q = 2 * 5 * heads * levels * num_points * 4
+    return max(_SUBLANE, (SMEM_BUDGET - 2 * 2 * 1024 * 4) // per_q)
 
 
 def pyramid_row_offsets(spatial_shapes: Shapes) -> Tuple[Tuple[int, ...], int]:
@@ -79,8 +111,8 @@ def pyramid_row_offsets(spatial_shapes: Shapes) -> Tuple[Tuple[int, ...], int]:
 
     Returns ``(offsets, total_rows)``: level ``l`` occupies rows
     ``[offsets[l], offsets[l] + slab_rows(hw_l))`` of the row-major
-    ``(total_rows, D)`` super-slab (every level's slab is already padded
-    to a sublane multiple, so the offsets stay aligned).
+    ``(total_rows, G*D)`` super-slab (every level's slab is already
+    padded to a sublane multiple, so the offsets stay aligned).
     """
     offs, total = [], 0
     for hw in spatial_shapes:
@@ -99,25 +131,14 @@ def _per_level_itemsizes(spatial_shapes: Shapes, value_itemsize) -> Tuple[int, .
 
 
 def fused_resident_bytes(spatial_shapes: Shapes, head_dim: int, *,
-                         slab_itemsize=4, train: bool = True,
-                         accum_itemsize: int = 4) -> int:
-    """VMEM-resident bytes of the fused whole-pyramid kernels.
-
-    Σ slab_rows(hw) x D in each level's COMMITTED slab dtype
-    (``slab_itemsize`` may be a per-level sequence — the mixed-dtype
-    super-slab stores every level at its own width), plus — in train
-    mode — the same row extent again in the accum dtype for the resident
-    grad super-slab.  The ONE definition of the packed pyramid's
-    residency: the fitting rung, the fused block planner and
-    ``MsdaPlan.level_report`` all read it from here.
-    """
-    items = _per_level_itemsizes(spatial_shapes, slab_itemsize)
+                         heads: int = 1) -> int:
+    """VMEM-resident bytes of a fused launch over ``spatial_shapes``: the
+    packed super-slab (:func:`resident_bytes` over every level's rows).
+    The ONE definition of the packed pyramid's residency: the fitting
+    rung, the fused block planner and ``MsdaPlan.level_report`` all read
+    it from here."""
     _, total = pyramid_row_offsets(spatial_shapes)
-    resident = sum(slab_rows(hw) * head_dim * it
-                   for hw, it in zip(spatial_shapes, items))
-    if train:
-        resident += total * head_dim * accum_itemsize
-    return resident
+    return resident_bytes(total, head_dim, heads=heads)
 
 
 def fusion_prefix(
@@ -128,27 +149,27 @@ def fusion_prefix(
     value_itemsize=4,
     train: bool = True,
     vmem_budget: int = VMEM_BUDGET,
-    accum_itemsize: int = 4,
+    heads: int = 1,
 ) -> int:
     """The planner's partial-fusion occupancy model.
 
     Returns the largest level prefix length ``k`` such that the packed
-    super-slab of levels ``[0..k)`` (:func:`fused_resident_bytes`, each
-    level at its committed itemsize) PLUS a minimal one-sublane query
-    step's working set over those ``k`` levels fits ``vmem_budget`` —
-    ``k == len(spatial_shapes)`` means the whole pyramid fuses, ``0``
-    means not even a single level does.  The fused launch covers
-    ``[0..k)`` and the tail runs per-level, so launches per direction
-    drop from ``L`` to ``L - k + 1``.
+    super-slab of levels ``[0..k)`` (:func:`fused_resident_bytes`) PLUS a
+    minimal one-sublane query step's working set over those ``k`` levels
+    (saved corners at the widest committed itemsize) fits
+    ``vmem_budget`` — ``k == len(spatial_shapes)`` means the whole
+    pyramid fuses, ``0`` means not even a single level does.  The fused
+    launch covers ``[0..k)`` and the tail runs per-level, so launches
+    per direction drop from ``L`` to ``L - k + 1``.
     """
     L = len(spatial_shapes)
     items = _per_level_itemsizes(spatial_shapes, value_itemsize)
     for k in range(L, 0, -1):
-        resident = fused_resident_bytes(
-            spatial_shapes[:k], head_dim, slab_itemsize=items[:k],
-            train=train, accum_itemsize=accum_itemsize)
+        resident = fused_resident_bytes(spatial_shapes[:k], head_dim,
+                                        heads=heads)
         per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=max(items[:k]), levels=k)
+                                slab_itemsize=max(items[:k]), levels=k,
+                                heads=heads)
         if resident + _SUBLANE * per_q <= vmem_budget:
             return k
     return 0
@@ -162,7 +183,7 @@ def fused_pyramid_fits(
     value_itemsize=4,
     train: bool = True,
     vmem_budget: int = VMEM_BUDGET,
-    accum_itemsize: int = 4,
+    heads: int = 1,
 ) -> bool:
     """Whole-pyramid fitting rung: does the FULL prefix fit?
 
@@ -172,7 +193,7 @@ def fused_pyramid_fits(
     return fusion_prefix(
         spatial_shapes, num_points, head_dim, value_itemsize=value_itemsize,
         train=train, vmem_budget=vmem_budget,
-        accum_itemsize=accum_itemsize) == len(spatial_shapes)
+        heads=heads) == len(spatial_shapes)
 
 
 def plan_blocks(
@@ -185,31 +206,31 @@ def plan_blocks(
     train: bool = True,
     vmem_budget: int = VMEM_BUDGET,
     adaptive: bool = True,
-    accum_itemsize: int = 4,
     fused: bool = False,
+    heads: int = 1,
 ) -> Tuple[int, ...]:
     """Per-level query-block sizes (the paper's adaptive vec-len, Fig. 7).
 
     Larger levels leave less VMEM for per-step tensors, so their blocks
     shrink; tiny levels get wide blocks (long vectors).  ``adaptive=False``
     reproduces the "-Adaptive VecLen" ablation (fixed minimal block).
+    Every block also respects the SMEM cap of its table chunks
+    (:func:`smem_block_cap`).
 
-    ``value_itemsize`` is the itemsize of the dtype the value slab is
-    *stored* in (a bf16-slab plan halves residency and widens blocks) —
-    a scalar, or a per-level sequence when the committed slab dtypes
-    mix; ``accum_itemsize`` sizes the train-mode grad slab, which stays
-    wide (fp32) regardless of the slab dtype.  The per-step working set
-    includes the train-mode saved-corner output block (see
-    :func:`per_query_bytes`).
+    ``value_itemsize`` is the itemsize of the committed slab dtype — a
+    scalar, or a per-level sequence when the committed slab dtypes mix;
+    it sizes the train-mode saved corners (the resident slab is fp32
+    whatever the dtype, see :func:`resident_bytes`).  ``heads`` is the
+    head group one launch holds on its lanes.
 
     ``fused=True`` plans the whole-pyramid kernel instead: the resident
-    set is the PACKED super-slab (all given levels at their own
-    itemsizes, plus the train grad super-slab) and one shared block
-    serves every level — returned replicated per level so the tuple
-    shape stays uniform.  To plan a partial-fusion prefix, pass the
-    prefix's shapes/itemsizes only.
+    set is the PACKED super-slab and one shared block serves every level
+    — returned replicated per level so the tuple shape stays uniform.
+    To plan a partial-fusion prefix, pass the prefix's shapes/itemsizes
+    only.
     """
-    def _clamp(bq: int) -> int:
+    def _clamp(bq: int, levels: int) -> int:
+        bq = min(bq, smem_block_cap(num_points, levels=levels, heads=heads))
         bq = max(_SUBLANE, min(2048, (bq // _SUBLANE) * _SUBLANE))
         return min(bq, _round_up(num_queries, _SUBLANE))
 
@@ -218,26 +239,23 @@ def plan_blocks(
         L = len(spatial_shapes)
         if not adaptive:
             return (_SUBLANE,) * L
-        resident = fused_resident_bytes(
-            spatial_shapes, head_dim, slab_itemsize=items,
-            train=train, accum_itemsize=accum_itemsize)
+        resident = fused_resident_bytes(spatial_shapes, head_dim, heads=heads)
         avail = max(vmem_budget - resident, 1 * 2**20)
         per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=max(items), levels=L)
-        return (int(_clamp(avail // per_q)),) * L
+                                slab_itemsize=max(items), levels=L,
+                                heads=heads)
+        return (int(_clamp(avail // per_q, L)),) * L
 
     out = []
     for hw, it in zip(spatial_shapes, items):
         if not adaptive:
             out.append(_SUBLANE)
             continue
-        resident = slab_rows(hw) * head_dim * it
-        if train:  # bwd keeps a widened (accum-dtype) grad slab too
-            resident += slab_rows(hw) * head_dim * accum_itemsize
+        resident = resident_bytes(slab_rows(hw), head_dim, heads=heads)
         avail = max(vmem_budget - resident, 1 * 2**20)
         per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=it)
-        out.append(int(_clamp(avail // per_q)))
+                                slab_itemsize=it, heads=heads)
+        out.append(int(_clamp(avail // per_q, 1)))
     return tuple(out)
 
 
@@ -247,32 +265,31 @@ class MSDAParams:
 
     spatial_shapes: Shapes
     block_q: Tuple[int, ...]
+    # True runs the kernels in the Pallas interpreter (CPU tests).  No
+    # default: a TPU build must say it compiles them with Mosaic.
+    interpret: bool
     fuse_gather: bool = True
     fuse_scatter: bool = True
     save_sampled: bool = False
-    interpret: bool = True
-    # per-level: route sampling through the MXU via one-hot matmuls
-    # (beyond-paper; profitable for small levels where HWp fits an MXU
-    # operand and the VPU gather would under-fill the vector unit)
+    # per-level: fetch and update rows through one-hot MXU matmuls
+    # (beyond-paper ablation of the row loop)
     onehot_levels: Tuple[bool, ...] = ()
-    # mixed precision: per-level dtype the VMEM value slab is stored in
-    # ('' entries / empty tuple -> keep the operand dtype) and the dtype
-    # partial outputs + the bwd grad slab accumulate in
+    # mixed precision: per-level dtype the value slab is rounded to
+    # ('' entries / empty tuple -> keep the operand dtype); the kernels
+    # accumulate outputs and the grad slab in fp32
     slab_dtypes: Tuple[str, ...] = ()
-    accum_dtype: str = "float32"
-    # dtype the grad_value must be emitted in (custom-VJP contract with
-    # the primal); '' -> infer from the residual slab (legacy behaviour,
-    # only correct when slab dtype == operand dtype)
-    io_dtype: str = ""
     # fused whole-pyramid kernels: levels packed into ONE super-slab,
     # one pallas launch per direction with a single shared block_q
     # (block_q[0]; the planner replicates it across the fused levels)
     fuse_levels: bool = False
     # partial fusion: number of levels in the fused prefix [0..k).
-    # 0 means "all levels" when fuse_levels is set (legacy whole-pyramid
+    # 0 means "all levels" when fuse_levels is set (whole-pyramid
     # fusion); 0 < k < L runs ONE fused launch over the prefix plus
     # per-level launches for the tail, summed into the same accumulator.
     fuse_prefix: int = 0
+    # Mosaic's scoped-VMEM limit for every launch (the plan's budget);
+    # 0 leaves the compiler default
+    vmem_limit: int = 0
 
     def slab_dtype(self, level: int) -> str:
         if self.slab_dtypes and self.slab_dtypes[level]:
@@ -288,11 +305,9 @@ class MSDAParams:
         return min(self.fuse_prefix, L) if self.fuse_prefix else L
 
     def fused_slab_dtypes(self, operand_dtype) -> Tuple[str, ...]:
-        """Per-level storage dtypes INSIDE the packed super-slab: each
-        level keeps its committed slab dtype (operand dtype where
-        uncommitted), so bf16-winner levels keep their residency win
-        under fusion — the slab is carrier-coded when they mix (see
-        :func:`packed_pyramid_layout`)."""
+        """Per-level slab dtypes: the committed one, else the operand
+        dtype.  A packed super-slab rounds each level to its own dtype
+        (see :func:`_pack_pyramid`)."""
         return tuple(self.slab_dtype(l) or str(jnp.dtype(operand_dtype))
                      for l in range(len(self.spatial_shapes)))
 
@@ -337,468 +352,263 @@ def _pad_q(x: jax.Array, q_axis: int, qpad: int, fill=0.0) -> jax.Array:
     return jnp.pad(x, pads, constant_values=fill)
 
 
-def packed_pyramid_layout(spatial_shapes: Shapes,
-                          dtype_names: Tuple[str, ...]):
-    """Carrier layout of a (possibly mixed-dtype) packed super-slab.
-
-    One JAX array has one dtype, so a super-slab whose levels commit
-    DIFFERENT dtypes is stored in an UNSIGNED-INT *carrier* whose
-    itemsize is the narrowest committed itemsize, with each level's
-    rows reinterpreted byte-for-byte: a level whose itemsize is
-    ``ratio`` x the carrier's occupies ``slab_rows(hw) * ratio``
-    carrier rows.  ``slab_rows`` is always a sublane multiple and
-    ``ratio >= 1``, so every offset stays aligned.  The carrier must
-    be an integer dtype: reinterpreting fp32 halves as bfloat16 can
-    produce NaN bit patterns that backends silently canonicalise in
-    transit (payload 0x7fc0), corrupting the wide level's low bytes —
-    integer lanes move bytes verbatim.
-
-    Returns ``(carrier, offsets, total, ratios)``: carrier dtype name,
-    per-level CARRIER row offsets, total carrier rows, and per-level
-    carrier-rows-per-logical-row.  With uniform dtypes the committed
-    dtype itself is the carrier and this degenerates to exactly
-    :func:`pyramid_row_offsets` (ratios all 1).
-    """
-    names = tuple(str(jnp.dtype(d)) for d in dtype_names)
-    assert len(names) == len(spatial_shapes), (names, spatial_shapes)
-    if len(set(names)) == 1:
-        carrier = names[0]
-    else:
-        carrier = f"uint{8 * min(jnp.dtype(n).itemsize for n in names)}"
-    ci = jnp.dtype(carrier).itemsize
-    ratios = tuple(jnp.dtype(n).itemsize // ci for n in names)
-    offs, total = [], 0
-    for hw, r in zip(spatial_shapes, ratios):
-        offs.append(total)
-        total += slab_rows(hw) * r
-    return carrier, tuple(offs), total, ratios
-
-
-def _encode_packed_level(lvl: jax.Array, carrier) -> jax.Array:
-    """(B,H,rows,D) level slab -> (B,H,rows*ratio,D) carrier rows.
-
-    Row-major byte reinterpretation — the exact inverse of
-    ``msda_fwd.decode_packed_rows`` (ratio consecutive carrier rows per
-    logical row, consecutive carrier elements per wide element).
-    """
-    dt = jnp.dtype(carrier)
-    if lvl.dtype == dt:
-        return lvl
-    ratio = lvl.dtype.itemsize // dt.itemsize
-    out = jax.lax.bitcast_convert_type(lvl, dt)
-    if ratio == 1:  # same itemsize, different dtype: shape unchanged
-        return out
-    B, Hh, rows, D = lvl.shape
-    return out.reshape(B, Hh, rows * ratio, D)
-
-
 def _pack_pyramid(value_t: jax.Array, spatial_shapes: Shapes,
-                  dtype=None, dtypes: Tuple[str, ...] = ()) -> jax.Array:
-    """(B,H,S,D) -> packed super-slab (B,H,total_rows,D), every level
+                  dtypes: Tuple[str, ...]) -> jax.Array:
+    """(B,G,S,C) -> packed fp32 super-slab (B,G,total_rows,C), every level
     zero-padded to its ``slab_rows`` extent at its static row offset.
 
-    ``dtype`` casts the whole slab uniformly (legacy whole-pyramid
-    path); ``dtypes`` instead commits a PER-LEVEL storage dtype — each
-    level is cast to its own dtype and byte-packed into the carrier
-    layout of :func:`packed_pyramid_layout`.
+    Each level is rounded to its own committed dtype in ``dtypes``, then
+    stored in fp32 (exact for every narrower float): the kernels address
+    the slab row by row, which Mosaic cannot do inside a packed 16-bit
+    tile.
     """
-    if dtypes:
-        carrier, _, _, _ = packed_pyramid_layout(spatial_shapes, dtypes)
-        parts = []
-        offset = 0
-        for hw, dt in zip(spatial_shapes, dtypes):
-            lvl = _pad_level(value_t, offset, hw).astype(dt)
-            parts.append(_encode_packed_level(lvl, carrier))
-            offset += hw[0] * hw[1]
-        return jnp.concatenate(parts, axis=2)
     parts = []
     offset = 0
-    for hw in spatial_shapes:
-        parts.append(_pad_level(value_t, offset, hw))
+    for hw, dt in zip(spatial_shapes, dtypes):
+        lvl = _pad_level(value_t, offset, hw)
+        parts.append(lvl.astype(dt).astype(jnp.float32))
         offset += hw[0] * hw[1]
-    slab = jnp.concatenate(parts, axis=2)
-    if dtype is not None and slab.dtype != jnp.dtype(dtype):
-        slab = slab.astype(dtype)
-    return slab
+    return jnp.concatenate(parts, axis=2)
 
 
-def _unpack_grad_pyramid(slab: jax.Array, spatial_shapes: Shapes) -> jax.Array:
-    """Inverse of :func:`_pack_pyramid` for the grad super-slab:
-    (B,H,total_rows,D) -> (B,H,S,D)."""
-    outs = []
-    r = 0
-    for hw in spatial_shapes:
-        rows = slab_rows(hw)
-        outs.append(_unpad_grad(slab[:, :, r:r + rows], hw))
-        r += rows
-    return jnp.concatenate(outs, axis=2)
+@dataclass(frozen=True)
+class Launch:
+    """One kernel launch of a plan: the levels it covers, its query
+    block, and whether it runs over a packed super-slab (the fused
+    prefix or whole pyramid) or over one level's own slab."""
+
+    levels: Tuple[int, ...]
+    block_q: int
+    packed: bool
 
 
-def _fused_launch_meta(p: MSDAParams, operand_dtype, k: int):
-    """Static layout of the fused prefix launch over levels [0..k):
-    (per-level dtype names, carrier gather offsets, plain grad offsets,
-    grad total rows, mixed?)."""
-    hws = p.spatial_shapes[:k]
-    dtypes = p.fused_slab_dtypes(operand_dtype)[:k]
-    carrier, goffs, _, _ = packed_pyramid_layout(hws, dtypes)
-    row_offsets, total_rows = pyramid_row_offsets(hws)
-    mixed = any(str(jnp.dtype(d)) != carrier for d in dtypes)
-    return dtypes, goffs, row_offsets, total_rows, mixed
-
-
-def _fwd_impl_fused(p: MSDAParams, value, loc, attn):
-    """Fused whole-pyramid forward: ONE pallas launch. Returns (out, res)."""
-    B, S, Hh, D = value.shape
-    _, Q, _, L, P, _ = loc.shape
-    # (B,S,H,D) -> (B,H,S,D); (B,Q,H,L,P,2) -> (B,H,Q,L,P,2)
-    value_t = jnp.transpose(value, (0, 2, 1, 3))
-    loc_f = jnp.transpose(loc, (0, 2, 1, 3, 4, 5))
-    attn_f = jnp.transpose(attn, (0, 2, 1, 3, 4))
-
-    accum = jnp.dtype(p.accum_dtype)
-    dtypes, goffs, _, _, mixed = _fused_launch_meta(p, value.dtype, L)
-    slab = _pack_pyramid(value_t, p.spatial_shapes, dtypes=dtypes)
-    bq = p.block_q[0]
-    qpad = _round_up(Q, bq)
-    loc_f = _pad_q(loc_f, 2, qpad, 0.5)
-    attn_f = _pad_q(attn_f, 2, qpad, 0.0)
-    out, saved = msda_fwd.msda_fwd_fused(
-        slab,
-        loc_f,
-        attn_f,
-        hws=p.spatial_shapes,
-        row_offsets=goffs,
-        block_q=bq,
-        fuse_gather=p.fuse_gather,
-        save_sampled=p.save_sampled,
-        onehot_levels=p.onehot_levels,
-        interpret=p.interpret,
-        out_dtype=accum,
-        slab_dtypes=dtypes if mixed else (),
-    )
-    out = jnp.transpose(out[:, :, :Q], (0, 2, 1, 3)).reshape(B, Q, Hh * D)
-    out = out.astype(value.dtype)
-    if p.save_sampled:
-        residuals = (None, saved, loc_f, attn_f)
-    else:
-        residuals = (slab, None, loc_f, attn_f)
-    return out, residuals
-
-
-def _bwd_impl_fused(p: MSDAParams, residuals, gout):
-    """Fused whole-pyramid backward: ONE pallas launch."""
-    slab, saved, loc_f, attn_f = residuals
-    B, Hh, Qpad, L, P, _ = loc_f.shape
-    HD = gout.shape[-1]
-    D = HD // Hh
-    Q = gout.shape[1]
-    gout_t = jnp.transpose(gout.reshape(B, Q, Hh, D), (0, 2, 1, 3))
-    gout_t = _pad_q(gout_t, 2, Qpad, 0.0)
-    io_dtype = p.io_dtype or (slab.dtype if saved is None else saved.dtype)
-    dtypes, goffs, row_offsets, total_rows, mixed = _fused_launch_meta(
-        p, io_dtype, L)
-    gval, gloc, gattn = msda_bwd.msda_bwd_fused(
-        slab,
-        loc_f,
-        attn_f,
-        gout_t,
-        saved,
-        hws=p.spatial_shapes,
-        row_offsets=row_offsets,
-        total_rows=total_rows,
-        block_q=p.block_q[0],
-        fuse_scatter=p.fuse_scatter,
-        onehot_levels=p.onehot_levels,
-        interpret=p.interpret,
-        accum_dtype=p.accum_dtype,
-        slab_dtypes=dtypes if mixed else (),
-        gather_offsets=goffs if mixed else (),
-    )
-    gvalue = _unpack_grad_pyramid(gval, p.spatial_shapes)  # (B,H,S,D)
-    gvalue = jnp.transpose(gvalue, (0, 2, 1, 3))
-    gloc = jnp.transpose(gloc[:, :, :Q], (0, 2, 1, 3, 4, 5))  # (B,Q,H,L,P,2)
-    gattn = jnp.transpose(gattn[:, :, :Q], (0, 2, 1, 3, 4))  # (B,Q,H,L,P)
-    return gvalue, gloc, gattn
-
-
-def _fwd_impl_prefix(p: MSDAParams, k: int, value, loc, attn):
-    """Partial-fusion forward: ONE fused launch over levels [0..k) plus
-    per-level launches for the tail, summed into the same accumulator —
-    ``L - k + 1`` launches instead of ``L``.  Returns (out, res)."""
-    B, S, Hh, D = value.shape
-    _, Q, _, L, P, _ = loc.shape
-    value_t = jnp.transpose(value, (0, 2, 1, 3))
-    # fused-layout loc/attn (query-major); tail levels slice level l out
-    loc_f = jnp.transpose(loc, (0, 2, 1, 3, 4, 5))   # (B,H,Q,L,P,2)
-    attn_f = jnp.transpose(attn, (0, 2, 1, 3, 4))    # (B,H,Q,L,P)
-
-    accum = jnp.dtype(p.accum_dtype)
-    dtypes, goffs, _, _, mixed = _fused_launch_meta(p, value.dtype, k)
-    slab_pre = _pack_pyramid(value_t, p.spatial_shapes[:k], dtypes=dtypes)
-
-    bq0 = p.block_q[0]
-    qpad0 = _round_up(Q, bq0)
-    out_pre, saved_pre = msda_fwd.msda_fwd_fused(
-        slab_pre,
-        _pad_q(loc_f[:, :, :, :k], 2, qpad0, 0.5),
-        _pad_q(attn_f[:, :, :, :k], 2, qpad0, 0.0),
-        hws=p.spatial_shapes[:k],
-        row_offsets=goffs,
-        block_q=bq0,
-        fuse_gather=p.fuse_gather,
-        save_sampled=p.save_sampled,
-        onehot_levels=p.onehot_levels[:k] if p.onehot_levels else (),
-        interpret=p.interpret,
-        out_dtype=accum,
-        slab_dtypes=dtypes if mixed else (),
-    )
-    out = out_pre[:, :, :Q]  # (B,H,Q,D) accum dtype
-
-    tail_slabs, tail_saved = [], []
-    offset = sum(h * w for h, w in p.spatial_shapes[:k])
-    for l in range(k, L):
-        hw = p.spatial_shapes[l]
-        bq = p.block_q[l]
-        qpad = _round_up(Q, bq)
-        slab = _pad_level(value_t, offset, hw)
-        sdt = p.slab_dtype(l)
-        if sdt:
-            slab = slab.astype(sdt)
-        offset += hw[0] * hw[1]
-        out_l, saved_l = msda_fwd.msda_fwd_level(
-            slab,
-            _pad_q(loc_f[:, :, :, l], 2, qpad, 0.5),
-            _pad_q(attn_f[:, :, :, l], 2, qpad, 0.0),
-            hw=hw,
-            block_q=bq,
-            fuse_gather=p.fuse_gather,
-            save_sampled=p.save_sampled,
-            onehot_gather=p.onehot_levels[l] if p.onehot_levels else False,
-            interpret=p.interpret,
-            out_dtype=accum,
-        )
-        out = out + out_l[:, :, :Q]
-        tail_slabs.append(slab)
-        tail_saved.append(saved_l)
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, Hh * D)
-    out = out.astype(value.dtype)
-    # residuals carry UNPADDED loc/attn in the fused layout — fwd/bwd
-    # re-pad per launch (the fused prefix and each tail level may
-    # commit different block sizes)
-    loc_r = loc_f[:, :, :Q]
-    attn_r = attn_f[:, :, :Q]
-    if p.save_sampled:
-        residuals = (None, (saved_pre, *tail_saved), loc_r, attn_r)
-    else:
-        residuals = ((slab_pre, *tail_slabs), None, loc_r, attn_r)
-    return out, residuals
-
-
-def _bwd_impl_prefix(p: MSDAParams, k: int, residuals, gout):
-    """Partial-fusion backward: ONE fused launch over the prefix plus
-    per-level launches for the tail."""
-    slabs, saved_all, loc_f, attn_f = residuals
-    B, Hh, Q, L, P, _ = loc_f.shape
-    HD = gout.shape[-1]
-    D = HD // Hh
-    gout_t = jnp.transpose(gout.reshape(B, Q, Hh, D), (0, 2, 1, 3))  # (B,H,Q,D)
-
-    slab_pre = slabs[0] if slabs is not None else None
-    saved_pre = saved_all[0] if saved_all is not None else None
-    io_dtype = p.io_dtype or (slab_pre if slab_pre is not None
-                              else saved_pre).dtype
-    dtypes, goffs, row_offsets, total_rows, mixed = _fused_launch_meta(
-        p, io_dtype, k)
-
-    bq0 = p.block_q[0]
-    qpad0 = _round_up(Q, bq0)
-    gval_pre, gloc_pre, gattn_pre = msda_bwd.msda_bwd_fused(
-        slab_pre,
-        _pad_q(loc_f[:, :, :, :k], 2, qpad0, 0.5),
-        _pad_q(attn_f[:, :, :, :k], 2, qpad0, 0.0),
-        _pad_q(gout_t, 2, qpad0, 0.0),
-        saved_pre,
-        hws=p.spatial_shapes[:k],
-        row_offsets=row_offsets,
-        total_rows=total_rows,
-        block_q=bq0,
-        fuse_scatter=p.fuse_scatter,
-        onehot_levels=p.onehot_levels[:k] if p.onehot_levels else (),
-        interpret=p.interpret,
-        accum_dtype=p.accum_dtype,
-        slab_dtypes=dtypes if mixed else (),
-        gather_offsets=goffs if mixed else (),
-    )
-    gvals = [_unpack_grad_pyramid(gval_pre, p.spatial_shapes[:k])]
-    glocs = [gloc_pre[:, :, :Q]]    # (B,H,Q,k,P,2)
-    gattns = [gattn_pre[:, :, :Q]]  # (B,H,Q,k,P)
-
-    for l in range(k, L):
-        hw = p.spatial_shapes[l]
-        bq = p.block_q[l]
-        qpad = _round_up(Q, bq)
-        saved_l = saved_all[1 + l - k] if saved_all is not None else None
-        slab_l = slabs[1 + l - k] if slabs is not None else None
-        gval, gloc, gattn = msda_bwd.msda_bwd_level(
-            slab_l,
-            _pad_q(loc_f[:, :, :, l], 2, qpad, 0.5),
-            _pad_q(attn_f[:, :, :, l], 2, qpad, 0.0),
-            _pad_q(gout_t, 2, qpad, 0.0),
-            saved_l,
-            hw=hw,
-            hwp_rows=slab_rows(hw),
-            block_q=bq,
-            fuse_scatter=p.fuse_scatter,
-            onehot_scatter=p.onehot_levels[l] if p.onehot_levels else False,
-            interpret=p.interpret,
-            accum_dtype=p.accum_dtype,
-        )
-        gvals.append(_unpad_grad(gval, hw))
-        glocs.append(gloc[:, :, :Q])    # (B,H,Q,P,2)
-        gattns.append(gattn[:, :, :Q])  # (B,H,Q,P)
-
-    gvalue = jnp.concatenate(gvals, axis=2)  # (B,H,S,D) accum dtype
-    gvalue = jnp.transpose(gvalue, (0, 2, 1, 3))
-    # tail grads are (B,H,Q,P,...) per level — lift to the L axis and
-    # append after the prefix block
-    gloc = jnp.concatenate(
-        [glocs[0]] + [g.reshape(B, Hh, Q, 1, P, 2) for g in glocs[1:]], axis=3)
-    gattn = jnp.concatenate(
-        [gattns[0]] + [g.reshape(B, Hh, Q, 1, P) for g in gattns[1:]], axis=3)
-    gloc = jnp.transpose(gloc, (0, 2, 1, 3, 4, 5))  # (B,Q,H,L,P,2)
-    gattn = jnp.transpose(gattn, (0, 2, 1, 3, 4))   # (B,Q,H,L,P)
-    return gvalue, gloc, gattn
-
-
-def _fwd_impl(p: MSDAParams, value, loc, attn):
-    """Kernel-backed forward. Returns (out, residuals)."""
+def plan_launches(p: MSDAParams) -> Tuple[Launch, ...]:
+    """The launch schedule of ``p``, the same in both directions: the
+    whole pyramid, a fused prefix plus a per-level tail, or one launch
+    per level."""
+    L = len(p.spatial_shapes)
     k = p.fused_prefix_len()
-    if k == len(p.spatial_shapes) and k:
-        return _fwd_impl_fused(p, value, loc, attn)
-    if k:
-        return _fwd_impl_prefix(p, k, value, loc, attn)
-    B, S, Hh, D = value.shape
-    _, Q, _, L, P, _ = loc.shape
-    # (B,S,H,D) -> (B,H,S,D); (B,Q,H,L,P,2) -> (B,H,L,Q,P,2)
-    value_t = jnp.transpose(value, (0, 2, 1, 3))
-    loc_t = jnp.transpose(loc, (0, 2, 3, 1, 4, 5))
-    attn_t = jnp.transpose(attn, (0, 2, 3, 1, 4))
-
-    accum = jnp.dtype(p.accum_dtype)
-    out = jnp.zeros((B, Hh, Q, D), accum)
-    slabs, saved_all = [], []
-    offset = 0
-    for l, hw in enumerate(p.spatial_shapes):
-        bq = p.block_q[l]
-        qpad = _round_up(Q, bq)
-        slab = _pad_level(value_t, offset, hw)
-        sdt = p.slab_dtype(l)
-        if sdt:  # committed slab dtype (may narrow: bf16 slab, fp32 accum)
-            slab = slab.astype(sdt)
-        offset += hw[0] * hw[1]
-        loc_l = _pad_q(loc_t[:, :, l], 2, qpad, 0.5)
-        attn_l = _pad_q(attn_t[:, :, l], 2, qpad, 0.0)
-        onehot = p.onehot_levels[l] if p.onehot_levels else False
-        out_l, saved_l = msda_fwd.msda_fwd_level(
-            slab,
-            loc_l,
-            attn_l,
-            hw=hw,
-            block_q=bq,
-            fuse_gather=p.fuse_gather,
-            save_sampled=p.save_sampled,
-            onehot_gather=onehot,
-            interpret=p.interpret,
-            out_dtype=accum,
-        )
-        out = out + out_l[:, :, :Q]
-        slabs.append(slab)
-        saved_all.append(saved_l)
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, Hh * D)
-    out = out.astype(value.dtype)
-    if p.save_sampled:
-        residuals = (None, tuple(saved_all), loc_t, attn_t)
-    else:
-        residuals = (tuple(slabs), None, loc_t, attn_t)
-    return out, residuals
+    out = [Launch(tuple(range(k)), p.block_q[0], True)] if k else []
+    out += [Launch((l,), p.block_q[l], False) for l in range(k, L)]
+    return tuple(out)
 
 
-def _bwd_impl(p: MSDAParams, residuals, gout):
-    k = p.fused_prefix_len()
-    if k == len(p.spatial_shapes) and k:
-        return _bwd_impl_fused(p, residuals, gout)
-    if k:
-        return _bwd_impl_prefix(p, k, residuals, gout)
-    slabs, saved_all, loc_t, attn_t = residuals
-    B, Hh, L, Q, P, _ = loc_t.shape
-    HD = gout.shape[-1]
-    D = HD // Hh
-    gout_t = jnp.transpose(gout.reshape(B, Q, Hh, D), (0, 2, 1, 3))  # (B,H,Q,D)
+def _launch_geometry(p: MSDAParams, launch: Launch):
+    """((row offset, Wp, slab rows, one-hot?) per level, total rows)."""
+    hws = [p.spatial_shapes[l] for l in launch.levels]
+    offs, total = pyramid_row_offsets(hws)
+    geoms = tuple(
+        (off, hw[1] + 2, slab_rows(hw),
+         bool(p.onehot_levels[l]) if p.onehot_levels else False)
+        for off, hw, l in zip(offs, hws, launch.levels))
+    return geoms, total
 
-    gvals, glocs, gattns = [], [], []
-    for l, hw in enumerate(p.spatial_shapes):
-        bq = p.block_q[l]
-        qpad = _round_up(Q, bq)
-        loc_l = _pad_q(loc_t[:, :, l], 2, qpad, 0.5)
-        attn_l = _pad_q(attn_t[:, :, l], 2, qpad, 0.0)
-        gout_l = _pad_q(gout_t, 2, qpad, 0.0)
-        saved_l = saved_all[l] if saved_all is not None else None
-        slab_l = slabs[l] if slabs is not None else None
-        gval, gloc, gattn = msda_bwd.msda_bwd_level(
-            slab_l,
-            loc_l,
-            attn_l,
-            gout_l,
-            saved_l,
-            hw=hw,
-            hwp_rows=slab_rows(hw),
-            block_q=bq,
-            fuse_scatter=p.fuse_scatter,
-            onehot_scatter=p.onehot_levels[l] if p.onehot_levels else False,
-            interpret=p.interpret,
-            accum_dtype=p.accum_dtype,
-        )
-        gvals.append(_unpad_grad(gval, hw))
-        glocs.append(gloc[:, :, :Q])
-        gattns.append(gattn[:, :, :Q])
 
-    gvalue = jnp.concatenate(gvals, axis=2)  # (B,H,S,D) accum dtype
-    gvalue = jnp.transpose(gvalue, (0, 2, 1, 3))
-    gloc = jnp.stack(glocs, axis=2)  # (B,H,L,Q,P,2)
-    gloc = jnp.transpose(gloc, (0, 3, 1, 2, 4, 5))  # (B,Q,H,L,P,2)
-    gattn = jnp.stack(gattns, axis=2)  # (B,H,L,Q,P)
-    gattn = jnp.transpose(gattn, (0, 3, 1, 2, 4))  # (B,Q,H,L,P)
-    return gvalue, gloc, gattn
+def _level_tables(p: MSDAParams, loc, attn, G: int, qmax: int):
+    """Corner rows and weights of every level: ``(idx, w)``.
+
+    Plain element-wise XLA, differentiable in ``loc`` / ``attn`` through
+    ``w``: JAX's autodiff of the bilinear weights is the backward's
+    grad-loc / grad-attn chain rule.  ``idx`` (L, B, H//G, G, P, qmax)
+    int32 holds the top-left corner row of every (point, query) in the
+    level's own padded slab; ``w`` (L, B, H//G, G, 4*P, qmax) the four
+    corner weights (corner-major) with the validity mask and the
+    attention weight folded in.  Queries past Q are zero-weight padding.
+
+    A scan over levels: the loop body (and its transpose) is compiled
+    apart from whatever a fusion tier does with the tables, so XLA
+    cannot fuse — and FMA-contract — the weight math differently per
+    tier (tier parity is bitwise).  The query axis is minor-most, so the
+    TPU layouts of the tables are dense and cheap to transpose.
+    """
+    B, Q, H, L, P, _ = loc.shape
+    NG = H // G
+    hw = jnp.asarray(p.spatial_shapes, jnp.int32)  # (L, 2)
+    loc5 = loc.astype(jnp.float32).reshape(B, Q, H, L, P * 2)
+
+    def query_minor(x, n):
+        # (B, Q, H, n) -> (B, NG, G, n, qmax), padded with zeros
+        x = jnp.transpose(x.reshape(B, Q, NG, G, n), (0, 2, 3, 4, 1))
+        return jnp.pad(x, ((0, 0),) * 4 + ((0, qmax - Q),))
+
+    def level(_, xs):
+        l, h, w = xs
+        xy = jax.lax.dynamic_index_in_dim(loc5, l, axis=3, keepdims=False)
+        a = jax.lax.dynamic_index_in_dim(attn, l, axis=3, keepdims=False)
+        a = a.astype(jnp.float32)
+        idx00, lx, ly, (m00, m10, m01, m11) = msda_fwd.corner_indices(
+            xy.reshape(B, Q, H, P, 2), h, w, w + 2)
+        wt = jnp.stack([
+            (1 - lx) * (1 - ly) * m00 * a,
+            lx * (1 - ly) * m10 * a,
+            (1 - lx) * ly * m01 * a,
+            lx * ly * m11 * a], axis=3)  # (B, Q, H, 4, P)
+        return None, (query_minor(idx00, P),
+                      query_minor(wt.reshape(B, Q, H, 4 * P), 4 * P))
+
+    _, (idx, w) = jax.lax.scan(
+        level, None, (jnp.arange(L), hw[:, 0], hw[:, 1]))
+    return idx, w
+
+
+def _flat_table(x: jax.Array) -> jax.Array:
+    """(B, NG, nq, ...) chunks -> the flat SMEM table, each (batch, head
+    group, query block) chunk padded to ``msda_fwd.table_block``."""
+    B, NG, nq = x.shape[:3]
+    x = x.reshape(B * NG * nq, -1)
+    pad = msda_fwd.table_block(x.shape[1]) - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad))).reshape(-1)
+
+
+def _chunked(x: jax.Array, block_q: int) -> jax.Array:
+    """(Ll, B, NG, G, n, Qp) level tables of one launch -> its flat SMEM
+    table, chunks laid out (head, level, n, query) — see
+    ``msda_fwd.idx_slot`` / ``w_slot``.  The transpose keeps the
+    query-block axis minor."""
+    Ll, B, NG, G, n, qp = x.shape
+    x = x.reshape(Ll, B, NG, G, n, qp // block_q, block_q)
+    return _flat_table(jnp.transpose(x, (1, 2, 5, 3, 0, 4, 6)))
+
+
+def _corner_tables(p: MSDAParams, loc, attn, head_dim: int):
+    """Per launch, the SMEM tables ``(idx, w)`` the kernels consume:
+    :func:`_level_tables` cut to the launch's levels and padded queries,
+    corner rows lifted by the levels' row offsets in the launch slab,
+    chunked by :func:`_chunked`.  Data movement only."""
+    B, Q, H, L, P, _ = loc.shape
+    G = msda_fwd.head_group(H, head_dim)
+    launches = plan_launches(p)
+    qmax = max(_round_up(Q, launch.block_q) for launch in launches)
+    idx_all, w_all = _level_tables(p, loc, attn, G, qmax)
+    idxs, ws = [], []
+    for launch in launches:
+        geoms, _ = _launch_geometry(p, launch)
+        lo, hi = launch.levels[0], launch.levels[-1] + 1
+        qp = _round_up(Q, launch.block_q)
+        offs = jnp.asarray([g[0] for g in geoms], jnp.int32)
+        idx = idx_all[lo:hi, ..., :qp] + offs[:, None, None, None, None, None]
+        idxs.append(_chunked(idx, launch.block_q))
+        ws.append(_chunked(w_all[lo:hi, ..., :qp], launch.block_q))
+    return tuple(idxs), tuple(ws)
+
+
+def _launch_slab(p: MSDAParams, launch: Launch, value_g, operand_dtype):
+    """The fp32 slab one launch keeps resident: one level's padded slab,
+    or the packed super-slab of a fused launch."""
+    dts = p.fused_slab_dtypes(operand_dtype)
+    if launch.packed:
+        k = len(launch.levels)
+        return _pack_pyramid(value_g, p.spatial_shapes[:k], dts[:k])
+    (l,) = launch.levels
+    start = sum(h * w for h, w in p.spatial_shapes[:l])
+    lvl = _pad_level(value_g, start, p.spatial_shapes[l])
+    return lvl.astype(dts[l]).astype(jnp.float32)
+
+
+def _corner_dtype(p: MSDAParams, launch: Launch, operand_dtype):
+    """Dtype the raw corners of a launch are kept in: the widest slab
+    dtype among its levels (every corner is already rounded to its own
+    level's dtype, so this is exact)."""
+    dts = p.fused_slab_dtypes(operand_dtype)
+    return max((jnp.dtype(dts[l]) for l in launch.levels),
+               key=lambda d: d.itemsize)
+
+
+def _unpack_grad(gslab, p: MSDAParams, launch: Launch, geoms):
+    """Grad slab of one launch -> per-level (B, NG, h*w, G*D) grads."""
+    return [_unpad_grad(gslab[:, :, off:off + rows], p.spatial_shapes[l])
+            for l, (off, _, rows, _) in zip(launch.levels, geoms)]
+
+
+def _kernel_gather(p: MSDAParams, dims, operand_dtype):
+    """Custom-VJP weighted corner gather ``(value, idxs, ws) -> out``.
+
+    ``dims`` = (B, S, H, D, Q, P) are static.  The forward runs the
+    gather kernel per launch (saving corners in train mode); the
+    backward runs the scatter kernel per launch (regathering corners
+    when none were saved), which also returns the weight-table grads.
+    """
+    B, S, H, D, Q, P = dims
+    G = msda_fwd.head_group(H, D)
+    NG = H // G
+    launches = plan_launches(p)
+
+    def grouped_value(value):
+        return jnp.transpose(value.reshape(B, S, NG, G * D), (0, 2, 1, 3))
+
+    def run_fwd(value, idxs, ws, save):
+        value_g = grouped_value(value)
+        out = jnp.zeros((B, NG, Q, G * D), jnp.float32)
+        slabs, saved = [], []
+        for launch, idx, w in zip(launches, idxs, ws):
+            geoms, _ = _launch_geometry(p, launch)
+            slab = _launch_slab(p, launch, value_g, operand_dtype)
+            o, s = msda_fwd.msda_gather(
+                slab, idx, w, levels=geoms, num_points=P, head_dim=D,
+                block_q=launch.block_q, fuse_gather=p.fuse_gather,
+                save_dtype=(_corner_dtype(p, launch, operand_dtype)
+                            if save else None),
+                interpret=p.interpret, vmem_limit=p.vmem_limit)
+            out = out + o[:, :, :Q]
+            slabs.append(slab)
+            saved.append(s)
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, H * D)
+        return out.astype(operand_dtype), (tuple(slabs), tuple(saved))
+
+    @jax.custom_vjp
+    def gather(value, idxs, ws):
+        return run_fwd(value, idxs, ws, save=False)[0]
+
+    def fwd(value, idxs, ws):
+        out, (slabs, saved) = run_fwd(value, idxs, ws, p.save_sampled)
+        # train plans keep the corners, inference plans the slabs
+        return out, (None if p.save_sampled else slabs,
+                     saved if p.save_sampled else None, idxs, ws)
+
+    def bwd(res, gout):
+        slabs, saved, idxs, ws = res
+        gout_g = jnp.transpose(
+            gout.astype(jnp.float32).reshape(B, Q, NG, G * D), (0, 2, 1, 3))
+        gvals, gws = [], []
+        for i, (launch, idx, w) in enumerate(zip(launches, idxs, ws)):
+            geoms, rows = _launch_geometry(p, launch)
+            qp = _round_up(Q, launch.block_q)
+            gslab, gw = msda_bwd.msda_scatter(
+                _pad_q(gout_g, 2, qp, 0.0), idx, w,
+                slabs[i] if saved is None else saved[i],
+                regather=saved is None, rows=rows, levels=geoms,
+                num_points=P, head_dim=D, block_q=launch.block_q,
+                fuse_scatter=p.fuse_scatter, interpret=p.interpret,
+                vmem_limit=p.vmem_limit)
+            gvals += _unpack_grad(gslab, p, launch, geoms)
+            gws.append(_flat_table(gw))
+        gvalue = jnp.concatenate(gvals, axis=2)  # (B,NG,S,G*D)
+        gvalue = jnp.transpose(gvalue, (0, 2, 1, 3)).reshape(B, S, H, D)
+        gidx = tuple(np.zeros(i.shape, jax.dtypes.float0) for i in idxs)
+        return gvalue.astype(operand_dtype), gidx, tuple(gws)
+
+    gather.defvjp(fwd, bwd)
+    return gather
 
 
 def build_kernel_op(p: MSDAParams):
-    """Custom-VJP executor for one committed kernel configuration.
+    """Differentiable executor for one committed kernel configuration:
+    ``op(value (B,S,H,D), loc (B,Q,H,L,P,2), attn (B,Q,H,L,P)) ->
+    (B,Q,H*D)``.
 
     Deliberately *uncached*: the bounded plan cache in
     ``repro.kernels.plan`` owns the lifetime of compiled ops (and its
-    ``clear_plans()`` hook lets long-lived serving processes drop them) —
-    the old unbounded ``lru_cache`` here leaked one op per distinct
-    config forever.
+    ``clear_plans()`` hook lets long-lived serving processes drop them).
     """
 
-    @jax.custom_vjp
     def op(value, loc, attn):
-        return _fwd_impl(p, value, loc, attn)[0]
+        B, S, H, D = value.shape
+        Q, P = loc.shape[1], loc.shape[4]
+        idxs, ws = _corner_tables(p, loc, attn, D)
+        return _kernel_gather(p, (B, S, H, D, Q, P), value.dtype)(
+            value, idxs, ws)
 
-    def fwd(value, loc, attn):
-        out, res = _fwd_impl(p, value, loc, attn)
-        return out, res
-
-    def bwd(res, gout):
-        slabs, saved_all, loc_t, attn_t = res
-        # grad_value must match the *operand* dtype, which a bf16-slab
-        # plan no longer shares with the residual slabs
-        vdt = p.io_dtype or (slabs[0] if slabs is not None else saved_all[0]).dtype
-        gvalue, gloc, gattn = _bwd_impl(p, res, gout)
-        return gvalue.astype(vdt), gloc.astype(loc_t.dtype), gattn.astype(attn_t.dtype)
-
-    op.defvjp(fwd, bwd)
-    return op
+    return jax.jit(op)
 
 
 def resolve_backend(backend: str) -> str:

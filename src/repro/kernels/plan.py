@@ -101,32 +101,45 @@ def _round_up(x: int, m: int) -> int:
 # device kind, so plans for larger-VMEM parts stop under-blocking)
 # --------------------------------------------------------------------------
 
-# substring of jax.Device.device_kind (lowercased) -> usable per-core bytes.
-# Conservative: leaves headroom for Mosaic spills and double-buffering.
+# substring of jax.Device.device_kind (lowercased) -> usable per-core bytes,
+# which plans also hand Mosaic as its scoped-VMEM limit.  TPU v5e: 128 MiB
+# of VMEM per TensorCore; 100 MiB is what the paper-width DETR plans were
+# compiled against (tests/test_tpu_compile.py), the rest is left to
+# Mosaic's internal scratch.  The other entries are conservative planning
+# budgets that no chip run here has checked.
 DEVICE_VMEM_BUDGETS: Tuple[Tuple[str, int], ...] = (
     ("v6", 64 * 2**20),  # trillium-class
     ("v5p", 64 * 2**20),
-    ("v5 lite", 32 * 2**20),
-    ("v5e", 32 * 2**20),
+    ("v5 lite", 100 * 2**20),
+    ("v5e", 100 * 2**20),
     ("v4", 32 * 2**20),
     ("v3", 16 * 2**20),
     ("v2", 16 * 2**20),
 )
-_FALLBACK_VMEM_BUDGET = 32 * 2**20  # CPU / interpret / unknown parts
+# the Pallas interpreter (CPU hosts) has no VMEM; plans there keep a
+# nominal budget so their tiling stays comparable across hosts
+_INTERPRET_VMEM_BUDGET = 32 * 2**20
 
 
 def default_vmem_budget(device_kind: Optional[str] = None) -> int:
-    """Usable VMEM bytes for block planning, by accelerator kind."""
+    """Usable VMEM bytes for block planning, by accelerator kind.
+
+    With no ``device_kind`` the first JAX device decides.  A TPU whose
+    kind is not in :data:`DEVICE_VMEM_BUDGETS` is an error: planning it
+    against a guessed budget would only fail later, in Mosaic, or waste
+    the part.  Non-TPU hosts (the interpreter) get a nominal budget.
+    """
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # no backend initialised yet
-            device_kind = "cpu"
+        device_kind = jax.devices()[0].device_kind
     kind = device_kind.lower()
     for sub, budget in DEVICE_VMEM_BUDGETS:
         if sub in kind:
             return budget
-    return _FALLBACK_VMEM_BUDGET
+    if "tpu" in kind:
+        raise ValueError(
+            f"no VMEM budget for TPU kind {device_kind!r}; add it to "
+            "repro.kernels.plan.DEVICE_VMEM_BUDGETS or set vmem_budget")
+    return _INTERPRET_VMEM_BUDGET
 
 
 # --------------------------------------------------------------------------
@@ -157,13 +170,16 @@ class MsdaSpec:
     adaptive_block: bool = True
     onehot_small_levels: bool = False
     # -- precision policy (the second planned axis) -----------------------
-    # slab_dtype: dtype the VMEM value slab is STORED in.  '' follows the
-    # operand dtype; 'auto' lets tune="autotune" race fp32 vs bf16 per
-    # level; any concrete dtype pins it (bf16 halves residency -> the
-    # planner widens block_q).  accum_dtype: the widened accumulator for
-    # fwd partial outputs and the bwd grad_value slab — kept fp32 so a
-    # bf16-slab plan is "bf16 storage, fp32 math", per DEFA's
-    # reduced-precision-sampling / wide-accumulation observation.
+    # slab_dtype: dtype each level's values are ROUNDED to before they
+    # enter the (always fp32) VMEM slab.  '' follows the operand dtype;
+    # 'auto' lets tune="autotune" race fp32 vs bf16 per level; any
+    # concrete dtype pins it.  The Pallas slab stays fp32 either way
+    # (bf16 rows cannot be addressed singly in a packed tile), so a bf16
+    # slab saves residency nowhere: it only halves a train plan's saved
+    # corners (and their per-query output block).  accum_dtype: the
+    # widened accumulator of the ``cpu`` executor and of the sharded
+    # grad_value reduction, which level_report sizes the grad slab by;
+    # the Pallas kernels always accumulate in fp32.
     slab_dtype: str = ""
     accum_dtype: str = "float32"
     # -- pyramid kernel fusion tiers (the third planned axis) -------------
@@ -251,6 +267,13 @@ class MsdaSpec:
     @property
     def accum_itemsize(self) -> int:
         return jnp.dtype(self.accum_dtype).itemsize
+
+    @property
+    def heads_per_launch(self) -> int:
+        """Heads one kernel launch holds side by side on its lanes."""
+        from repro.kernels import msda_fwd
+
+        return msda_fwd.head_group(self.num_heads, self.head_dim)
 
     def fuse_prefix_pin(self) -> int:
         """The k of a ``"prefix:k"`` fuse_levels pin, else 0."""
@@ -454,7 +477,7 @@ def _resolve_fuse_tier(spec: MsdaSpec, slab_dtypes: Tuple[str, ...],
         spec.spatial_shapes, spec.num_points, spec.head_dim,
         value_itemsize=_slab_itemsizes(slab_dtypes),
         train=spec.train, vmem_budget=spec.vmem_budget,
-        accum_itemsize=spec.accum_itemsize)
+        heads=spec.heads_per_launch)
     if k == L:
         return True, 0
     if k >= 2:
@@ -477,14 +500,14 @@ def _tier_block_q(spec: MsdaSpec, slab_dtypes: Tuple[str, ...],
         spec.spatial_shapes[:k], spec.num_points, spec.head_dim,
         spec.num_queries, value_itemsize=items[:k], train=spec.train,
         vmem_budget=spec.vmem_budget, adaptive=spec.adaptive_block,
-        accum_itemsize=spec.accum_itemsize, fused=True)
+        heads=spec.heads_per_launch, fused=True)
     bq = (pre[0],) * k
     for hw, it in zip(spec.spatial_shapes[k:], items[k:]):
         bq += (ops.plan_blocks(
             (hw,), spec.num_points, spec.head_dim, spec.num_queries,
             value_itemsize=it, train=spec.train,
             vmem_budget=spec.vmem_budget, adaptive=spec.adaptive_block,
-            accum_itemsize=spec.accum_itemsize)[0],)
+            heads=spec.heads_per_launch)[0],)
     return bq
 
 
@@ -520,10 +543,9 @@ def _build_pallas(spec: MsdaSpec, tuning: PlanTuning) -> Callable:
         interpret=tuning.interpret,
         onehot_levels=tuple(tuning.onehot_levels),
         slab_dtypes=tuple(tuning.slab_dtypes) or _default_slab_dtypes(spec),
-        accum_dtype=spec.accum_dtype,
-        io_dtype=spec.dtype,
         fuse_levels=bool(tuning.fuse_levels),
         fuse_prefix=int(tuning.fuse_prefix),
+        vmem_limit=spec.vmem_budget,
     )
     return ops.build_kernel_op(params)
 
@@ -555,7 +577,7 @@ def _heuristic_block_q(spec: MsdaSpec, *, fused: bool = False,
         train=spec.train,
         vmem_budget=spec.vmem_budget,
         adaptive=spec.adaptive_block,
-        accum_itemsize=spec.accum_itemsize,
+        heads=spec.heads_per_launch,
         fused=fused,
     )
 
@@ -576,7 +598,7 @@ def _blocks_for_slab_dtypes(spec: MsdaSpec, slab_dtypes: Tuple[str, ...]) -> Tup
             train=spec.train,
             vmem_budget=spec.vmem_budget,
             adaptive=spec.adaptive_block,
-            accum_itemsize=spec.accum_itemsize,
+            heads=spec.heads_per_launch,
         )[0])
     return tuple(out)
 
@@ -1085,26 +1107,23 @@ def _autotune_plan(
 
     def race(variants: Dict[Any, tuple], timed="fwd"):
         """Interleave-time variants {key: (bq, dts[, oh[, fused[,
-        prefix]]])}; unbuildable candidates drop out."""
-        fns = {}
+        prefix]]])}; a candidate that fails to build drops out, and if
+        every one fails the race raises with the compiler's message —
+        never a silently untimed plan."""
+        fns, errors = {}, []
         for k, v in variants.items():
             try:
                 fns[k] = get_fn(*v, timed=timed)
-            except Exception:
-                continue  # candidate doesn't fit/compile: skip
+            except Exception as e:  # candidate doesn't fit/compile: skip
+                errors.append(f"{k}: {type(e).__name__}: {e}")
         if not fns:
-            return None, {}
+            raise RuntimeError(
+                f"autotune: no candidate of {spec} builds on backend "
+                f"{backend_name!r}:\n" + "\n".join(errors))
         times = _time_executors(fns, args)
         return min(times, key=times.get), times
 
-    bkey, _ = race({c: (c, base_dts) for c in candidates})
-    if bkey is None:
-        # every candidate failed to build: fall back to the heuristic and
-        # do NOT persist — a never-validated plan must not poison the
-        # per-device winner cache for future processes
-        return (heur, base_dts, onehot, False, 0, _resolve_sparsity(spec),
-                _resolve_query_order(spec), "heuristic")
-    best = bkey
+    best, _ = race({c: (c, base_dts) for c in candidates})
 
     best_dts = base_dts
     if race_dtypes:
@@ -1115,9 +1134,8 @@ def _autotune_plan(
         wide, narrow = (str(jnp.dtype(d)) for d in _SLAB_DTYPE_CANDIDATES)
         current = (wide,) * spec.num_levels
         # per-level flips even under a fused pin: the packed super-slab
-        # keeps each level's committed dtype (carrier-coded when they
-        # mix — see ops.packed_pyramid_layout), so a bf16-winner level
-        # keeps its residency win inside the fused launch
+        # rounds each level to its own committed dtype (see
+        # ops._pack_pyramid)
         for ls in [(l,) for l in range(spec.num_levels)]:
             trial = tuple(narrow if l in ls else d
                           for l, d in enumerate(current))
@@ -1164,7 +1182,7 @@ def _autotune_plan(
         # model's k.  Every challenger runs at its OWN geometry (shared
         # prefix block planned against the packed residency, per-level
         # tail blocks) with the COMMITTED per-level slab dtypes (the
-        # carrier-coded super-slab keeps mixed commitments).  Timed
+        # packed super-slab rounds each level to its own).  Timed
         # fwd+VJP for train specs — the backward is where fusion
         # changes launch count and gout streaming the most.
         from repro.kernels import ops
@@ -1173,7 +1191,7 @@ def _autotune_plan(
             spec.spatial_shapes, spec.num_points, spec.head_dim,
             value_itemsize=_slab_itemsizes(best_dts),
             train=spec.train, vmem_budget=spec.vmem_budget,
-            accum_itemsize=spec.accum_itemsize)
+            heads=spec.heads_per_launch)
         timed = "train" if spec.train else "fwd"
         full_bq = _tier_block_q(spec, best_dts, 0)
         tier_bqs = {"fused": (full_bq, 0)}
@@ -1482,16 +1500,6 @@ def _autotune_grad_reduce(spec: MsdaSpec, backend_name: str, mesh,
 # --------------------------------------------------------------------------
 
 
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _mesh_cache_key(mesh) -> Optional[tuple]:
     if mesh is None:
         return None
@@ -1635,6 +1643,29 @@ def _resolve_grad_reduce(grad_reduce: str, mode: str, tp_size: int) -> str:
 
 def _build_sharded_exec(spec, inner_exec, inner_spec, mesh, mode, dp, tp,
                         tp_size: int, grad_reduce: str):
+    """The shard_map-wired executor of a mesh-carrying plan.  The batch
+    is split over the data axes when they divide it; a batch they do not
+    divide (one image on a 2x2 mesh) is replicated over them instead —
+    the spec carries no batch size, so this is decided per call."""
+    from repro.sharding import rules
+
+    batch_ways = (int(mesh.devices.size) // int(tp_size)
+                  if mode == "batchquery" else rules.axis_size(dp, mesh))
+    built: Dict[bool, Callable] = {}
+
+    def exec_(v, l, a):
+        split = v.shape[0] % batch_ways == 0
+        if split not in built:
+            built[split] = _sharded_op(spec, inner_exec, inner_spec, mesh,
+                                       mode, dp, tp, tp_size, grad_reduce,
+                                       split_batch=split)
+        return built[split](v, l, a)
+
+    return exec_
+
+
+def _sharded_op(spec, inner_exec, inner_spec, mesh, mode, dp, tp,
+                tp_size: int, grad_reduce: str, *, split_batch: bool):
     from repro.sharding import rules
 
     from jax.sharding import Mesh, PartitionSpec as P
@@ -1667,15 +1698,17 @@ def _build_sharded_exec(spec, inner_exec, inner_spec, mesh, mode, dp, tp,
         # transpose psum when grad_reduce="psum" — the TPU-idiomatic
         # realisation of the paper's staggered scatter (contention
         # eliminated via partial accumulators + reduction).
-        vspec = P(dp, None, None, None)
-        qspec = P(dp, tp, None, None, None, None)
-        wspec = P(dp, tp, None, None, None)
-        ospec = P(dp, tp, None)
+        bdp = dp if split_batch else None
+        vspec = P(bdp, None, None, None)
+        qspec = P(bdp, tp, None, None, None, None)
+        wspec = P(bdp, tp, None, None, None)
+        ospec = P(bdp, tp, None)
     else:
-        vspec = P(dp, None, tp, None)
-        qspec = P(dp, None, tp, None, None, None)
-        wspec = P(dp, None, tp, None, None)
-        ospec = P(dp, None, tp)
+        bdp = dp if split_batch else None
+        vspec = P(bdp, None, tp, None)
+        qspec = P(bdp, None, tp, None, None, None)
+        wspec = P(bdp, None, tp, None, None)
+        ospec = P(bdp, None, tp)
 
     Hd = inner_spec.num_heads * inner_spec.head_dim
 
@@ -1683,7 +1716,8 @@ def _build_sharded_exec(spec, inner_exec, inner_spec, mesh, mode, dp, tp,
         out = inner_exec(v, l, a)
         return out.reshape(l.shape[0], l.shape[1], Hd)
 
-    fwd_sharded = _shard_map_compat(run, mesh, (vspec, qspec, wspec), ospec)
+    fwd_sharded = jax.shard_map(run, mesh=mesh, in_specs=(vspec, qspec, wspec),
+                                  out_specs=ospec, check_vma=False)
     reduce = _resolve_grad_reduce(grad_reduce, mode, tp_size)
     if reduce == "none":
         return fwd_sharded
@@ -1722,8 +1756,9 @@ def _build_sharded_exec(spec, inner_exec, inner_spec, mesh, mode, dp, tp,
             gv = jax.lax.psum(gv, dp_axes)
         return gv.astype(vdt), gl, ga
 
-    bwd_sharded = _shard_map_compat(
-        bwd_shard, mesh, (vspec, qspec, wspec, ospec), (vspec, qspec, wspec))
+    bwd_sharded = jax.shard_map(
+        bwd_shard, mesh=mesh, in_specs=(vspec, qspec, wspec, ospec),
+        out_specs=(vspec, qspec, wspec), check_vma=False)
 
     @jax.custom_vjp
     def op(v, l, a):
@@ -1859,12 +1894,11 @@ class MsdaPlan:
             for l in range(s.num_levels))
         items = _slab_itemsizes(resolved)
         k = self.fuse_prefix  # 0 per-level, L whole-pyramid, else the tier
+        heads = s.heads_per_launch
         prefix_resident = 0
         if k:
             prefix_resident = ops.fused_resident_bytes(
-                s.spatial_shapes[:k], s.head_dim,
-                slab_itemsize=items[:k], train=s.train,
-                accum_itemsize=s.accum_itemsize)
+                s.spatial_shapes[:k], s.head_dim, heads=heads)
         # what the occupancy model would have picked on its own, so the
         # report carries predicted-vs-committed occupancy per level (a
         # raced/overridden block plan can land far from the model)
@@ -1881,8 +1915,10 @@ class MsdaPlan:
                 # no resident slabs — report what actually executes
                 sdt = "float32"
             in_prefix = l < k
+            # the level's slab per head in its committed dtype (+ the
+            # fp32 grad slab of a train plan): its HBM footprint
             slab_bytes = slab * s.head_dim * jnp.dtype(sdt).itemsize
-            if s.train:  # widened (accum-dtype) grad slab rides along
+            if s.train:
                 slab_bytes += slab * s.head_dim * s.accum_itemsize
             bq = self.tuning.block_q[l] if l < len(self.tuning.block_q) else 0
             # the fused prefix's per-step working set is sized by its
@@ -1893,8 +1929,10 @@ class MsdaPlan:
             per_q = ops.per_query_bytes(
                 s.num_points, s.head_dim, train=s.train,
                 slab_itemsize=step_item,
-                levels=k if in_prefix else 1)
-            resident = prefix_resident if in_prefix else slab_bytes
+                levels=k if in_prefix else 1, heads=heads)
+            resident = (prefix_resident if in_prefix
+                        else ops.resident_bytes(slab, s.head_dim,
+                                                heads=heads))
             occupancy = (resident + bq * per_q) / max(s.vmem_budget, 1)
             pred_bq = heur_bq[l] if l < len(heur_bq) else bq
             predicted = (resident + pred_bq * per_q) / max(s.vmem_budget, 1)
@@ -2100,6 +2138,10 @@ class MsdaPlan:
             per-level dense, non-ref backend   ->  the "ref" oracle
             ref                                ->  None (nothing below the oracle)
 
+        A plan whose Pallas kernels are compiled (``interpret=False``:
+        a TPU) has no oracle rung — falling back to XLA there would hide
+        a failing device kernel; its per-level rung is the bottom.
+
         Built RACE-FREE from the existing spec: the demoted plan pins
         the axes it drops (``sparsity="off"``, ``query_order=
         "identity"``, ``fuse_levels="off"``) and is constructed with
@@ -2123,7 +2165,8 @@ class MsdaPlan:
             ns = dataclasses.replace(s, sparsity="off", query_order="identity",
                                      fuse_levels="off")
             backend = self.backend
-        elif self.backend != "ref":
+        elif self.backend != "ref" and not (self.backend == "pallas"
+                                            and not self.tuning.interpret):
             ns = dataclasses.replace(s, sparsity="off", query_order="identity",
                                      fuse_levels="off")
             backend = "ref"
@@ -2135,7 +2178,8 @@ class MsdaPlan:
 
     def fallback_chain(self, *, mesh=None) -> Tuple["MsdaPlan", ...]:
         """Every rung below this plan, top to bottom (ends at the ref
-        oracle; empty for a plan already on the bottom rung)."""
+        oracle, or at per-level Pallas for compiled kernels; empty for a
+        plan already on the bottom rung)."""
         chain: List[MsdaPlan] = []
         p = self.fallback(mesh=mesh)
         while p is not None:

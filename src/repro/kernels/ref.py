@@ -112,7 +112,10 @@ def msda_ref(
             + v01 * w01[..., None]
             + v11 * w11[..., None]
         )  # (B,Q,H,P,D)
-        out = out + jnp.einsum("bqhpd,bqhp->bqhd", sampled, attn[:, :, :, l])
+        # HIGHEST: an fp32 oracle on every backend (TPU's default fp32
+        # matmul rounds its operands to bf16)
+        out = out + jnp.einsum("bqhpd,bqhp->bqhd", sampled, attn[:, :, :, l],
+                               precision=jax.lax.Precision.HIGHEST)
     return out.reshape(B, Q, H * D).astype(out_dtype)
 
 

@@ -345,9 +345,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, verbose: bool = True
         memd[attr] = getattr(mem, attr, None)
 
     # roofline terms (per-chip HLO numbers vs per-chip peaks)
-    t_compute = ana["flops"] / mesh_lib.PEAK_FLOPS_BF16
-    t_memory = ana["mem_bytes"] / mesh_lib.HBM_BW
-    t_coll = ana["collectives"]["wire_bytes"] / mesh_lib.ICI_BW
+    pk = mesh_lib.peaks(mesh.devices.flat[0].device_kind)
+    t_compute = ana["flops"] / pk["flops_bf16"]
+    t_memory = ana["mem_bytes"] / pk["hbm_bw"]
+    t_coll = ana["collectives"]["wire_bytes"] / pk["ici_bw"]
     mflops = model_flops(cfg, shape)
     cell.update(
         status="ok",

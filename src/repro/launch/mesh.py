@@ -14,21 +14,43 @@ from __future__ import annotations
 
 import jax
 
-# TPU v5e-class hardware constants used by the roofline (per chip).
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
+# Per-chip peaks for the roofline, keyed by jax.Device.device_kind.
+# TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of inter-chip
+# interconnect (per link: 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of ``device_kind``; a kind not in :data:`PEAKS` is an error,
+    not a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak figures for device kind {device_kind!r}; add them, "
+            "with their source, to repro.launch.mesh.PEAKS") from None
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the MSDA plans and rules.hint shard through shard_map and
+    # with_sharding_constraint, which Explicit axes (jax.make_mesh's
+    # default) would turn into assertions
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh():
     """1-device mesh with production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def parse_mesh_shape(token: str):

@@ -57,18 +57,18 @@ def serving_smoke(arch: str, store_path: str, compile_cache_dir: str,
     from repro.serving import aot, persistence
 
     # enable the compilation cache BEFORE any compile (params init
-    # included) so both boots persist/hit the same entry set
-    cache_on = persistence.enable_jax_compilation_cache(compile_cache_dir)
-    assert cache_on, "persistent compilation cache failed to enable"
+    # included) so both boots persist/hit the same entry set; counted in
+    # the directory in effect (JAX_COMPILATION_CACHE_DIR wins)
+    cache_dir = persistence.enable_jax_compilation_cache(compile_cache_dir)
     warm = persistence.PlanStore(store_path).exists()
-    cache0 = persistence.compilation_cache_entries(compile_cache_dir)
+    cache0 = persistence.compilation_cache_entries(cache_dir)
     plan_mod.reset_autotune_stats()
     aot.reset_stats()
 
     cfg = reduced(get_config(arch))
     params = train_state.init_model(jax.random.PRNGKey(0), cfg)
     eng = ServeEngine(cfg, params, slots=slots, capacity=capacity,
-                      store_path=store_path, compile_cache_dir=compile_cache_dir,
+                      store_path=store_path, compile_cache_dir=cache_dir,
                       dtype_policy="auto", tune="autotune")
     eng.warmup(prompt_lengths=(4,))
     boot_tune = plan_mod.autotune_stats()
@@ -101,7 +101,7 @@ def serving_smoke(arch: str, store_path: str, compile_cache_dir: str,
         "request_traces": probe.traces,
         "request_compiles": probe.compiles,
         "new_xla_cache_entries":
-            persistence.compilation_cache_entries(compile_cache_dir) - cache0,
+            persistence.compilation_cache_entries(cache_dir) - cache0,
         "completed": [len(r.out) for r in reqs],
         # aot probe counters ride inside the metrics dict so zero-retrace
         # is auditable from the uploaded artifact, not just the asserts
@@ -394,7 +394,9 @@ def main() -> None:
                     help="plan-store path: warm boots restore every plan "
                          "with zero autotune races")
     ap.add_argument("--compile-cache", default=None,
-                    help="JAX persistent compilation cache directory")
+                    help="JAX persistent compilation cache directory "
+                         "(JAX_COMPILATION_CACHE_DIR, when set, wins; "
+                         "default: .jax_cache/ at the checkout root)")
     ap.add_argument("--dtype-policy", default=None,
                     choices=("follow", "float32", "bfloat16", "auto"))
     ap.add_argument("--tune", default=None, choices=("heuristic", "autotune"))
@@ -436,12 +438,18 @@ def main() -> None:
             obs.disable_trace()
             print(f"[serve] trace -> {args.trace_out}")
 
+    from repro.serving import persistence
+
+    # one cache rule for every mode, before the first compile
+    cache_dir = persistence.enable_jax_compilation_cache(args.compile_cache)
+    print(f"[serve] compilation cache: {cache_dir}")
+
     if args.serving_smoke:
-        if not (args.store and args.compile_cache):
-            ap.error("--serving-smoke needs --store and --compile-cache")
+        if not args.store:
+            ap.error("--serving-smoke needs --store")
         try:
             serving_smoke(args.arch or "phi-3-vision-4.2b", args.store,
-                          args.compile_cache,
+                          cache_dir,
                           slots=args.slots or 2, capacity=args.capacity or 64)
         finally:
             _export()
@@ -479,7 +487,7 @@ def main() -> None:
     eng = ServeEngine(cfg, params, slots=args.slots or 4,
                       capacity=args.capacity or 128,
                       temperature=args.temperature, store_path=args.store,
-                      compile_cache_dir=args.compile_cache,
+                      compile_cache_dir=cache_dir,
                       dtype_policy=args.dtype_policy, tune=args.tune,
                       mesh=mesh)
     rng = np.random.default_rng(0)

@@ -23,13 +23,17 @@ import argparse
 import os
 import tempfile
 import time
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config, reduced
 from repro.data.pipeline import DataConfig, Pipeline
 from repro.launch.mesh import make_mesh_2d, parse_mesh_shape
+from repro.serving import persistence
+from repro.sharding import rules
 from repro.train import loop as train_loop
 from repro.train import state as train_state
 from repro.training import (
@@ -60,10 +64,10 @@ def _tokens_per_step(cfg, args) -> int:
     return args.batch * args.seq
 
 
-def _warm_plans(cfg, mesh, recorder, plan_store: str) -> None:
+def _warm_plans(cfg, mesh, recorder, plan_store: str) -> Dict[str, Any]:
     """Commit MSDA plans before the first trace; elastic via the store."""
     if cfg.msda is None:
-        return
+        return {}
     from repro.core import deformable_transformer as dt
     from repro.kernels import plan as plan_mod
     from repro.serving.persistence import PlanStore
@@ -85,25 +89,49 @@ def _warm_plans(cfg, mesh, recorder, plan_store: str) -> None:
             meta={"writer": "launch.train",
                   "mesh": None if mesh is None else plan_mod.mesh_token(mesh)})
         print(f"[train] plan store: persisted {n} plans -> {plan_store}")
+    return plans
 
 
 def _build_harness(cfg, args, mesh, recorder, faults=None,
                    ckpt_dir=None, total_steps=None) -> TrainingHarness:
+    """The training loop over ``cfg``.  With a ``mesh`` the step is
+    traced under it (the MSDA plans shard their launches over it), the
+    state is replicated and the batch split over the data axis."""
     pipe = Pipeline(_data_config(cfg, args))
     steps = total_steps if total_steps is not None else args.steps
-    step_fn = jax.jit(
-        train_loop.make_train_step(
-            cfg, num_microbatches=args.microbatches, peak_lr=args.lr,
-            warmup_steps=max(steps // 10, 1), total_steps=steps,
-        ),
-        donate_argnums=(0,),
+    step = train_loop.make_train_step(
+        cfg, num_microbatches=args.microbatches, peak_lr=args.lr,
+        warmup_steps=max(steps // 10, 1), total_steps=steps,
     )
+    put_state = put_batch = lambda t: t  # noqa: E731
+    jit_kw = {}
+    if mesh is not None:
+        replicated = NamedSharding(mesh, P())
+        # a batch the data axis does not divide is replicated instead
+        by_data = (NamedSharding(mesh, P("data"))
+                   if args.batch % dict(mesh.shape)["data"] == 0
+                   else replicated)
+        put_state = lambda t: jax.device_put(t, replicated)  # noqa: E731
+        put_batch = lambda t: jax.device_put(t, by_data)  # noqa: E731
+        # the loop feeds each step's state to the next: pin it replicated
+        shapes = jax.eval_shape(lambda: train_state.init_state(
+            jax.random.PRNGKey(args.seed), cfg))
+        jit_kw["out_shardings"] = (
+            jax.tree.map(lambda _: replicated, shapes), replicated)
+    jitted = jax.jit(step, donate_argnums=(0,), **jit_kw)
+
+    def step_fn(state, batch):
+        return _traced(mesh, jitted, state, batch)
+
+    step_fn.lower = lambda *a: _traced(mesh, jitted.lower, *a)
 
     def batch_fn(step: int):
-        return {k: jnp.asarray(v) for k, v in pipe.batch(step).items()}
+        return put_batch(
+            {k: jnp.asarray(v) for k, v in pipe.batch(step).items()})
 
     def init_fn():
-        return train_state.init_state(jax.random.PRNGKey(args.seed), cfg)
+        return put_state(
+            train_state.init_state(jax.random.PRNGKey(args.seed), cfg))
 
     hcfg = HarnessConfig(
         total_steps=steps, ckpt_every=args.ckpt_every,
@@ -112,6 +140,27 @@ def _build_harness(cfg, args, mesh, recorder, faults=None,
     return TrainingHarness(step_fn=step_fn, batch_fn=batch_fn,
                            init_fn=init_fn, config=hcfg, faults=faults,
                            telemetry=recorder)
+
+
+def _traced(mesh, fn, *args):
+    if mesh is None:
+        return fn(*args)
+    with rules.use_mesh(mesh):
+        return fn(*args)
+
+
+def compile_step(harness: TrainingHarness):
+    """AOT-compile the harness's step for its state and first batch and
+    install the executable: ``(compiled, seconds)``.  The compiled
+    program is what a caller inspects (``memory_analysis()``,
+    ``as_text()``); the loop then runs it without a second compile."""
+    state = harness.init_fn()
+    batch = harness.batch_fn(0)
+    t0 = time.perf_counter()
+    compiled = harness.step_fn.lower(state, batch).compile()
+    seconds = time.perf_counter() - t0
+    harness.step_fn = compiled
+    return compiled, seconds
 
 
 def _parse_faults(args) -> "FaultSchedule | None":
@@ -123,7 +172,7 @@ def _parse_faults(args) -> "FaultSchedule | None":
     return None
 
 
-def main() -> None:
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deformable-detr")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -160,10 +209,18 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None,
                     help="dump the obs metrics registry at exit "
                          "(.json -> JSON, else Prometheus text)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(argv=None) -> Dict[str, Any]:
+    """The training driver, in-process: parse ``argv``, train, return
+    what happened — the config, the committed MSDA plans, the compiled
+    step (with its compile seconds), per-step seconds and losses."""
+    args = parse_args(argv)
     from repro import obs
 
+    cache_dir = persistence.enable_jax_compilation_cache()
+    print(f"[train] compilation cache: {cache_dir}")
     if args.trace_out:
         obs.enable_trace(args.trace_out, level=args.trace_level)
 
@@ -179,7 +236,7 @@ def main() -> None:
             train_smoke(args)
         finally:
             _export()
-        return
+        return {}
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -190,9 +247,11 @@ def main() -> None:
         config={"arch": args.arch, "smoke": bool(args.smoke),
                 "steps": args.steps, "batch": args.batch,
                 "mesh": args.mesh, "seed": args.seed})
-    _warm_plans(cfg, mesh, recorder, args.plan_store)
+    plans = _warm_plans(cfg, mesh, recorder, args.plan_store)
     harness = _build_harness(cfg, args, mesh, recorder,
                              faults=_parse_faults(args))
+    compiled, compile_s = compile_step(harness)
+    print(f"[train] step compiled in {compile_s:.1f}s")
     t0 = time.time()
     out = harness.run()
     dt_s = time.time() - t0
@@ -214,6 +273,15 @@ def main() -> None:
         path = recorder.write(args.bench_out)
         print(f"[train] wrote telemetry -> {path}")
     _export()
+    return {"cfg": cfg, "mesh": mesh, "plans": plans, "compiled": compiled,
+            "compile_seconds": compile_s, "losses": losses,
+            "step_seconds": [r["wall_s"] for r in recorder.steps],
+            "final_step": out["final_step"], "restarts": out["restarts"],
+            "cache_dir": cache_dir}
+
+
+def main() -> None:
+    run()
 
 
 # --------------------------------------------------------------------------

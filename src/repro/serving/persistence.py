@@ -31,6 +31,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.kernels import plan as plan_mod
 from repro.obs import trace as _obs_trace
@@ -416,32 +417,48 @@ class PlanStore:
 # --------------------------------------------------------------------------
 
 
-def enable_jax_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+# the checkout root (src/repro/serving/persistence.py -> three levels up)
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def compilation_cache_dir(requested: Optional[str] = None) -> str:
+    """Where JAX's persistent compilation cache lives, by one rule.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins over everything (JAX
+    reads it itself; ``requested`` cannot override it).  Otherwise the
+    ``requested`` directory (a launcher's ``--compile-cache``), else the
+    fixed ``.jax_cache/`` at the checkout root — fixed, because the
+    path is part of every entry's key and a moving directory never hits.
+    """
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return env
+    return requested or os.path.join(CHECKOUT_ROOT, ".jax_cache")
+
+
+def enable_jax_compilation_cache(requested: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
     Thresholds are zeroed so even the CPU tier's fast compiles persist
-    (the default min-compile-time gate would skip them, and the smoke
-    job's no-recompilation assertion needs every executable cached).
-    Best-effort: an old jax without the knobs just serves cold.
+    (the default min-compile-time gate would skip them, and the serving
+    smoke's no-recompilation assertion needs every executable cached).
+    Call it before the first compile; any failure raises.
     """
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return False
-    try:
-        # jax latches cache initialisation at the process's FIRST compile
-        # and never re-reads the dir config: a boot that compiled anything
-        # (params init!) before reaching here would silently cache nothing.
-        # Drop the latched (empty-dir) state so the next compile re-reads.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass  # private API moved: processes that set the dir early still cache
-    return True
+    cache_dir = compilation_cache_dir(requested)
+    os.makedirs(cache_dir, exist_ok=True)
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # jax latches cache initialisation at the process's FIRST compile and
+    # never re-reads the dir config: a boot that compiled anything (params
+    # init!) before reaching here would silently cache nothing.  Drop the
+    # latched state so the next compile re-reads it.
+    compilation_cache.reset_cache()
+    return cache_dir
 
 
 def compilation_cache_entries(cache_dir: str) -> int:

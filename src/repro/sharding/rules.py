@@ -41,10 +41,7 @@ def use_mesh(mesh: Mesh):
     """Install a mesh for spec resolution + sharding hints."""
     tok = _MESH.set(mesh)
     try:
-        # jax.set_mesh is the >=0.6 spelling; older jax uses the Mesh
-        # object itself as the ambient-mesh context manager.
-        setter = getattr(jax, "set_mesh", None)
-        with (setter(mesh) if setter is not None else mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH.reset(tok)

@@ -30,10 +30,10 @@ Tolerance tiers (documented, per dtype policy):
 
 Fusion tiers add **no tolerance of their own** — the same per-policy
 tiers above apply to every ``fuse`` variant, mixed-dtype prefixes
-included.  The packed super-slab is carrier-coded (an unsigned-int
-carrier moves each level's committed bytes verbatim, uniform slabs
-keep their float dtype), so a fused-prefix plan reads bit-identical
-level data to the per-level plan under the same dtype policy: the only
+included.  The packed super-slab rounds each level to its own
+committed dtype and stores it in fp32 (exact), so a fused-prefix plan
+reads bit-identical level data to the per-level plan under the same
+dtype policy: the only
 numeric difference between tiers is gather order inside one fp32
 accumulation, which the fp32 reassociation tier already budgets for.
 

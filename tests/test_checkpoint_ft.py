@@ -3,6 +3,7 @@ import os
 import time
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -60,7 +61,8 @@ def test_elastic_restore_resharded(tmp_path):
 
     s = _state()
     ckpt.save(s, str(tmp_path), 1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), s)
     r = ckpt.restore(str(tmp_path), s, shardings=sh)
     assert r["params"]["w"].sharding.mesh.shape == {"data": 1, "model": 1}

@@ -20,6 +20,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 import numpy as np
 import pytest
 
@@ -71,11 +72,11 @@ def count_pallas_calls(fn, *args) -> int:
         return n
 
     def _jaxprs_of(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             return [v.jaxpr]
-        if hasattr(v, "jaxpr") and isinstance(getattr(v, "jaxpr", None), jax.core.Jaxpr):
+        if hasattr(v, "jaxpr") and isinstance(getattr(v, "jaxpr", None), jex_core.Jaxpr):
             return [v.jaxpr]
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, jex_core.Jaxpr):
             return [v]
         if isinstance(v, (list, tuple)):
             return [j for item in v for j in _jaxprs_of(item)]
@@ -124,8 +125,9 @@ def test_fused_onehot_routing_matches():
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(ref), atol=2e-5)
     # mixed routing: level 0 VPU, level 1 MXU (hand-pinned via params)
     params = ops.MSDAParams(
-        spatial_shapes=LEVELS, block_q=(24, 24), save_sampled=False,
-        onehot_levels=(False, True), fuse_levels=True, io_dtype="float32")
+        spatial_shapes=LEVELS, block_q=(24, 24), interpret=True,
+        save_sampled=False,
+        onehot_levels=(False, True), fuse_levels=True)
     out_m = ops.build_kernel_op(params)(value, loc, attn)
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(ref), atol=2e-5)
 
@@ -336,10 +338,13 @@ def test_grad_reduce_race_persists_per_topology(tmp_path, monkeypatch):
 
 
 def test_train_occupancy_counts_saved_corner_block():
-    # per-query bytes must grow by the (4P, D) slab-dtype corner rows
+    # per-query bytes must grow by the double-buffered (4P, lanes)
+    # slab-dtype corner rows, the weight grads and phase 1's fp32 corners
     base = ops.per_query_bytes(P, D)
     train = ops.per_query_bytes(P, D, train=True, slab_itemsize=4)
-    assert train == base + 4 * P * D * 4
+    lanes = ops.lane_width(1, D)
+    assert train == (base + 2 * 4 * P * lanes * 4 + 2 * 4 * P * 4
+                     + 4 * P * lanes * 4)
     # and the planner therefore never gives a train plan MORE queries
     # per step than the equivalent inference plan
     shapes = ((64, 64), (32, 32))

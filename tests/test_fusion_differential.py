@@ -5,14 +5,16 @@ per-level executor (fuse off) is the reference, and EVERY fusion tier
 of the same configuration — each strict prefix 1 <= k < L and the
 whole-pyramid launch — must reproduce its forward output and full VJP
 (value, loc, attn) **bitwise** in fp32.  No tolerances: the packed
-super-slab is carrier-coded, so a fused tier reads bit-identical level
-data and accumulates in the same order per level.
+super-slab rounds each level to its own committed dtype, so a fused
+tier reads bit-identical level data and accumulates in the same order
+per level.
 
 The sweep varies everything the packing logic branches on:
 
 * pyramid depth 1..5 with irregular level shapes,
 * committed per-level slab dtypes — uniform fp32 AND mixed
-  fp32/bfloat16 (the carrier-coded super-slab's reason to exist),
+  fp32/bfloat16 (each level rounded to its own dtype in the packed
+  super-slab),
 * sampling locations straddling the [0, 1] border (masked corners),
 * both residual modes — train-style saved corners (``save_sampled``)
   and the inference regather path.
@@ -33,6 +35,7 @@ oracle with minimised random cases on top.
 """
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 import numpy as np
 import pytest
 
@@ -101,9 +104,9 @@ def _params(case, fused, prefix):
     L = len(case["shapes"])
     bq = -(-case["Q"] // 8) * 8
     return ops.MSDAParams(
-        spatial_shapes=case["shapes"], block_q=(bq,) * L,
+        spatial_shapes=case["shapes"], block_q=(bq,) * L, interpret=True,
         fuse_levels=fused, fuse_prefix=prefix,
-        save_sampled=case["save_sampled"], io_dtype="float32",
+        save_sampled=case["save_sampled"],
         slab_dtypes=tuple(case["dtypes"]))
 
 
@@ -154,12 +157,12 @@ def count_pallas_calls(fn, *args) -> int:
         return n
 
     def _jaxprs_of(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             return [v.jaxpr]
         if hasattr(v, "jaxpr") and isinstance(getattr(v, "jaxpr", None),
-                                              jax.core.Jaxpr):
+                                              jex_core.Jaxpr):
             return [v.jaxpr]
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, jex_core.Jaxpr):
             return [v]
         if isinstance(v, (list, tuple)):
             return [j for item in v for j in _jaxprs_of(item)]
@@ -193,8 +196,8 @@ def test_sweep_covers_the_interesting_axes():
 
 
 def test_mixed_dtype_prefix_pinpoint():
-    """The exact configuration the carrier encoding exists for, pinned
-    rather than drawn: a bf16 level INSIDE an fp32 prefix, strict tier,
+    """The mixed-dtype packing's hardest case, pinned rather than
+    drawn: a bf16 level INSIDE an fp32 prefix, strict tier,
     both residual modes."""
     for save in (False, True):
         _assert_tiers_bitwise({
@@ -251,8 +254,8 @@ def test_mutated_packed_slab_breaks_parity(monkeypatch):
     # masked weight would null the perturbation
     row = 1 * (case["shapes"][0][1] + 2) + 1
 
-    def tampered(value_t, spatial_shapes, dtype=None, dtypes=()):
-        slab = orig(value_t, spatial_shapes, dtype=dtype, dtypes=dtypes)
+    def tampered(value_t, spatial_shapes, dtypes):
+        slab = orig(value_t, spatial_shapes, dtypes)
         return slab.at[0, 0, row, 0].add(jnp.asarray(1e-3, slab.dtype))
 
     monkeypatch.setattr(ops, "_pack_pyramid", tampered)

@@ -111,29 +111,33 @@ def _round_up8(x):
 @settings(**SET)
 def test_planned_block_q_respects_vmem_model(args):
     """For random specs — TRAIN ones included — heuristic block_q stays
-    sublane(8)-aligned, never exceeds the query extent or the 2048 cap,
-    and under the slab-bytes model never exceeds vmem_budget (unless
-    already clamped at the 8-row floor / the model's 1 MiB minimum
-    working set).  The per-query working set includes the train-mode
-    saved-corner output block (block_q x 4P x D in the slab dtype) the
-    model used to ignore."""
+    sublane(8)-aligned, never exceeds the query extent, the 2048 cap or
+    the SMEM cap of its table chunks, and under the VMEM model never
+    exceeds vmem_budget (unless already clamped at the 8-row floor / the
+    model's 1 MiB minimum working set).  The per-query working set of a
+    train plan is the backward step: gout rows, the saved corners
+    (block_q x 4P rows of the head group's lanes, slab dtype), weight
+    grads and phase 1's fp32 copy of the corners."""
     levels, P, D, Q, budget, train, slab = args
     spec = plan_mod.MsdaSpec(
         spatial_shapes=levels, num_heads=2, head_dim=D, num_points=P,
         num_queries=Q, train=train, vmem_budget=budget, slab_dtype=slab)
+    G = spec.heads_per_launch
+    lanes = ops.lane_width(G, D)
     bqs = plan_mod._heuristic_block_q(spec)
     per_q = ops.per_query_bytes(P, D, train=train,
-                                slab_itemsize=spec.slab_itemsize)
+                                slab_itemsize=spec.slab_itemsize, heads=G)
     if train:
-        assert per_q == ops.per_query_bytes(P, D) + 4 * P * D * spec.slab_itemsize
+        assert per_q == (2 * (lanes * 4 + 4 * P * lanes * spec.slab_itemsize
+                              + G * 4 * P * 4) + 4 * P * lanes * 4)
     for hw, bq in zip(levels, bqs):
         assert bq % 8 == 0 and 8 <= bq <= 2048
         assert bq <= _round_up8(Q)
-        resident = ops.slab_rows(hw) * D * spec.slab_itemsize
-        if train:
-            resident += ops.slab_rows(hw) * D * spec.accum_itemsize
+        assert bq <= max(8, ops.smem_block_cap(P, heads=G))
+        # the resident slab is fp32 over the head group's lanes
+        resident = ops.slab_rows(hw) * lanes * 4
         # the documented model: per-step bytes fit what the budget leaves
-        # after the resident slab(s), floored at a 1 MiB working set
+        # after the resident slab, floored at a 1 MiB working set
         assert bq * per_q <= max(budget - resident, 1 * _MIB) or bq == 8
 
 
@@ -169,10 +173,10 @@ def test_fusion_tier_respects_vmem_fitting_model(args):
     spec = mk("auto")
     dts = plan_mod._default_slab_dtypes(spec)
     fused, prefix = plan_mod._resolve_fuse_tier(spec, dts, "pallas")
+    G = spec.heads_per_launch
     k_model = ops.fusion_prefix(
         levels, P, D, value_itemsize=plan_mod._slab_itemsizes(dts),
-        train=train, vmem_budget=spec.vmem_budget,
-        accum_itemsize=spec.accum_itemsize)
+        train=train, vmem_budget=spec.vmem_budget, heads=G)
     if L >= 2:
         if k_model == L:
             assert (fused, prefix) == (True, 0)  # whole pyramid
@@ -183,23 +187,20 @@ def test_fusion_tier_respects_vmem_fitting_model(args):
         # the k == L rung is the historical whole-pyramid fitting model
         fits = ops.fused_pyramid_fits(
             levels, P, D, value_itemsize=spec.slab_itemsize, train=train,
-            vmem_budget=spec.vmem_budget, accum_itemsize=spec.accum_itemsize)
+            vmem_budget=spec.vmem_budget, heads=G)
         assert (k_model == L) == fits
         rows = sum(ops.slab_rows(hw) for hw in levels)
-        resident = rows * D * spec.slab_itemsize
-        if train:
-            resident += rows * D * spec.accum_itemsize
+        resident = rows * ops.lane_width(G, D) * 4  # fp32 super-slab
         per_q = ops.per_query_bytes(P, D, train=train,
                                     slab_itemsize=spec.slab_itemsize,
-                                    levels=L)
+                                    levels=L, heads=G)
         assert fits == (resident + 8 * per_q <= spec.vmem_budget)
         # every committed prefix actually fits its own residency model
         if 0 < k_model:
             kth = ops.fusion_prefix(
                 levels[:k_model], P, D,
                 value_itemsize=plan_mod._slab_itemsizes(dts[:k_model]),
-                train=train, vmem_budget=spec.vmem_budget,
-                accum_itemsize=spec.accum_itemsize)
+                train=train, vmem_budget=spec.vmem_budget, heads=G)
             assert kth == k_model
     else:
         assert (fused, prefix) == (False, 0)  # single level: nothing to fuse

@@ -1,4 +1,5 @@
 """Plan/execute API: spec -> plan -> execute, registry, caches, shim parity."""
+import os
 import warnings
 
 import jax
@@ -365,3 +366,68 @@ def test_unknown_tune_mode_errors():
     value, loc, attn = _inputs()
     with pytest.raises(ValueError, match="tune"):
         msda_plan(_spec(value, loc), tune="genetic")
+
+
+# --------------------------------------------------------------------------
+# no silent stand-ins for the device: budgets, peaks, fallbacks, autotune
+# --------------------------------------------------------------------------
+
+
+def test_vmem_budget_unknown_tpu_kind_raises():
+    assert plan_mod.default_vmem_budget("TPU v5 lite") == 100 * 2**20
+    with pytest.raises(ValueError, match="TPU v99"):
+        plan_mod.default_vmem_budget("TPU v99")
+
+
+def test_peaks_unknown_device_kind_raises():
+    from repro.launch import mesh as mesh_lib
+
+    assert mesh_lib.peaks("TPU v5 lite")["flops_bf16"] == 197e12
+    with pytest.raises(ValueError, match="cpu"):
+        mesh_lib.peaks("cpu")
+
+
+def test_compiled_pallas_plan_has_no_oracle_rung():
+    """Compiled kernels (a TPU) never degrade to the XLA oracle: the
+    ladder stops at per-level Pallas.  Interpreted ones keep the rung."""
+    spec = MsdaSpec(spatial_shapes=LEVELS, num_heads=2, head_dim=8,
+                    num_points=2, num_queries=64, fuse_levels="on")
+    compiled = msda_plan(spec, backend="pallas", interpret=False)
+    chain = compiled.fallback_chain()
+    assert [p.backend for p in chain] == ["pallas"]
+    assert not chain[0].fused and chain[0].tuning.interpret is False
+    interpreted = msda_plan(spec, backend="pallas", interpret=True)
+    assert [p.backend for p in interpreted.fallback_chain()] == [
+        "pallas", "ref"]
+
+
+def test_autotune_raises_when_no_candidate_builds(tmp_path, monkeypatch):
+    monkeypatch.setenv("REPRO_MSDA_AUTOTUNE_CACHE", str(tmp_path / "t.json"))
+
+    def broken(spec, tuning):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: nope")
+
+    registry.register_backend("broken-kernels", broken)
+    try:
+        spec = MsdaSpec(spatial_shapes=LEVELS, num_heads=2, head_dim=8,
+                        num_points=2, num_queries=64)
+        with pytest.raises(RuntimeError, match="Mosaic failed"):
+            msda_plan(spec, backend="broken-kernels", tune="autotune")
+    finally:
+        registry.unregister_backend("broken-kernels")
+
+
+def test_compilation_cache_dir_rule(monkeypatch, tmp_path):
+    from repro.serving import persistence
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    # the checkout root: where pytest.ini sits
+    assert os.path.isfile(os.path.join(persistence.CHECKOUT_ROOT,
+                                       "pytest.ini"))
+    assert persistence.compilation_cache_dir() == os.path.join(
+        persistence.CHECKOUT_ROOT, ".jax_cache")
+    assert persistence.compilation_cache_dir(str(tmp_path)) == str(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    # the environment wins over a launcher's --compile-cache
+    assert persistence.compilation_cache_dir("/elsewhere") == str(
+        tmp_path / "env")

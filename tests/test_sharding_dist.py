@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import get_config, reduced
 from repro.core import msda as msda_mod
@@ -29,15 +29,18 @@ def test_param_specs_cover_all_archs():
 
 
 def test_resolve_axes_multi_pod():
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     assert rules.resolve_axis("dp", mesh) == ("pod", "data")
     assert rules.resolve_axis("tp", mesh) == "model"
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     assert rules.resolve_axis("dp", mesh1) == "data"
 
 
 def test_hint_degrades_nondivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with rules.use_mesh(mesh):
         x = jnp.ones((3, 5))
         y = rules.hint(x, "dp", "tp")  # 3 % 1 == 0 fine on 1-dev mesh
@@ -146,9 +149,6 @@ def test_ring_allreduce_equals_psum():
     commutative; the ring order is a rotation of the device order)."""
     mesh = _mesh(2, 2)
     x = jnp.arange(2 * 37 * 3, dtype=jnp.float32).reshape(2, 37, 3) * 0.37
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     def ring(v):
         return msda_bwd.ring_allreduce(v, "model", 2, axis=1)
 
@@ -156,10 +156,10 @@ def test_ring_allreduce_equals_psum():
         return jax.lax.psum(v, "model")
 
     kw = dict(mesh=mesh, in_specs=P(None, None, None),
-              out_specs=P(None, None, None), check_rep=False)
+              out_specs=P(None, None, None), check_vma=False)
     # chunk axis 37 does not divide the axis size: exercises the padding
-    out_ring = shard_map(ring, **kw)(x)
-    out_psum = shard_map(psum, **kw)(x)
+    out_ring = jax.shard_map(ring, **kw)(x)
+    out_psum = jax.shard_map(psum, **kw)(x)
     assert np.array_equal(np.asarray(out_ring), np.asarray(out_psum))
 
 
