@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import msda as msda_mod
 from repro.models import attention, layers
+from repro.obs import scopes
 from repro.sharding import rules
 
 
@@ -103,56 +104,63 @@ def _level_emb_expanded(params, cfg, dtype):
 def encode_pyramid(params, cfg, pyramid: jax.Array, *, train: bool = False,
                    remat: bool = True) -> jax.Array:
     """pyramid: (B, S, d) flattened multi-scale features -> memory (B, S, d)."""
-    mc = cfg.msda
-    dt = pyramid.dtype
-    x = pyramid + _level_emb_expanded(params, cfg, dt)[None]
-    refs = msda_mod.level_ref_points(mc.levels)[None].astype(jnp.float32)  # (1,S,2)
-    refs = jnp.broadcast_to(refs, (x.shape[0], *refs.shape[1:]))
-    x = rules.hint(x, "dp", None, None)
+    with jax.named_scope(scopes.ENCODER):
+        mc = cfg.msda
+        dt = pyramid.dtype
+        x = pyramid + _level_emb_expanded(params, cfg, dt)[None]
+        refs = msda_mod.level_ref_points(mc.levels)[None].astype(jnp.float32)  # (1,S,2)
+        refs = jnp.broadcast_to(refs, (x.shape[0], *refs.shape[1:]))
+        x = rules.hint(x, "dp", None, None)
 
-    def step(x, lp):
-        h = layers.apply_norm(lp["norm1"], x, cfg.norm_eps)
-        # 87k pixel queries: shard queries over 'model' — or dp x tp
-        # jointly when the mesh + Q clear the 2D threshold (value
-        # replicated per shard; grad_value ring-reduced — the
-        # staggered-scatter analogue, see docs/sharding.md).  The
-        # sharding mode is committed on the cached MsdaPlan.
-        y = msda_mod.msda_attention(lp["msda"], mc, h, h, refs, train=train,
-                                    query_parallel=mc.query_parallel)
-        x = x + y
-        h2 = layers.apply_norm(lp["norm2"], x, cfg.norm_eps)
-        x = x + layers.apply_mlp(lp["mlp"], cfg, h2)
-        return x, None
+        def step(x, lp):
+            h = layers.apply_norm(lp["norm1"], x, cfg.norm_eps)
+            # 87k pixel queries: shard queries over 'model' — or dp x tp
+            # jointly when the mesh + Q clear the 2D threshold (value
+            # replicated per shard; grad_value ring-reduced — the
+            # staggered-scatter analogue, see docs/sharding.md).  The
+            # sharding mode is committed on the cached MsdaPlan.
+            y = msda_mod.msda_attention(lp["msda"], mc, h, h, refs, train=train,
+                                        query_parallel=mc.query_parallel)
+            x = x + y
+            with jax.named_scope(scopes.FFN):
+                h2 = layers.apply_norm(lp["norm2"], x, cfg.norm_eps)
+                x = x + layers.apply_mlp(lp["mlp"], cfg, h2)
+            return x, None
 
-    if remat:
-        step = jax.checkpoint(step)
-    x, _ = jax.lax.scan(step, x, params["enc_layers"])
-    return x
+        if remat:
+            step = jax.checkpoint(step)
+        x, _ = jax.lax.scan(step, x, params["enc_layers"])
+        return x
 
 
 def decode_queries(params, cfg, memory: jax.Array, *, train: bool = False):
     """300 object queries -> (class_logits (B,300,C), boxes (B,300,4))."""
-    mc = cfg.msda
-    B = memory.shape[0]
-    dt = memory.dtype
-    q = jnp.broadcast_to(params["query_emb"].astype(dt)[None], (B, 300, cfg.d_model))
-    refs = jax.nn.sigmoid(layers.apply_linear(params["ref_head"], params["query_emb"]))
-    refs = jnp.broadcast_to(refs[None].astype(jnp.float32), (B, 300, 2))
+    with jax.named_scope(scopes.DECODER):
+        mc = cfg.msda
+        B = memory.shape[0]
+        dt = memory.dtype
+        q = jnp.broadcast_to(params["query_emb"].astype(dt)[None], (B, 300, cfg.d_model))
+        refs = jax.nn.sigmoid(layers.apply_linear(params["ref_head"], params["query_emb"]))
+        refs = jnp.broadcast_to(refs[None].astype(jnp.float32), (B, 300, 2))
 
-    def step(q, lp):
-        h = layers.apply_norm(lp["norm1"], q, cfg.norm_eps)
-        q = q + attention.attention_fwd(lp["self_attn"], cfg, h, causal=False, rope=False)
-        h2 = layers.apply_norm(lp["norm2"], q, cfg.norm_eps)
-        q = q + msda_mod.msda_attention(lp["msda"], mc, h2, memory, refs, train=train)
-        h3 = layers.apply_norm(lp["norm3"], q, cfg.norm_eps)
-        q = q + layers.apply_mlp(lp["mlp"], cfg, h3)
-        return q, None
+        def step(q, lp):
+            with jax.named_scope(scopes.SELF_ATTN):
+                h = layers.apply_norm(lp["norm1"], q, cfg.norm_eps)
+                q = q + attention.attention_fwd(lp["self_attn"], cfg, h, causal=False,
+                                                rope=False)
+            h2 = layers.apply_norm(lp["norm2"], q, cfg.norm_eps)
+            q = q + msda_mod.msda_attention(lp["msda"], mc, h2, memory, refs, train=train)
+            with jax.named_scope(scopes.FFN):
+                h3 = layers.apply_norm(lp["norm3"], q, cfg.norm_eps)
+                q = q + layers.apply_mlp(lp["mlp"], cfg, h3)
+            return q, None
 
-    q, _ = jax.lax.scan(step, q, params["dec_layers"])
-    q = layers.apply_norm(params["final_norm"], q, cfg.norm_eps)
-    logits = layers.apply_linear(params["class_head"], q)
-    b = jax.nn.gelu(layers.apply_linear(params["box_head"]["l1"], q))
-    boxes = jax.nn.sigmoid(layers.apply_linear(params["box_head"]["l2"], b))
+        q, _ = jax.lax.scan(step, q, params["dec_layers"])
+    with jax.named_scope(scopes.HEADS):
+        q = layers.apply_norm(params["final_norm"], q, cfg.norm_eps)
+        logits = layers.apply_linear(params["class_head"], q)
+        b = jax.nn.gelu(layers.apply_linear(params["box_head"]["l1"], q))
+        boxes = jax.nn.sigmoid(layers.apply_linear(params["box_head"]["l2"], b))
     return logits, boxes
 
 
@@ -191,23 +199,27 @@ def detr_loss(params, cfg, batch: Dict[str, jax.Array], *, train: bool = True,
     logits, boxes = decode_queries(params, cfg, memory, train=train)
     labels, gt_boxes = batch["labels"], batch["boxes"]
     B, T = labels.shape
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)  # (B,Q,C)
+    with jax.named_scope(scopes.LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)  # (B,Q,C)
 
     def one(lp, bx, lab, gbx):
-        valid = lab >= 0
-        lab_c = jnp.maximum(lab, 0)
-        cost_cls = -lp[:, lab_c]  # (Q,T)
-        cost_l1 = jnp.abs(bx[:, None, :] - gbx[None, :, :]).sum(-1)
-        cost = cost_cls + 5.0 * cost_l1
-        cost = jnp.where(valid[None, :], cost, jnp.inf)
-        assign = greedy_match(cost, T)
-        nll = -lp[assign, lab_c] * valid
-        l1 = (jnp.abs(bx[assign] - gbx).sum(-1)) * valid
-        # unmatched queries pushed to the background class (= class 0 here)
-        matched = jnp.zeros((lp.shape[0],), bool).at[assign].set(valid)
-        bg = -lp[:, 0] * (~matched)
-        denom = jnp.maximum(valid.sum(), 1)
-        return (nll.sum() + 5.0 * l1.sum()) / denom + bg.mean()
+        with jax.named_scope(scopes.MATCHING):
+            valid = lab >= 0
+            lab_c = jnp.maximum(lab, 0)
+            cost_cls = -lp[:, lab_c]  # (Q,T)
+            cost_l1 = jnp.abs(bx[:, None, :] - gbx[None, :, :]).sum(-1)
+            cost = cost_cls + 5.0 * cost_l1
+            cost = jnp.where(valid[None, :], cost, jnp.inf)
+            assign = greedy_match(cost, T)
+        with jax.named_scope(scopes.LOSS):
+            nll = -lp[assign, lab_c] * valid
+            l1 = (jnp.abs(bx[assign] - gbx).sum(-1)) * valid
+            # unmatched queries pushed to the background class (= class 0 here)
+            matched = jnp.zeros((lp.shape[0],), bool).at[assign].set(valid)
+            bg = -lp[:, 0] * (~matched)
+            denom = jnp.maximum(valid.sum(), 1)
+            return (nll.sum() + 5.0 * l1.sum()) / denom + bg.mean()
 
     losses = jax.vmap(one)(logp, boxes.astype(jnp.float32), labels, gt_boxes.astype(jnp.float32))
-    return losses.mean()
+    with jax.named_scope(scopes.LOSS):
+        return losses.mean()

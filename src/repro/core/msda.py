@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro.kernels import plan as plan_mod
 from repro.models import layers
+from repro.obs import scopes
 from repro.sharding import rules
 
 
@@ -157,26 +158,28 @@ def msda_attention(
     L, H, Pn = len(levels), msda_cfg.num_heads, msda_cfg.num_points
     B, Q, d = query.shape
     D = d // H
-    value = (value_feats @ p["value_proj"].astype(query.dtype)).reshape(B, -1, H, D)
+    with jax.named_scope(scopes.MSDA_PROJ):
+        value = (value_feats @ p["value_proj"].astype(query.dtype)).reshape(B, -1, H, D)
 
-    off = query @ p["w_offsets"].astype(query.dtype) + p["b_offsets"].astype(query.dtype)
-    off = off.reshape(B, Q, H, L, Pn, 2).astype(jnp.float32)
-    wh = jnp.asarray([[w, h] for (h, w) in levels], jnp.float32)  # (L,2) x,y order
-    refs = reference_points[:, :, None, None, None, :]
-    if valid_ratios is not None:
-        # bucketed serving (Deformable-DETR valid_ratios): the pyramid
-        # only occupies the top-left (w*rx, h*ry) region of each padded
-        # level.  Scaling the REFERENCE POINTS by the ratio (offsets stay
-        # normalised by the padded extents wh) lands every sample on the
-        # same pixel coordinate as in the unpadded level:
-        # (x*r)*W - 0.5 == x*w - 0.5, and pad-region corners gather the
-        # zeros that out-of-range corners contributed anyway.
-        refs = refs * valid_ratios[:, None, None, :, None, :].astype(jnp.float32)
-    loc = refs + off / wh[None, None, None, :, None, :]
+        off = query @ p["w_offsets"].astype(query.dtype) + p["b_offsets"].astype(query.dtype)
+        off = off.reshape(B, Q, H, L, Pn, 2).astype(jnp.float32)
+        wh = jnp.asarray([[w, h] for (h, w) in levels], jnp.float32)  # (L,2) x,y order
+        refs = reference_points[:, :, None, None, None, :]
+        if valid_ratios is not None:
+            # bucketed serving (Deformable-DETR valid_ratios): the pyramid
+            # only occupies the top-left (w*rx, h*ry) region of each padded
+            # level.  Scaling the REFERENCE POINTS by the ratio (offsets stay
+            # normalised by the padded extents wh) lands every sample on the
+            # same pixel coordinate as in the unpadded level:
+            # (x*r)*W - 0.5 == x*w - 0.5, and pad-region corners gather the
+            # zeros that out-of-range corners contributed anyway.
+            refs = refs * valid_ratios[:, None, None, :, None, :].astype(jnp.float32)
+        loc = refs + off / wh[None, None, None, :, None, :]
 
-    aw = query @ p["w_weights"].astype(query.dtype) + p["b_weights"].astype(query.dtype)
-    aw = jax.nn.softmax(aw.reshape(B, Q, H, L * Pn).astype(jnp.float32), axis=-1)
-    aw = aw.reshape(B, Q, H, L, Pn)
+        aw = query @ p["w_weights"].astype(query.dtype) + p["b_weights"].astype(query.dtype)
+        aw = jax.nn.softmax(aw.reshape(B, Q, H, L * Pn).astype(jnp.float32), axis=-1)
+        aw = aw.reshape(B, Q, H, L, Pn).astype(query.dtype)
+        value = value.astype(query.dtype)
 
     # one cached plan per static geometry: the mesh (when >1 device) bakes
     # shard_map wiring in, keeping the irregular gathers LOCAL per shard
@@ -189,8 +192,9 @@ def msda_attention(
         msda_cfg, num_queries=Q, head_dim=D, dtype=query.dtype, train=train,
         backend=backend, mesh=mesh, query_parallel=query_parallel,
     )
-    out = plan(value.astype(query.dtype), loc, aw.astype(query.dtype))
-    return out @ p["out_proj"].astype(query.dtype)
+    out = plan(value, loc, aw)
+    with jax.named_scope(scopes.MSDA_PROJ):
+        return out @ p["out_proj"].astype(query.dtype)
 
 
 # --------------------------------------------------------------------------

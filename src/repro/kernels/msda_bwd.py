@@ -39,8 +39,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.msda_fwd import (
     LevelGeom, compiler_params, corner_offset, corner_walk, fetch_row,
-    idx_slot, keep_corner, lane_heads, resident, saved_slot, static_loop,
-    table_specs, w_slot)
+    idx_slot, keep_corner, kernel_metadata, lane_heads, resident, saved_slot,
+    static_loop, table_specs, w_slot)
+from repro.obs import scopes
 
 
 def _scatter_kernel(idx_ref, w_ref, gout_ref, src_ref, gval_ref, gw_ref,
@@ -130,6 +131,7 @@ def msda_scatter(
     fuse_scatter: bool = True,
     interpret: bool,
     vmem_limit: int = 0,
+    first_level: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Backward over one slab: ``(grad_slab, grad_w)``.
 
@@ -137,6 +139,8 @@ def msda_scatter(
     the weight table ``w``, (B, NG, nq, G, L*4P, block_q) fp32 — the
     table's chunk layout.  ``src`` holds the corners: the forward's
     saved block, or (``regather``) the fp32 slab they are re-read from.
+    ``first_level`` is the pyramid index of ``levels[0]``, for the
+    kernel's metadata only.
     """
     B, NG, qp, GD = gout.shape
     L, P, D = len(levels), num_points, head_dim
@@ -175,6 +179,8 @@ def msda_scatter(
         scratch_shapes=[pltpu.VMEM((block_q * K, GD), jnp.float32)],
         compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
+        name=scopes.SCATTER_KERNEL,
+        metadata=kernel_metadata("scatter", first_level, L, block_q),
     )(idx, w, gout, src)
     return gval, gw
 

@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import scopes
+
 # lane width of one vreg: the head group fills it when head_dim allows
 LANES = 128
 
@@ -237,6 +239,15 @@ def compiler_params(vmem_limit: int):
 SMEM_TILE = 1024
 
 
+def kernel_metadata(kind: str, first_level: int, levels: int,
+                    block_q: int) -> dict:
+    """The metadata an MSDA kernel carries into its HLO custom call
+    (``frontend_attributes={kernel_metadata={...}}``) and trace event."""
+    return {"msda": kind,
+            "levels": f"{first_level}-{first_level + levels - 1}",
+            "block_q": str(block_q)}
+
+
 def table_block(n: int) -> int:
     """Padded length of one query block's table chunk of ``n`` entries."""
     return -(-n // SMEM_TILE) * SMEM_TILE
@@ -281,6 +292,7 @@ def msda_gather(
     save_dtype=None,
     interpret: bool,
     vmem_limit: int = 0,
+    first_level: int = 0,
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Weighted corner gather over one slab: ``(out, saved)``.
 
@@ -288,7 +300,8 @@ def msda_gather(
     levels, points and corners of weight x corner row.  With
     ``save_dtype`` the raw corners also come back as (B, NG, Qp*L*4P,
     G*D) in that dtype: each query's L*4P rows in :func:`saved_slot`
-    order.
+    order.  ``first_level`` is the pyramid index of ``levels[0]``, for
+    the kernel's metadata only.
     """
     B, NG, R, GD = slab.shape
     L, P, D = len(levels), num_points, head_dim
@@ -323,6 +336,8 @@ def msda_gather(
         scratch_shapes=scratch,
         compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
+        name=scopes.GATHER_KERNEL,
+        metadata=kernel_metadata("gather", first_level, L, block_q),
     )(idx, w, slab)
     if save_dtype is not None:
         return outs[0], outs[1]

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import msda_bwd, msda_fwd
+from repro.obs import scopes
 
 Shapes = Tuple[Tuple[int, int], ...]
 
@@ -537,23 +538,32 @@ def _kernel_gather(p: MSDAParams, dims, operand_dtype):
         return jnp.transpose(value.reshape(B, S, NG, G * D), (0, 2, 1, 3))
 
     def run_fwd(value, idxs, ws, save):
-        value_g = grouped_value(value)
-        out = jnp.zeros((B, NG, Q, G * D), jnp.float32)
-        slabs, saved = [], []
-        for launch, idx, w in zip(launches, idxs, ws):
-            geoms, _ = _launch_geometry(p, launch)
-            slab = _launch_slab(p, launch, value_g, operand_dtype)
-            o, s = msda_fwd.msda_gather(
-                slab, idx, w, levels=geoms, num_points=P, head_dim=D,
-                block_q=launch.block_q, fuse_gather=p.fuse_gather,
-                save_dtype=(_corner_dtype(p, launch, operand_dtype)
-                            if save else None),
-                interpret=p.interpret, vmem_limit=p.vmem_limit)
-            out = out + o[:, :, :Q]
-            slabs.append(slab)
-            saved.append(s)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, H * D)
-        return out.astype(operand_dtype), (tuple(slabs), tuple(saved))
+        with jax.named_scope(scopes.MSDA_FWD):
+            with jax.named_scope(scopes.MSDA_SLAB):
+                value_g = grouped_value(value)
+            with jax.named_scope(scopes.MSDA_REDUCE):
+                out = jnp.zeros((B, NG, Q, G * D), jnp.float32)
+            slabs, saved = [], []
+            for launch, idx, w in zip(launches, idxs, ws):
+                geoms, _ = _launch_geometry(p, launch)
+                with jax.named_scope(scopes.MSDA_SLAB):
+                    slab = _launch_slab(p, launch, value_g, operand_dtype)
+                with jax.named_scope(scopes.MSDA_KERNEL):
+                    o, s = msda_fwd.msda_gather(
+                        slab, idx, w, levels=geoms, num_points=P, head_dim=D,
+                        block_q=launch.block_q, fuse_gather=p.fuse_gather,
+                        save_dtype=(_corner_dtype(p, launch, operand_dtype)
+                                    if save else None),
+                        interpret=p.interpret, vmem_limit=p.vmem_limit,
+                        first_level=launch.levels[0])
+                with jax.named_scope(scopes.MSDA_REDUCE):
+                    out = out + o[:, :, :Q]
+                slabs.append(slab)
+                saved.append(s)
+            with jax.named_scope(scopes.MSDA_REDUCE):
+                out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, H * D)
+                out = out.astype(operand_dtype)
+            return out, (tuple(slabs), tuple(saved))
 
     @jax.custom_vjp
     def gather(value, idxs, ws):
@@ -566,26 +576,35 @@ def _kernel_gather(p: MSDAParams, dims, operand_dtype):
                      saved if p.save_sampled else None, idxs, ws)
 
     def bwd(res, gout):
-        slabs, saved, idxs, ws = res
-        gout_g = jnp.transpose(
-            gout.astype(jnp.float32).reshape(B, Q, NG, G * D), (0, 2, 1, 3))
-        gvals, gws = [], []
-        for i, (launch, idx, w) in enumerate(zip(launches, idxs, ws)):
-            geoms, rows = _launch_geometry(p, launch)
-            qp = _round_up(Q, launch.block_q)
-            gslab, gw = msda_bwd.msda_scatter(
-                _pad_q(gout_g, 2, qp, 0.0), idx, w,
-                slabs[i] if saved is None else saved[i],
-                regather=saved is None, rows=rows, levels=geoms,
-                num_points=P, head_dim=D, block_q=launch.block_q,
-                fuse_scatter=p.fuse_scatter, interpret=p.interpret,
-                vmem_limit=p.vmem_limit)
-            gvals += _unpack_grad(gslab, p, launch, geoms)
-            gws.append(_flat_table(gw))
-        gvalue = jnp.concatenate(gvals, axis=2)  # (B,NG,S,G*D)
-        gvalue = jnp.transpose(gvalue, (0, 2, 1, 3)).reshape(B, S, H, D)
-        gidx = tuple(np.zeros(i.shape, jax.dtypes.float0) for i in idxs)
-        return gvalue.astype(operand_dtype), gidx, tuple(gws)
+        with jax.named_scope(scopes.MSDA_BWD):
+            slabs, saved, idxs, ws = res
+            with jax.named_scope(scopes.MSDA_SLAB):
+                gout_g = jnp.transpose(
+                    gout.astype(jnp.float32).reshape(B, Q, NG, G * D),
+                    (0, 2, 1, 3))
+            gvals, gws = [], []
+            for i, (launch, idx, w) in enumerate(zip(launches, idxs, ws)):
+                geoms, rows = _launch_geometry(p, launch)
+                qp = _round_up(Q, launch.block_q)
+                with jax.named_scope(scopes.MSDA_SLAB):
+                    gout_p = _pad_q(gout_g, 2, qp, 0.0)
+                with jax.named_scope(scopes.MSDA_KERNEL):
+                    gslab, gw = msda_bwd.msda_scatter(
+                        gout_p, idx, w,
+                        slabs[i] if saved is None else saved[i],
+                        regather=saved is None, rows=rows, levels=geoms,
+                        num_points=P, head_dim=D, block_q=launch.block_q,
+                        fuse_scatter=p.fuse_scatter, interpret=p.interpret,
+                        vmem_limit=p.vmem_limit, first_level=launch.levels[0])
+                with jax.named_scope(scopes.MSDA_GRAD_UNPACK):
+                    gvals += _unpack_grad(gslab, p, launch, geoms)
+                    gws.append(_flat_table(gw))
+            with jax.named_scope(scopes.MSDA_GRAD_UNPACK):
+                gvalue = jnp.concatenate(gvals, axis=2)  # (B,NG,S,G*D)
+                gvalue = jnp.transpose(gvalue, (0, 2, 1, 3)).reshape(B, S, H, D)
+                gvalue = gvalue.astype(operand_dtype)
+            gidx = tuple(np.zeros(i.shape, jax.dtypes.float0) for i in idxs)
+            return gvalue, gidx, tuple(gws)
 
     gather.defvjp(fwd, bwd)
     return gather
@@ -604,7 +623,11 @@ def build_kernel_op(p: MSDAParams):
     def op(value, loc, attn):
         B, S, H, D = value.shape
         Q, P = loc.shape[1], loc.shape[4]
-        idxs, ws = _corner_tables(p, loc, attn, D)
+        # the table math's own transpose, under the forward's name,
+        # is the backward's grad-loc / grad-attn (scopes.layer_of)
+        with jax.named_scope(scopes.MSDA_FWD), \
+                jax.named_scope(scopes.MSDA_TABLES):
+            idxs, ws = _corner_tables(p, loc, attn, D)
         return _kernel_gather(p, (B, S, H, D, Q, P), value.dtype)(
             value, idxs, ws)
 

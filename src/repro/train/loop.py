@@ -24,6 +24,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
 from repro.optim import adamw, schedule
 from repro.train.state import TrainState, loss_fn
 
@@ -65,7 +66,10 @@ def make_train_step(
         )
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array]):
-        params_c = constrain(jax.tree.map(cast_param, state.params))
+        # the optimizer scope holds the mixed-precision copies too: the
+        # compute-dtype weights and the fp32 gradients
+        with jax.named_scope(scopes.OPTIMIZER):
+            params_c = constrain(jax.tree.map(cast_param, state.params))
 
         def micro_loss(p, mb):
             return lf(p, mb, remat=remat)
@@ -78,29 +82,38 @@ def make_train_step(
                 loss, grads = jax.value_and_grad(micro_loss)(params_c, mb)
                 # grads arrive in compute dtype, already reduce-scattered by
                 # the FSDP backward; accumulate into the sharded fp32 buffer
-                gacc = constrain(
-                    jax.tree.map(lambda a, g: a + g.astype(jnp.float32), gacc, grads)
-                )
-                return (gacc, lacc + loss), None
+                with jax.named_scope(scopes.OPTIMIZER):
+                    gacc = constrain(
+                        jax.tree.map(lambda a, g: a + g.astype(jnp.float32), gacc, grads)
+                    )
+                with jax.named_scope(scopes.LOSS):
+                    lacc = lacc + loss
+                return (gacc, lacc), None
 
-            zeros = constrain(
-                jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            )
+            with jax.named_scope(scopes.OPTIMIZER):
+                zeros = constrain(
+                    jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+                )
             (gsum, lsum), _ = jax.lax.scan(one_micro, (zeros, jnp.float32(0.0)), micro)
-            grads = jax.tree.map(lambda g: g / num_microbatches, gsum)
-            loss = lsum / num_microbatches
+            with jax.named_scope(scopes.OPTIMIZER):
+                grads = jax.tree.map(lambda g: g / num_microbatches, gsum)
+            with jax.named_scope(scopes.LOSS):
+                loss = lsum / num_microbatches
         else:
             loss, grads = jax.value_and_grad(micro_loss)(params_c, batch)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
 
-        lr = schedule.warmup_cosine(
-            state.step, peak_lr=peak_lr, warmup_steps=warmup_steps, total_steps=total_steps
-        )
-        new_params, new_opt, gnorm = adamw.adamw_update(
-            grads, state.opt, state.params,
-            lr=lr, weight_decay=weight_decay, clip_norm=clip_norm,
-        )
-        new_state = TrainState(params=new_params, opt=new_opt, step=state.step + 1)
+        with jax.named_scope(scopes.OPTIMIZER):
+            lr = schedule.warmup_cosine(
+                state.step, peak_lr=peak_lr, warmup_steps=warmup_steps,
+                total_steps=total_steps
+            )
+            new_params, new_opt, gnorm = adamw.adamw_update(
+                grads, state.opt, state.params,
+                lr=lr, weight_decay=weight_decay, clip_norm=clip_norm,
+            )
+            new_state = TrainState(params=new_params, opt=new_opt, step=state.step + 1)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         return new_state, metrics
 
