@@ -9,10 +9,21 @@ Paper mapping (xMSDA §4.1 -> TPU):
   so — like the paper's scalar-addressed pixel copies out of the on-chip
   buffer — the top-left corner row of every (query, head, level, point)
   and its four bilinear weights arrive in SMEM, computed outside the
-  kernel by cheap element-wise XLA (:func:`corner_indices`).  The kernel
-  walks its query block and issues one dynamic row load per corner;
-  the x-pair partner sits at ``idx + 1`` and the y-pair partner at
-  ``idx + Wp`` of the row-major slab, so the corner walk is branch-free.
+  kernel by cheap element-wise XLA (:func:`corner_indices`).  The
+  x-pair partner sits at ``idx + 1`` and the y-pair partner at
+  ``idx + Wp`` of the row-major slab, so a point's four corners are two
+  two-row loads (:func:`fetch_pair`), branch-free.
+* **The row loop's scalar budget.**  The scalar unit sets the pace: a
+  bundle has two scalar slots, at most one of them reading SMEM, and a
+  gathered row costs a weight read and its address, plus a corner-row
+  read, its address and a VMEM address per point or row pair.  Each
+  (level, head) pair is one accumulation chain.  Once a query's rows
+  exceed :data:`UNROLLED_ROWS` the point loop is rolled and one step of
+  it advances every chain by a point: the chains' adds hide each
+  other's latency, and the step's L*G*5 table values stay in the scalar
+  register file (unrolled over every point, the scheduler hoists them
+  all and spills them to SMEM).  A table read is its step's base,
+  carried by the loop, plus a static offset: one add per read.
 * **Heads on lanes.**  The slab is ``(rows, G*D)``: G heads of one group
   side by side on the lane axis (G*D = 128 for the DETR head_dim 32), so
   VMEM and HBM hold no lane padding.  A row load fetches all G heads of a
@@ -185,6 +196,22 @@ def keep_corner(row_scratch, k, mine, row):
         mine, row, row_scratch[pl.ds(k, 1), :])
 
 
+def fetch_pair(slab_ref, geom: LevelGeom, i):
+    """Rows ``i`` and ``i + 1`` (a corner pair along x) as two (1, lanes)
+    fp32 rows: one two-row load and one address, the second row rotated
+    down a sublane."""
+    if geom[3]:
+        return fetch_row(slab_ref, geom, i), fetch_row(slab_ref, geom, i + 1)
+    two = slab_ref[pl.ds(i, 2), :]
+    return two[0:1], two[1:2]
+
+
+# rows one unrolled query step may gather: past this, the table values
+# the scheduler keeps live outgrow the scalar register file and spill to
+# SMEM (on v5e a 64-row step spills 3 words, a 128-row one 131)
+UNROLLED_ROWS = 64
+
+
 def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                    *, levels: Tuple[LevelGeom, ...], P: int, G: int, D: int,
                    qb: int, fuse_gather: bool, unroll: bool):
@@ -194,34 +221,78 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
     :func:`idx_slot` / :func:`w_slot`), corner rows already lifted into
     this launch's slab, weights with the validity mask and the attention
     weight folded in.
+
+    Every (level, head) pair is one accumulation chain.  A step of the
+    walk's outer index (the point, or the corner of the corner-major
+    walk) advances every chain; the steps are a rolled loop when the
+    query's rows exceed :data:`UNROLLED_ROWS`.  The tables are linear in
+    their indices, so a read is a static offset from the step's base.
     """
     L = len(levels)
     heads = lane_heads(G, D)
     zero = jnp.zeros((1, G * D), jnp.float32)
+    rolled = not unroll or L * G * P * 4 > UNROLLED_ROWS
+    save = saved_ref is not None
 
-    def body(q, carry):
-        total = zero
+    def point_step(q, p, carry):
+        # point p of every chain, its four corners as two row pairs;
+        # ``base`` == idx_slot(0, q, 0, p) == w_slot(0, q, 0, 0, p)
+        base, accs = carry
+        accs = list(accs)
         for l, geom in enumerate(levels):
-            def head(h, part, l=l, geom=geom):
-                mine = heads == h
+            kept = [zero] * 4  # the level's corners, each head on its lanes
+            for h in range(G):
+                i = idx_ref[base + idx_slot(h, 0, l, 0, qb, L, P)]
+                rows = fetch_pair(slab_ref, geom, i) + fetch_pair(
+                    slab_ref, geom, i + geom[1])
+                acc = accs[l * G + h]
+                for c, row in enumerate(rows):
+                    w = w_ref[base + w_slot(h, 0, l, c, 0, qb, L, P)]
+                    acc = acc + w * row
+                    if save:
+                        kept[c] = jnp.where(heads == h, row, kept[c])
+                accs[l * G + h] = acc
+            if save:
+                for c in range(4):
+                    row_scratch[pl.ds(saved_slot(l, c, p, P), 1), :] = kept[c]
+        return base + idx_slot(0, 0, 0, 1, qb, L, P), tuple(accs)
 
-                def corner(p, c, acc):
+    def corner_step(q, c, carry):
+        # corner c of every point of every chain (the corner-major walk);
+        # ``base`` == w_slot(0, q, 0, c, 0)
+        base, accs = carry
+        accs = list(accs)
+        for l, geom in enumerate(levels):
+            for p in range(P):
+                kept = zero
+                for h in range(G):
                     i = idx_ref[idx_slot(h, q, l, p, qb, L, P)]
                     row = fetch_row(slab_ref, geom,
                                     i + corner_offset(c, geom[1]))
-                    if saved_ref is not None:
-                        keep_corner(row_scratch, saved_slot(l, c, p, P),
-                                    mine, row)
-                    return acc + w_ref[w_slot(h, q, l, c, p, qb, L, P)] * row
+                    accs[l * G + h] = accs[l * G + h] + w_ref[
+                        base + w_slot(h, 0, l, 0, p, qb, L, P)] * row
+                    if save:
+                        kept = jnp.where(heads == h, row, kept)
+                if save:
+                    row_scratch[pl.ds(saved_slot(l, c, p, P), 1), :] = kept
+        return base + w_slot(0, 0, 0, 1, 0, qb, L, P), tuple(accs)
 
-                acc = corner_walk(P, fuse_gather, unroll, corner, zero)
-                return jnp.where(mine, acc, part)
+    step, n = (point_step, P) if fuse_gather else (corner_step, 4)
 
-            # per-level partials summed in level order: the same adds the
-            # per-level launches' outputs see outside (tier parity)
-            total = total + static_loop(G, head, zero, unroll)
+    def body(q, carry):
+        _, accs = static_loop(n, functools.partial(step, q),
+                              (q, (zero,) * (L * G)), not rolled)
+        # each head's lanes take that head's level sums, added to the
+        # total in level order: the adds the per-level launches' outputs
+        # see outside (tier parity)
+        total = zero
+        for l in range(L):
+            part = zero
+            for h in range(G):
+                part = jnp.where(heads == h, accs[l * G + h], part)
+            total = total + part
         out_ref[pl.ds(q, 1), :] = total
-        if saved_ref is not None:
+        if save:
             saved_ref[row_block(q, L * 4 * P), :] = row_scratch[...].astype(
                 saved_ref.dtype)
         return carry
