@@ -4,12 +4,14 @@
         --control-seeds 1,2,3 [--out FILE]
 
 For every seed it runs the cell as the benchmark does (weights and
-batches from the seed, the program's compiled entry, the checked steps
+inputs from the seed, the program's compiled entry, the checked steps
 or the window's answers) with a window of one call, and reports the
-numbers that decide `correct` against the float32 reference (with
-``--fault``, of the program with that fault planted).  For the control
-seeds it also puts the reference computed in float8 (e4m3) in
-the program's place and reports the same numbers: the control has to
+numbers that decide `correct` against the family's float32 reference
+(with ``--fault``, of the program with that fault planted, from
+``chipbench/faults.py``).  For the control seeds it also reports the
+readings of the family's control (``control`` of
+``chipbench/families/<family>.py``; for Deformable-DETR the reference
+computed in float8, e4m3) against the reference: the control has to
 fail one of them.  The limits are then set between the program's
 largest reading and the control's smallest (``chipbench/limits/``).
 
@@ -22,7 +24,7 @@ import argparse
 import json
 import sys
 
-from chipbench import catalog, checks, faults, reference
+from chipbench import catalog, faults
 from chipbench import run as bench_run
 
 
@@ -46,14 +48,16 @@ def main(argv=None) -> int:
     cfg = catalog.config(bench, cell["config"])
     traffic = catalog.traffic(cell["traffic"])
     mode = traffic["mode"]
-    numbers = checks.NUMBERS[mode]
+    fam = catalog.family(cfg)
+    compare = fam.compare
     readings = {}
 
-    def record(prog, ref):
-        readings.update(checks.READINGS[mode](prog, ref))
-        return numbers(prog, ref)
+    def record(*a, **k):
+        numbers, r = compare(*a, **k)
+        readings.update(r)
+        return numbers, r
 
-    checks.NUMBERS[mode] = record
+    fam.compare = record
     for seed in sorted(set(seeds) | control):
         row = {"workload": args.workload, "seed": seed}
         if args.fault:
@@ -62,9 +66,7 @@ def main(argv=None) -> int:
         def on_reference(ref, inputs, seed=seed, row=row):
             row["reference"] = ref.get("losses")
             if seed in control:
-                low = checks.REFERENCE[mode](cfg, traffic, inputs,
-                                             reference.FLOAT8)
-                row["control"] = checks.READINGS[mode](low, ref)
+                row["control"] = fam.control(mode, cfg, traffic, inputs, ref)
 
         bench_run.run(bench_run.parse_args([
             "--workload", args.workload, "--seed", str(seed),

@@ -13,9 +13,10 @@ instruction's ``op_name`` from there, and the events join it on their
 instruction name: the names come from the program that ran, not from
 another compile of it.
 
-A traced run hands a metric's reader the parsed trace, not its file; the
-file is where ``chipbench.run`` writes it, ``--trace-dir`` or else
-``<checkout>/.chipbench/trace/<workload>`` (``profile_path``).
+A traced run hands a metric's reader the parsed trace and the directory
+of its profile (``run.trace_dir``: ``--trace-dir``, or else
+``<checkout>/.chipbench/trace/<workload>``), where ``profile_path`` finds
+the file.
 
 ``clock_offset_bounds`` bounds the offset between the device's clock and
 the host's from the host's enqueue and completion of each program run.
@@ -23,10 +24,10 @@ the host's from the host's enqueue and completion of each program run.
 from __future__ import annotations
 
 import importlib.util
-import sys
 from typing import Dict, Iterator, List, Optional, Set, Tuple
 
 from chipbench import trace
+from chipbench.harness import BenchError
 
 UNATTRIBUTED = "unattributed"
 METADATA_PLANE = "/host:metadata"
@@ -214,20 +215,13 @@ def profile_op_names(path: str) -> Tuple[Dict[str, str], Set[str]]:
     return op_names, names
 
 
-def profile_path() -> Optional[str]:
-    """The profile of the traced run this process is making, where
-    ``chipbench.run`` writes it; None outside its command line."""
-    from chipbench import catalog
-    from chipbench.run import parse_args
-
-    try:
-        args = parse_args(sys.argv[1:])
-    except SystemExit:
+def profile_path(run) -> Optional[str]:
+    """The profile of a traced run, in the directory it carries
+    (``run.trace_dir``); None where it has none."""
+    if getattr(run, "trace_dir", None) is None:
         return None
-    trace_dir = args.trace_dir or (
-        f"{catalog.ROOT}/.chipbench/trace/{args.workload}")
     try:
-        return trace.find_xplane(trace_dir)
+        return trace.find_xplane(run.trace_dir)
     except FileNotFoundError:
         return None
 
@@ -238,8 +232,6 @@ def profile_path() -> Optional[str]:
 
 
 def _fail(msg: str):
-    from chipbench.run import BenchError
-
     raise BenchError(msg)
 
 
@@ -249,7 +241,7 @@ def op_names(run) -> Dict[str, str]:
     from its profile (and kept on the run).  The run fails if there are
     none, or if they are not the traced program's."""
     if getattr(run, "op_names", None) is None:
-        path = profile_path()
+        path = profile_path(run)
         if path is None:
             _fail("found no profile of this traced run to read the "
                   "program's op names from")

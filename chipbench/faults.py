@@ -1,7 +1,8 @@
 """Faults planted in the timed path, to show that `correct` catches them.
 
-Each wraps the program's training step or forward pass before it is
-compiled (the run's ``train_step_fn`` / ``forward_fn`` hooks).  The
+Each wraps the ``deformable-detr`` family's training step or forward
+pass before it is compiled (the run's ``train_step_fn`` / ``forward_fn``
+hooks, which ``chipbench/families/deformable-detr.py`` applies).  The
 tests drive whole runs with them at a size a CPU holds; the calibration
 reads them on the chip at a cell's own size.  The cells run on one chip,
 so an exchange between chips left out does not arise.

@@ -1,4 +1,6 @@
-"""The one traffic generator: synthetic detection batches.
+"""The ``deformable-detr`` family's traffic generator
+(``chipbench/families/deformable-detr.py``): synthetic detection
+batches.
 
 It copies the semantics, dtypes and shapes of the program's
 ``detection`` data source: a float32 pyramid of N(0, std) features over

@@ -1,4 +1,5 @@
-"""Plain Deformable-DETR in float32: the yardstick that decides `correct`.
+"""Plain Deformable-DETR in float32: the yardstick that decides `correct`
+for the ``deformable-detr`` family (``chipbench/families/deformable-detr.py``).
 
 Written from the layer equations alone; it imports nothing of the
 program.  Parameters come in the program's tree layout (dicts named as

@@ -10,10 +10,9 @@ plane, on a clock that the device's can lead by about a millisecond.
 An op event is named by its HLO instruction (``%fusion.3 = f32[...]
 fusion(...)``).  Control-flow ops (``while``, ``conditional``, ``call``)
 span the ops they run and are left out.  A Pallas kernel is a
-``tpu_custom_call``; the program gives its kernels no stable name yet, so
-the MSDA forward kernel is told from the VJP's by its operands: the
-gather takes three (corner rows, weights, value slab), the scatter four
-(the same and the cotangent).
+``tpu_custom_call``, named after the kernel where the program names it
+(``chipbench/device_scopes.py`` finds the MSDA kernels so); the
+breakdown labels a kernel event with its operand count as well.
 
 Everything here is plain arithmetic on (name, start, end) triples, so it
 is tested on a small trace recorded on the chip.
@@ -159,13 +158,6 @@ def op_seconds(events: Sequence[Event]) -> Dict[str, float]:
     for n, s, e in events:
         tot[n] = tot.get(n, 0.0) + (e - s) * 1e-9
     return tot
-
-
-def kernel_seconds(events: Sequence[Event], operands: int) -> Tuple[float, int]:
-    """Total seconds and count of the Pallas kernel events with
-    ``operands`` operands."""
-    sel = [(s, e) for n, s, e in events if pallas_operands(n) == operands]
-    return sum(e - s for s, e in sel) * 1e-9, len(sel)
 
 
 def breakdown(tr: Trace, lo: float, hi: float, top: int = 10) -> dict:

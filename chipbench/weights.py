@@ -1,5 +1,7 @@
-"""Weights made by the benchmark from the seed, on the device, in one
-jitted call, in the program's parameter layout.
+"""Weights of the ``deformable-detr`` family
+(``chipbench/families/deformable-detr.py``), made by the benchmark from
+the seed, on the device, in one jitted call, in the program's parameter
+layout.
 
 The distributions are the registered model's initialisation: LeCun
 normal matrices, unit norm scales, zero biases, embeddings at 0.02, and

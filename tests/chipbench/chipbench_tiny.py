@@ -6,7 +6,8 @@ to two levels (16x16, 8x8) and the depth to 3+3 layers.
 
 It holds a ``BENCHMARK.json`` with one tiny training cell and one tiny
 inference cell, their configuration, traffic and limits files, copies
-of the benchmark's metric readers, and a link to the program's ``src``.
+of the benchmark's metric readers and model families, and a link to the
+program's ``src``.
 """
 from __future__ import annotations
 
@@ -20,7 +21,8 @@ if REPO not in sys.path:  # the benchmark package sits at the checkout root
     sys.path.insert(0, REPO)
 
 TINY_CONFIG = {
-    "name": "tiny-detr", "source": "test", "registered": "deformable-detr",
+    "name": "tiny-detr", "family": "deformable-detr", "source": "test",
+    "registered": "deformable-detr",
     "levels": [[16, 16], [8, 8]], "d_model": 256, "num_heads": 8,
     "head_dim": 32, "num_points": 4, "encoder_layers": 3, "decoder_layers": 3,
     "d_ff": 1024, "num_queries": 300, "num_classes": 91, "act": "gelu",
@@ -75,8 +77,10 @@ def make_root(path: str) -> str:
     cb = os.path.join(path, "chipbench")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(cb, sub), exist_ok=True)
-    shutil.copytree(os.path.join(REPO, "chipbench", "metrics"),
-                    os.path.join(cb, "metrics"), dirs_exist_ok=True)
+    for sub in ("metrics", "families"):
+        shutil.copytree(os.path.join(REPO, "chipbench", sub),
+                        os.path.join(cb, sub), dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
 
     def dump(rel, obj):
         with open(os.path.join(path, rel), "w") as f:

@@ -12,9 +12,10 @@ program's own runs come out correct in ``test_chipbench_faults.py``.
 import pytest
 
 import chipbench_tiny
-from chipbench import checks, generate, reference, weights
+from chipbench import catalog, checks, generate, reference, weights
 
 CFG = chipbench_tiny.TINY_CONFIG
+DETR = catalog.family(CFG, chipbench_tiny.REPO)
 
 
 def control_correct(mode, seed):
@@ -26,10 +27,10 @@ def control_correct(mode, seed):
                   "batches": batches[:traffic["checked_steps"]]}
     else:
         inputs = {"params": params, "batches": batches, "answered": [0, 1]}
-    ref = checks.REFERENCE[mode](CFG, traffic, inputs)
-    low = checks.REFERENCE[mode](CFG, traffic, inputs, reference.FLOAT8)
+    ref = DETR.REFERENCE[mode](CFG, traffic, inputs)
+    low = DETR.REFERENCE[mode](CFG, traffic, inputs, reference.FLOAT8)
     limits = chipbench_tiny.LIMITS[f"tiny-{mode}"]
-    return checks.judge(checks.NUMBERS[mode](low, ref), limits)["correct"]
+    return checks.judge(DETR.NUMBERS[mode](low, ref), limits)["correct"]
 
 
 @pytest.mark.parametrize("seed", chipbench_tiny.CONTROL_SEEDS[:3])

@@ -17,6 +17,7 @@ from chipbench.run import BenchError, TraceRun
 
 REPO = chipbench_tiny.REPO
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+DETR = catalog.family(chipbench_tiny.TINY_CONFIG, REPO)
 
 
 def _varint(n):
@@ -192,7 +193,8 @@ def _synthetic(events=STEP, op_names=None, mode="train"):
         op_names = {trace.op_kind(n)[0]: o for n, o, _, _ in events if o}
     cfg = {"encoder_layers": 1, "decoder_layers": 1}
     plans = {"encoder": _Plan(1, 1, spec), "decoder": _Plan(0, 0, spec)}
-    run = TraceRun(tr, cfg, {"mode": mode, "batch": 1}, plans,
+    run = TraceRun(tr, cfg, {"mode": mode, "batch": 1},
+                   DETR.msda_calls(cfg, mode, plans),
                    peaks.peaks("TPU v5 lite"), 2, 1, 1.0, 0, 1000)
     run.op_names = op_names
     return run
@@ -239,7 +241,7 @@ def test_new_readers_fail_without_op_names(name):
     with pytest.raises(BenchError, match="no op names"):
         _read(name, _synthetic(op_names={}))
     # none handed over, and no traced run's profile to read them from
-    # (this process is no ``chipbench.run`` command line)
+    # (the run carries no profile directory)
     run = _synthetic()
     run.op_names = None
     with pytest.raises(BenchError, match="found no profile"):
@@ -248,14 +250,14 @@ def test_new_readers_fail_without_op_names(name):
 
 @pytest.mark.parametrize("name", NEW)
 def test_new_readers_fail_on_another_named_kernel_count(name):
-    # the recomputed gather left unnamed: the operand count still finds
-    # it, the name does not
+    # the recomputed gather left unnamed: the operand count would still
+    # find it, the name does not, and no reader counts it
     step = [(_kernel("op.4", 3), *rest[1:]) if i == 3 else rest
             for i, rest in enumerate(STEP)]
     run = _synthetic(step)
-    assert _read("msda_fwd_roofline", run) > 0
-    with pytest.raises(BenchError, match="named 'msda_gather'"):
-        _read(name, run)
+    for reader in (name, "msda_fwd_roofline"):
+        with pytest.raises(BenchError, match="named 'msda_gather'"):
+            _read(reader, run)
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -304,6 +306,29 @@ def chip_trace():
     return trace.load(os.path.join(DATA, "msda_small.xplane.pb"))
 
 
+KERNEL_BY_OPERANDS = {3: "msda_gather", 4: "msda_scatter"}
+
+
+def _by_operands(events, operands):
+    """Seconds and count of the Pallas kernel events with ``operands``
+    operands: how the MSDA kernels were found before they had names."""
+    sel = [(s, e) for n, s, e in events if trace.pallas_operands(n) == operands]
+    return sum(e - s for s, e in sel) * 1e-9, len(sel)
+
+
+def _named_as_now(tr):
+    """A copy of a trace recorded before the program named its kernels,
+    each MSDA kernel event renamed as the program names it now (the
+    gather takes three operands, the scatter four)."""
+    def rename(i, n):
+        kernel = KERNEL_BY_OPERANDS.get(trace.pallas_operands(n))
+        return re.sub(r"^%[\w.-]+", f"%{kernel}.{i}", n) if kernel else n
+    return trace.Trace(
+        device_ops={p: [(rename(i, n), s, e) for i, (n, s, e) in enumerate(evs)]
+                    for p, evs in tr.device_ops.items()},
+        host_spans=list(tr.host_spans))
+
+
 def _pinned_run(chip_trace):
     from repro.kernels.plan import MsdaSpec
 
@@ -315,9 +340,12 @@ def _pinned_run(chip_trace):
                            "deformable-detr.json")) as f:
         cfg = dict(json.load(f), encoder_layers=1, decoder_layers=1)
     plans = {"encoder": _Plan(1, 1, spec), "decoder": _Plan(0, 0, spec)}
-    return TraceRun(chip_trace, cfg, {"mode": "infer", "batch": 1}, plans,
+    return TraceRun(_named_as_now(chip_trace), cfg,
+                    {"mode": "infer", "batch": 1},
+                    DETR.msda_calls(cfg, "infer", plans),
                     peaks.peaks("TPU v5 lite"), calls, calls,
-                    (hi - lo) * 1e-9, lo, hi)
+                    (hi - lo) * 1e-9, lo, hi,
+                    flops_per_image=DETR.flops_per_image(cfg, "infer"))
 
 
 @pytest.mark.parametrize("name", sorted(PINNED))
@@ -415,14 +443,13 @@ def compiled_programs():
     import jax.numpy as jnp
 
     from chipbench import generate, weights
-    from chipbench.run import program_config
     from repro.core import deformable_transformer as dt
     from repro.optim import adamw
     from repro.train import loop as train_loop
     from repro.train.state import TrainState
 
     cfg = chipbench_tiny.TINY_CONFIG
-    mcfg = program_config(cfg)
+    mcfg = DETR.program_config(cfg)
     mcfg = dataclasses.replace(
         mcfg, msda=dataclasses.replace(mcfg.msda, backend="pallas"))
 
@@ -502,7 +529,7 @@ def test_named_kernels_are_the_operand_counted_ones(named):
     tr, _ = named
     calls = sum(1 for n, _, _ in tr.host_spans if n == "chipbench.dispatch")
     for events in tr.device_ops.values():
-        for name, operands in (("msda_gather", 3), ("msda_scatter", 4)):
+        for operands, name in KERNEL_BY_OPERANDS.items():
             by_name = [(s, e) for n, s, e in events
                        if device_scopes.kernel_name(n) == name]
             by_operands = [(s, e) for n, s, e in events
@@ -532,16 +559,19 @@ def test_named_trace_through_the_traced_run(named):
     lo, hi = tr.window()
     cfg = {"encoder_layers": 1, "decoder_layers": 1}
     plans = {"encoder": _Plan(1, 1), "decoder": _Plan(0, 0)}
-    run = TraceRun(tr, cfg, {"mode": "infer", "batch": 1}, plans, None,
+    run = TraceRun(tr, cfg, {"mode": "infer", "batch": 1},
+                   DETR.msda_calls(cfg, "infer", plans), None,
                    calls, calls, (hi - lo) * 1e-9, lo, hi)
     run.op_names = names
     ds = device_scopes
+    by_operands = {d: _by_operands(run.device_events(), n)[0]
+                   for d, n in (("fwd", 3), ("bwd", 4))}
     for direction in ("fwd", "bwd"):
         assert ds.named_kernel_seconds(run, direction) == pytest.approx(
-            run.msda_kernel_seconds(direction), rel=1e-12)
+            by_operands[direction], rel=1e-12)
         assert ds.scope_seconds(run, "msda_kernel", direction) == (
-            pytest.approx(run.msda_kernel_seconds(direction), rel=1e-12))
-    kernels = run.msda_kernel_seconds("fwd") + run.msda_kernel_seconds("bwd")
+            pytest.approx(by_operands[direction], rel=1e-12))
+    kernels = by_operands["fwd"] + by_operands["bwd"]
     ops = ds.scope_seconds(run, "msda_fwd") + ds.scope_seconds(run, "msda_bwd")
     assert kernels < ops < run.busy_s
 
@@ -576,19 +606,18 @@ def _profile_dir(tmp_path, source):
     return tmp_path
 
 
-def test_a_reader_finds_the_profile_of_its_run(tmp_path, monkeypatch):
+def test_a_reader_finds_the_profile_of_its_run(tmp_path):
     named = os.path.join(DATA, "msda_small_named.xplane.pb")
-    argv = ["run.py", "--workload", "w", "--seed", "1", "--seconds", "1",
-            "--trace", "1"]
-    monkeypatch.setattr("sys.argv", argv)
-    assert device_scopes.profile_path() is None  # no traced run here
+    run = _synthetic()
+    assert device_scopes.profile_path(run) is None  # it carries no directory
+    run.trace_dir = str(tmp_path / "empty")
+    assert device_scopes.profile_path(run) is None  # nor a profile in it
     d = _profile_dir(tmp_path, named)
-    monkeypatch.setattr("sys.argv", argv + ["--trace-dir", str(d)])
-    assert device_scopes.profile_path() == str(
+    run.trace_dir = str(d)
+    assert device_scopes.profile_path(run) == str(
         d / "plugins" / "profile" / "2026_10_18_00_00_00" / "host.xplane.pb")
     # a run of another program than the profile's: its instructions are
     # not in the profile's HLO, and the run fails
-    run = _synthetic()
     run.op_names = None
     with pytest.raises(BenchError, match="holds none of"):
         device_scopes.attributed(run)
@@ -596,10 +625,12 @@ def test_a_reader_finds_the_profile_of_its_run(tmp_path, monkeypatch):
     tr = trace.load(named)
     calls = sum(1 for n, _, _ in tr.host_spans if n == "chipbench.dispatch")
     lo, hi = tr.window()
-    own = TraceRun(tr, {"encoder_layers": 1, "decoder_layers": 1},
-                   {"mode": "infer", "batch": 1},
-                   {"encoder": _Plan(1, 1), "decoder": _Plan(0, 0)}, None,
-                   calls, calls, (hi - lo) * 1e-9, lo, hi)
+    cfg = {"encoder_layers": 1, "decoder_layers": 1}
+    own = TraceRun(tr, cfg, {"mode": "infer", "batch": 1},
+                   DETR.msda_calls(cfg, "infer", {"encoder": _Plan(1, 1),
+                                                  "decoder": _Plan(0, 0)}),
+                   None, calls, calls, (hi - lo) * 1e-9, lo, hi,
+                   trace_dir=str(d))
     split = device_scopes.scope_split(own)
     assert own.op_names == device_scopes.profile_op_names(named)[0]
     assert sum(split.values()) == pytest.approx(own.busy_s, rel=1e-9)

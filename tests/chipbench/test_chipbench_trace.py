@@ -1,17 +1,20 @@
 """The reduction from a profiler trace to metrics: by hand on a few
-intervals, and on a small trace recorded on a TPU v5e
-(``data/msda_small.xplane.pb``, written by ``record_trace.py``: three
-forward+VJP calls of a small MSDA Pallas plan under the benchmark's own
-window and spans)."""
+intervals, and on small traces recorded on a TPU v5e
+(``data/msda_small.xplane.pb`` and, once the program named its kernels,
+``data/msda_small_named.xplane.pb``, written by ``record_trace.py``:
+three forward+VJP calls of a small MSDA Pallas plan under the
+benchmark's own window and spans)."""
 import os
 
 import pytest
 
-import chipbench_tiny  # noqa: F401  (puts the checkout root on sys.path)
-from chipbench import trace
+import chipbench_tiny
+from chipbench import catalog, device_scopes, trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                     "msda_small.xplane.pb")
+NAMED = os.path.join(os.path.dirname(DATA), "msda_small_named.xplane.pb")
+DETR = catalog.family(chipbench_tiny.TINY_CONFIG, chipbench_tiny.REPO)
 EVENTS = [("a", 0, 10), ("b", 5, 20), ("a", 30, 40), ("c", 38, 45),
           ("d", 60, 61)]
 
@@ -42,9 +45,13 @@ def test_op_names_and_kernel_seconds():
     assert trace.pallas_operands(SCATTER) == 4
     assert trace.pallas_operands("%fusion.1 = f32[2] fusion(%a), kind=kLoop") == 0
     assert trace.short_name(SCATTER) == "%op.55 pallas kernel, 4 operands"
-    evs = [(GATHER, 0, 5), (SCATTER, 5, 20), (GATHER, 20, 24)]
-    assert trace.kernel_seconds(evs, 3) == (pytest.approx(9e-9), 2)
-    assert trace.kernel_seconds(evs, 4) == (pytest.approx(15e-9), 1)
+    gather = GATHER.replace("%op.16", "%msda_gather.16")
+    scatter = SCATTER.replace("%op.55", "%msda_scatter.55")
+    evs = [(gather, 0, 5), (scatter, 5, 20), (gather, 20, 24), (GATHER, 24, 30)]
+    assert device_scopes.trace_kernel_seconds(evs, "msda_gather") == (
+        pytest.approx(9e-9), 2)
+    assert device_scopes.trace_kernel_seconds(evs, "msda_scatter") == (
+        pytest.approx(15e-9), 1)
 
 
 def test_host_activity_labels_gaps():
@@ -70,6 +77,18 @@ def chip_trace():
     return trace.load(DATA)
 
 
+@pytest.fixture(scope="module")
+def named_trace():
+    return trace.load(NAMED)
+
+
+def _by_operands(events, operands):
+    """Seconds and count of the Pallas kernel events with ``operands``
+    operands: the gather takes three, the scatter four."""
+    sel = [(s, e) for n, s, e in events if trace.pallas_operands(n) == operands]
+    return sum(e - s for s, e in sel) * 1e-9, len(sel)
+
+
 def test_chip_trace_planes_and_window(chip_trace):
     assert trace.first_device(chip_trace) == "/device:TPU:0"
     lo, hi = chip_trace.window()
@@ -85,8 +104,8 @@ def test_chip_trace_kernels_one_per_call(chip_trace):
     events = chip_trace.device_ops["/device:TPU:0"]
     calls = sum(1 for n, _, _ in chip_trace.host_spans
                 if n == "chipbench.dispatch")
-    fwd_s, n_fwd = trace.kernel_seconds(events, 3)
-    bwd_s, n_bwd = trace.kernel_seconds(events, 4)
+    fwd_s, n_fwd = _by_operands(events, 3)
+    bwd_s, n_bwd = _by_operands(events, 4)
     assert n_fwd == n_bwd == calls
     assert 0 < fwd_s < bwd_s
 
@@ -120,7 +139,8 @@ def _traced_run(chip_trace, mode, encoder_layers):
     lo, hi = chip_trace.window()
     cfg = {"encoder_layers": encoder_layers, "decoder_layers": 1}
     plans = {"encoder": _Plan(1, 1), "decoder": _Plan(0, 0)}
-    return TraceRun(chip_trace, cfg, {"mode": mode, "batch": 1}, plans, None,
+    return TraceRun(chip_trace, cfg, {"mode": mode, "batch": 1},
+                    DETR.msda_calls(cfg, mode, plans), None,
                     calls, calls, (hi - lo) * 1e-9, lo, hi)
 
 
@@ -133,18 +153,19 @@ def test_msda_launches_follow_the_plans_and_remat(chip_trace):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_msda_kernel_seconds_when_the_count_matches(chip_trace, direction):
-    # the recorded trace: one plan call per window call, one launch each way
-    run = _traced_run(chip_trace, "infer", encoder_layers=1)
+def test_msda_kernel_seconds_when_the_count_matches(named_trace, direction):
+    # the recorded trace: one plan call per window call, one launch each
+    # way; the kernels found by name are those with the kernel's operands
+    run = _traced_run(named_trace, "infer", encoder_layers=1)
     operands = 3 if direction == "fwd" else 4
-    seconds = run.msda_kernel_seconds(direction)
-    assert seconds == trace.kernel_seconds(run.device_events(), operands)[0] > 0
+    seconds = device_scopes.named_kernel_seconds(run, direction)
+    assert seconds == _by_operands(run.device_events(), operands)[0] > 0
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_msda_kernel_seconds_fail_on_another_count(chip_trace, direction):
+def test_msda_kernel_seconds_fail_on_another_count(named_trace, direction):
     from chipbench.run import BenchError
 
-    run = _traced_run(chip_trace, "infer", encoder_layers=2)
+    run = _traced_run(named_trace, "infer", encoder_layers=2)
     with pytest.raises(BenchError, match="committed plans launch"):
-        run.msda_kernel_seconds(direction)
+        device_scopes.named_kernel_seconds(run, direction)

@@ -7,9 +7,10 @@ import os
 import pytest
 
 import chipbench_tiny
-from chipbench import peaks, work
+from chipbench import catalog, peaks, work
 
 REPO = chipbench_tiny.REPO
+DETR = catalog.family(chipbench_tiny.TINY_CONFIG, REPO)
 LEVELS = ((256, 256), (128, 128), (64, 64), (32, 32), (16, 16))
 Q = 87296                    # 65536 + 16384 + 4096 + 1024 + 256
 SAMPLES = Q * 8 * 5 * 4      # queries x heads x levels x points = 13,967,360
@@ -68,9 +69,9 @@ def test_model_forward_flops_paper_width():
     heads = 307_200 + 13_977_600 + 39_321_600 + 614_400
     total = 6 * enc_layer + 6 * dec_layer + heads
     assert total == 914_925_361_152
-    assert work.model_forward_flops(cfg) == total
-    assert work.flops_per_image(cfg, "train") == 3 * total
-    assert work.flops_per_image(cfg, "infer") == total
+    assert DETR.model_forward_flops(cfg) == total
+    assert DETR.flops_per_image(cfg, "train") == 3 * total
+    assert DETR.flops_per_image(cfg, "infer") == total
 
 
 def test_model_forward_flops_coco800_levels():
@@ -79,7 +80,7 @@ def test_model_forward_flops_coco800_levels():
     S = 22_223
     enc_layer = (2 * S * 256 * 256 * 2 + 2 * S * 256 * 128 * 3
                  + 10 * S * 128 * 32 + 4 * S * 256 * 1024)
-    assert work.model_forward_flops(cfg) > 6 * enc_layer
+    assert DETR.model_forward_flops(cfg) > 6 * enc_layer
 
 
 def test_unknown_device_kind_is_an_error():
