@@ -142,18 +142,60 @@ def attention_plan(
     )
 
 
+def sampling_locations(
+    reference_points: jax.Array,  # (B, Q, 2) or (B, Q, Z, 2) normalised
+    off: jax.Array,  # (B, Q, H, L, P, 2) float32, in level pixels
+    levels,
+    valid_ratios: Optional[jax.Array] = None,  # (B, L, 2) x,y fractions
+) -> jax.Array:
+    """Normalised sample locations (B, Q, H, L, P, 2): each point's offset
+    over its level's (w, h), around its query's reference point.
+
+    ``reference_points`` holds one (x, y) per query, or ``Z`` anchors per
+    query, ``(B, Q, Z, 2)``: point ``p`` of every (head, level) then
+    belongs to anchor ``p % Z`` (``P % Z == 0``), as BEVFormer's spatial
+    cross-attention spreads its points over a pillar's heights.
+    """
+    B, Q, H, L, Pn = off.shape[:5]
+    wh = jnp.asarray([[w, h] for (h, w) in levels], jnp.float32)  # (L,2) x,y order
+    if reference_points.ndim == 4:
+        # (B, Q, Z, 2) anchors: the points of each (head, level) as
+        # (P/Z, Z), point p on anchor p % Z
+        Z = reference_points.shape[2]
+        if Pn % Z or valid_ratios is not None:
+            raise ValueError(f"{Z} anchors per query need num_points "
+                             f"% Z == 0 and no valid_ratios")
+        refs = reference_points[:, :, None, None, None, :, :]
+        off = off.reshape(B, Q, H, L, Pn // Z, Z, 2)
+        loc = refs + off / wh[None, None, None, :, None, None, :]
+        return loc.reshape(B, Q, H, L, Pn, 2)
+    refs = reference_points[:, :, None, None, None, :]
+    if valid_ratios is not None:
+        # bucketed serving (Deformable-DETR valid_ratios): the pyramid
+        # only occupies the top-left (w*rx, h*ry) region of each padded
+        # level.  Scaling the REFERENCE POINTS by the ratio (offsets stay
+        # normalised by the padded extents wh) lands every sample on the
+        # same pixel coordinate as in the unpadded level:
+        # (x*r)*W - 0.5 == x*w - 0.5, and pad-region corners gather the
+        # zeros that out-of-range corners contributed anyway.
+        refs = refs * valid_ratios[:, None, None, :, None, :].astype(jnp.float32)
+    return refs + off / wh[None, None, None, :, None, :]
+
+
 def msda_attention(
     p: dict,
     msda_cfg,
     query: jax.Array,  # (B, Q, d)
     value_feats: jax.Array,  # (B, S, d)
-    reference_points: jax.Array,  # (B, Q, 2) normalised
+    reference_points: jax.Array,  # (B, Q, 2) or (B, Q, Z, 2) normalised
     *,
     train: bool = False,
     backend: Optional[str] = None,
     query_parallel: bool = False,
     valid_ratios: Optional[jax.Array] = None,  # (B, L, 2) x,y fractions
 ) -> jax.Array:
+    """Deformable attention of ``query`` over the pyramid ``value_feats``
+    around ``reference_points`` (see :func:`sampling_locations`)."""
     levels = msda_cfg.levels
     L, H, Pn = len(levels), msda_cfg.num_heads, msda_cfg.num_points
     B, Q, d = query.shape
@@ -163,18 +205,7 @@ def msda_attention(
 
         off = query @ p["w_offsets"].astype(query.dtype) + p["b_offsets"].astype(query.dtype)
         off = off.reshape(B, Q, H, L, Pn, 2).astype(jnp.float32)
-        wh = jnp.asarray([[w, h] for (h, w) in levels], jnp.float32)  # (L,2) x,y order
-        refs = reference_points[:, :, None, None, None, :]
-        if valid_ratios is not None:
-            # bucketed serving (Deformable-DETR valid_ratios): the pyramid
-            # only occupies the top-left (w*rx, h*ry) region of each padded
-            # level.  Scaling the REFERENCE POINTS by the ratio (offsets stay
-            # normalised by the padded extents wh) lands every sample on the
-            # same pixel coordinate as in the unpadded level:
-            # (x*r)*W - 0.5 == x*w - 0.5, and pad-region corners gather the
-            # zeros that out-of-range corners contributed anyway.
-            refs = refs * valid_ratios[:, None, None, :, None, :].astype(jnp.float32)
-        loc = refs + off / wh[None, None, None, :, None, :]
+        loc = sampling_locations(reference_points, off, levels, valid_ratios)
 
         aw = query @ p["w_weights"].astype(query.dtype) + p["b_weights"].astype(query.dtype)
         aw = jax.nn.softmax(aw.reshape(B, Q, H, L * Pn).astype(jnp.float32), axis=-1)

@@ -27,6 +27,14 @@ Scopes, outermost first:
   ``msda_grad_unpack`` (grad slabs and weight-table grads unpacked).
 * ``matching``, ``loss``: the detection loss; ``optimizer``: the
   mixed-precision copies of weights and gradients, clipping and AdamW.
+* BEVFormer (``repro.models.bevformer``), inside ``encoder``:
+  ``bev_temporal`` (the can_bus MLP, the ego shift of the reference
+  points and the rotation of the previous BEV), ``bev_tsa`` (temporal
+  self-attention and its projections), ``bev_sca`` (spatial
+  cross-attention) and, inside it, ``sca_rebatch`` (the pillars'
+  projection into the cameras, the hit mask, the packing of each
+  camera's hit queries, the scatter back and the division by the
+  cameras hit).
 """
 from __future__ import annotations
 
@@ -49,6 +57,10 @@ MSDA_SLAB = "msda_slab"
 MSDA_KERNEL = "msda_kernel"
 MSDA_REDUCE = "msda_reduce"
 MSDA_GRAD_UNPACK = "msda_grad_unpack"
+BEV_TEMPORAL = "bev_temporal"
+BEV_TSA = "bev_tsa"
+BEV_SCA = "bev_sca"
+SCA_REBATCH = "sca_rebatch"
 
 # Pallas kernel names (``pl.pallas_call(name=...)``): the HLO custom call
 # and its trace event are named after them, and JAX puts them in the
@@ -60,7 +72,7 @@ KERNELS = {"fwd": GATHER_KERNEL, "bwd": SCATTER_KERNEL}
 SCOPES = (ENCODER, DECODER, SELF_ATTN, FFN, HEADS, MATCHING, LOSS,
           OPTIMIZER, MSDA_PROJ, MSDA_FWD, MSDA_BWD, MSDA_TABLES, MSDA_SLAB,
           MSDA_KERNEL, MSDA_REDUCE, MSDA_GRAD_UNPACK, GATHER_KERNEL,
-          SCATTER_KERNEL)
+          SCATTER_KERNEL, BEV_TEMPORAL, BEV_TSA, BEV_SCA, SCA_REBATCH)
 # the MSDA op as a whole, and its XLA work around the kernels
 MSDA_OPS = (MSDA_FWD, MSDA_BWD)
 MSDA_XLA = (MSDA_TABLES, MSDA_SLAB, MSDA_REDUCE, MSDA_GRAD_UNPACK)

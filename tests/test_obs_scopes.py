@@ -36,6 +36,16 @@ CASES = [
      ("matching",), "fwd"),
     ("jit(train_step)/transpose(jvp(loss))/vmap()/mul", ("loss",), "bwd"),
     ("jit(train_step)/optimizer/sqrt", ("optimizer",), "fwd"),
+    # BEVFormer: the temporal prologue, TSA, SCA and its re-batch
+    ("jit(frame)/encoder/bev_temporal/dot_general",
+     ("encoder", "bev_temporal"), "fwd"),
+    ("jit(frame)/encoder/while/body/closed_call/bev_tsa/msda_proj/"
+     "dot_general", ("encoder", "bev_tsa", "msda_proj"), "fwd"),
+    ("jit(frame)/encoder/while/body/closed_call/bev_sca/jit(op)/msda_fwd/"
+     "msda_kernel/msda_gather/pallas_call",
+     ("encoder", "bev_sca", "msda_fwd", "msda_kernel", "msda_gather"), "fwd"),
+    ("jit(frame)/encoder/while/body/closed_call/bev_sca/sca_rebatch/gather",
+     ("encoder", "bev_sca", "sca_rebatch"), "fwd"),
     # under no known scope: argument names, JAX's own top-level ops
     ("jit(train_step)/broadcast_in_dim", (), "fwd"),
     ("state.params['enc_layers']['mlp']['wi']", (), "fwd"),

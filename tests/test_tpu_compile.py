@@ -10,6 +10,8 @@ chip's compiler would refuse (tiling, VMEM / SMEM overflow, unsupported
 ops) and a program that does not fit the chip's HBM, without a chip.
 """
 import dataclasses
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +88,37 @@ def test_detr_plan_compiles_for_v5e(name, direction, one_chip,
     total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
              + ma.output_size_in_bytes)
     assert total <= V5E_HBM_BYTES, total
+
+
+def _bevformer_plans():
+    """BEVFormer-base's forward plans at the widths of ``bevformer-base``:
+    the largest camera's SCA call (9,664 packed queries, 4 levels x 8
+    points: the gather's rolled loop), TSA's and the decoder's (one
+    200x200 level, 4 points: the unrolled body)."""
+    from repro.models import bevformer as bf
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                           "configs", "bevformer-base.json")) as f:
+        cfg = bf.BEVFormerConfig.from_dict(json.load(f), cam_bounds=(9664,))
+    return {n: p.spec for n, p in bf.msda_plans(cfg).items()}
+
+
+@pytest.mark.parametrize("name", ["sca.cam0", "tsa", "decoder"])
+def test_bevformer_plan_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    spec = dataclasses.replace(
+        _bevformer_plans()[name],
+        vmem_budget=plan_mod.default_vmem_budget("TPU v5 lite"))
+    plan = plan_mod.msda_plan(spec, backend="pallas", interpret=False)
+    assert plan.launches_per_call() == {"fwd": 1, "bwd": 0}
+    s = plan.spec
+    L, P, H, D = s.num_levels, s.num_points, s.num_heads, s.head_dim
+    shape = lambda *dims, dt: jax.ShapeDtypeStruct(dims, dt,  # noqa: E731
+                                                   sharding=one_chip)
+    compiled = jax.jit(plan).lower(
+        shape(1, s.total_pixels, H, D, dt=jnp.float32),
+        shape(1, s.num_queries, H, L, P, 2, dt=jnp.float32),
+        shape(1, s.num_queries, H, L, P, dt=jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 # every kernel variant a plan can commit, at a small 3-level pyramid:
