@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.msda_fwd import (
     LevelGeom, compiler_params, corner_offset, corner_walk, fetch_row,
     idx_slot, keep_corner, kernel_metadata, lane_heads, resident, saved_slot,
-    static_loop, table_specs, w_slot)
+    static_loop, table_spec, w_slot)
 from repro.obs import scopes
 
 
@@ -160,7 +160,9 @@ def msda_scatter(
     gval, gw = pl.pallas_call(
         kernel,
         grid=(B, NG, nq),
-        in_specs=table_specs(block_q, G, L, P, NG, nq) + [
+        in_specs=[
+            table_spec(G * block_q * L * P, NG, nq),
+            table_spec(4 * G * block_q * L * P, NG, nq),
             pl.BlockSpec((None, None, block_q, GD),
                          lambda b, g, q: (b, g, q, 0)),
             src_spec,

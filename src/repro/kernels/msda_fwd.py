@@ -8,22 +8,25 @@ Paper mapping (xMSDA §4.1 -> TPU):
 * **Scalar-addressed row gather.**  Mosaic has no general vector gather,
   so — like the paper's scalar-addressed pixel copies out of the on-chip
   buffer — the top-left corner row of every (query, head, level, point)
-  and its four bilinear weights arrive in SMEM, computed outside the
-  kernel by cheap element-wise XLA (:func:`corner_indices`).  The
-  x-pair partner sits at ``idx + 1`` and the y-pair partner at
-  ``idx + Wp`` of the row-major slab, so a point's four corners are two
-  two-row loads (:func:`fetch_pair`), branch-free.
-* **The row loop's scalar budget.**  The scalar unit sets the pace: a
-  bundle has two scalar slots, at most one of them reading SMEM, and a
-  gathered row costs a weight read and its address, plus a corner-row
-  read, its address and a VMEM address per point or row pair.  Each
-  (level, head) pair is one accumulation chain.  Once a query's rows
-  exceed :data:`UNROLLED_ROWS` the point loop is rolled and one step of
-  it advances every chain by a point: the chains' adds hide each
-  other's latency, and the step's L*G*5 table values stay in the scalar
-  register file (unrolled over every point, the scheduler hoists them
-  all and spills them to SMEM).  A table read is its step's base,
-  carried by the loop, plus a static offset: one add per read.
+  arrives in SMEM, computed outside the kernel by cheap element-wise XLA
+  (:func:`corner_indices`).  The x-pair partner sits at ``idx + 1`` and
+  the y-pair partner at ``idx + Wp`` of the row-major slab, so a point's
+  four corners are two two-row loads (:func:`fetch_pair`), branch-free.
+  A table read is its step's base, carried by the loop, plus a static
+  offset: one add per read.
+* **Weights as vector operands.**  The corner weights arrive as VMEM
+  rows, G heads' weights of a query on the lanes of one or more rows
+  (:func:`weight_layout`).  The MXU spreads each head's weight over its
+  segment of lanes (:func:`spread_weights`), the G heads' corner rows
+  of a level merge into one row by lane selects, and one ``acc + w *
+  row`` advances the level's accumulation chain: every lane adds what a
+  scalar weight would, in the same order, so the output is bitwise that
+  of a walk with scalar weights.  The scalar unit keeps only the corner
+  rows and their addresses.
+* **The walk.**  A step gathers :func:`step_points` points of every
+  chain, at most :data:`STEP_ROWS` rows: the whole query when it fits,
+  else a rolled loop of steps, each of which spreads the next step's
+  weights on the MXU while it gathers, so no chain waits for them.
 * **Heads on lanes.**  The slab is ``(rows, G*D)``: G heads of one group
   side by side on the lane axis (G*D = 128 for the DETR head_dim 32), so
   VMEM and HBM hold no lane padding.  A row load fetches all G heads of a
@@ -159,10 +162,95 @@ def idx_slot(h, q, l, p, qb: int, L: int, P: int):
 
 
 def w_slot(h, q, l, c, p, qb: int, L: int, P: int):
-    """Entry of a corner weight in a block's weight table chunk, laid out
-    (head, saved slot, query) — also the layout the backward's weight
-    grads come out in."""
+    """Entry of a corner weight in a block's backward weight table chunk,
+    laid out (head, saved slot, query) — also the layout the backward's
+    weight grads come out in."""
     return (h * (L * 4 * P) + saved_slot(l, c, p, P)) * qb + q
+
+
+def weight_layout(L: int, P: int, D: int) -> Tuple[int, int]:
+    """``(rows per query, slots per point)`` of the forward's weight rows.
+
+    A query's corner weights are ``R`` rows of ``G*D`` lanes; lane
+    ``D*h + j`` of a row holds head ``h``'s weight for slot ``j``.  A
+    point's ``4L`` slots are (level, corner) ``4l + c``.  All points
+    share one row when ``4LP <= D``; otherwise each point starts a row,
+    and a point whose ``4L`` slots outgrow ``D`` takes several.
+    """
+    slots = 4 * L
+    if slots * P <= D:
+        return 1, slots
+    per_point = -(-slots // D)
+    return P * per_point, per_point * D
+
+
+def weight_lane(h, l, c, p, L: int, P: int, D: int) -> Tuple[int, int]:
+    """``(row, lane)`` of head ``h``'s weight of (level, corner, point)
+    among one query's weight rows (:func:`weight_layout`)."""
+    t = p * weight_layout(L, P, D)[1] + 4 * l + c
+    return t // D, h * D + t % D
+
+
+# the split scale: a float32 weight times this is split into bf16 parts
+# that are all normal numbers (exact on a chip that flushes subnormals),
+# for every weight of magnitude below 2**103
+SPLIT_SCALE = 2.0 ** 24
+
+
+def _round8(n: int) -> int:
+    return -(-n // 8) * 8
+
+
+def slot_grid(n: int, stride: int, D: int, lanes: int):
+    """(round8(n), lanes) int32: the slot each lane of an expanded weight
+    block holds, less ``stride`` times its sublane, so that ``grid ==
+    first`` selects slot ``first + stride * j`` into sublane ``j``."""
+    shape = (_round8(n), lanes)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % D
+    return lane - stride * jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+
+
+def segment_matrix(D: int, lanes: int):
+    """(lanes, lanes) bf16 0/1: entry (k, n) is 1 where lanes k and n
+    belong to the same head."""
+    shape = (lanes, lanes)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // D
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1) // D
+            ).astype(jnp.bfloat16)
+
+
+def spread_weights(row, pick, seg):
+    """Weight rows out of one compact row, on the MXU.
+
+    ``row`` (1, G*D) holds each head's weights on its own lanes;
+    ``pick`` (n, G*D) bool keeps, in sublane ``j``, the lane of each
+    head's segment that holds slot ``j``.  Multiplying by the segment
+    matrix ``seg`` copies each head's kept weight to every lane of its
+    segment.  The float32 weights go through the MXU as three exact bf16
+    parts, ``hi + mid + lo``, each times a 0/1 entry and summed with
+    zeros only, so every lane gets its weight back bit for bit.  A
+    negative zero comes back as zero, and a subnormal as zero, as the
+    chip flushes it in any product: no accumulation can tell either.
+    """
+    return spread_parts(split_weights(row, pick), seg)
+
+
+def split_weights(row, pick):
+    """The MXU operand of :func:`spread_weights` as its three bf16 parts,
+    ``hi``, ``mid`` and ``lo``."""
+    x = jnp.where(pick, jnp.broadcast_to(row * SPLIT_SCALE, pick.shape), 0.0)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def spread_parts(parts, seg):
+    """The parts of :func:`split_weights` times the segment matrix,
+    summed back into float32 weights."""
+    hi, mid, lo = (jnp.dot(part, seg, preferred_element_type=jnp.float32)
+                   for part in parts)
+    return ((hi + mid) + lo) * (1 / SPLIT_SCALE)
 
 
 def row_block(q, k: int):
@@ -206,10 +294,20 @@ def fetch_pair(slab_ref, geom: LevelGeom, i):
     return two[0:1], two[1:2]
 
 
-# rows one unrolled query step may gather: past this, the table values
-# the scheduler keeps live outgrow the scalar register file and spill to
-# SMEM (on v5e a 64-row step spills 3 words, a 128-row one 131)
-UNROLLED_ROWS = 64
+# rows one step of the forward's walk gathers at most.  A longer step
+# hides the MXU's latency under more gathers but holds more corner-row
+# table values live: on v5e the paper encoder's 80-row points gather at
+# 1.19 bundles a row one to a step and 0.90 two to a step, SCA's 64-row
+# points at 0.89 four to a step and 0.94 eight (tools/kernel_schedule.py)
+STEP_ROWS = 256
+
+
+def step_points(L: int, G: int, P: int) -> int:
+    """Points one step of the forward's walk gathers: the most that
+    divide the query's ``P`` and keep the step within :data:`STEP_ROWS`
+    rows (at least one).  ``P`` of them make the step a whole query."""
+    return max(k for k in range(1, P + 1)
+               if P % k == 0 and (k == 1 or 4 * L * G * k <= STEP_ROWS))
 
 
 def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
@@ -217,87 +315,163 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                    qb: int, fuse_gather: bool, unroll: bool):
     """One (batch, head group, query block) step of the forward.
 
-    ``idx_ref`` / ``w_ref``: this block's chunk of the SMEM tables (see
-    :func:`idx_slot` / :func:`w_slot`), corner rows already lifted into
-    this launch's slab, weights with the validity mask and the attention
-    weight folded in.
+    ``idx_ref``: this block's chunk of the SMEM corner-row table (see
+    :func:`idx_slot`), corner rows already lifted into this launch's
+    slab.  ``w_ref``: the block's weight rows in VMEM (see
+    :func:`weight_layout`), validity mask and attention weight folded in.
 
-    Every (level, head) pair is one accumulation chain.  A step of the
-    walk's outer index (the point, or the corner of the corner-major
-    walk) advances every chain; the steps are a rolled loop when the
-    query's rows exceed :data:`UNROLLED_ROWS`.  The tables are linear in
-    their indices, so a read is a static offset from the step's base.
+    Every level is one accumulation chain over all G heads' lanes: the
+    heads' corner rows are merged by lane selects and one
+    ``acc + w * row`` advances the chain, with ``w`` a weight row spread
+    on the MXU (:func:`spread_weights`).  Each lane sees the same adds in
+    the same order as one (level, head) chain of scalar weights would.
+    A walk step gathers :func:`step_points` points of every chain (the
+    whole query when they are all of its points) and spreads the next
+    step's weights meanwhile, so no chain waits for the MXU; the steps
+    of a query are a rolled loop.  The corner-row table is linear in its
+    indices, so a read is a static offset from the step's base.
+    ``fuse_gather=False`` walks the corners corner-major instead, a
+    rolled step per corner when the query's rows exceed
+    :data:`STEP_ROWS`, spreading each point's weights as it goes.
     """
     L = len(levels)
+    S = 4 * L  # weight slots of one point
+    R, stride = weight_layout(L, P, D)
+    packed = R == 1
+    per_point = stride // D  # weight rows of a point when not packed
+    GD = G * D
     heads = lane_heads(G, D)
-    zero = jnp.zeros((1, G * D), jnp.float32)
-    rolled = not unroll or L * G * P * 4 > UNROLLED_ROWS
+    mine = [heads == h for h in range(G)]
+    zero = jnp.zeros((1, GD), jnp.float32)
     save = saved_ref is not None
+    seg = segment_matrix(D, GD)
+    # a query whose weights share one row is one step
+    k = P if packed else step_points(L, G, P)
+    steps = P // k
+    # the slot picks, built once per grid step
+    if not fuse_gather:
+        grid_corner = slot_grid(L, 4, D, GD)
+    elif packed:
+        pick_query = slot_grid(S * P, 1, D, GD) == 0
+    else:
+        pick_rows = [slot_grid(min(D, S - r * D), 1, D, GD) == 0
+                     for r in range(per_point)]
 
-    def point_step(q, p, carry):
-        # point p of every chain, its four corners as two row pairs;
-        # ``base`` == idx_slot(0, q, 0, p) == w_slot(0, q, 0, 0, p)
-        base, accs = carry
+    def weight_row(r):
+        # row r of the block's weight rows
+        return w_ref[pl.ds(r, 1), :]
+
+    def step_weights(u):
+        # the spread weights of the block's u-th walk step, one dot a
+        # weight row of its points
+        if packed:
+            return (spread_weights(weight_row(u), pick_query, seg),)
+        out = []
+        for r in range(per_point):
+            parts = [split_weights(weight_row((u * k + i) * per_point + r),
+                                   pick_rows[r]) for i in range(k)]
+            out.append(spread_parts(tuple(
+                jnp.concatenate(part, axis=0) for part in zip(*parts)), seg))
+        return tuple(out)
+
+    def point_weights(spread, i):
+        # the weight rows of the step's i-th point, slot 4l + c each
+        if packed:
+            return [spread[0][i * S + s:i * S + s + 1] for s in range(S)]
+        out = []
+        for r, e in enumerate(spread):
+            n = min(D, S - r * D)
+            base = i * _round8(n)
+            out += [e[base + j:base + j + 1] for j in range(n)]
+        return out
+
+    def corner_weights(q, p, c):
+        # corner c of point p, one weight row per level: rows of the
+        # point that do not hold a level's slot spread zeros
+        if packed:
+            e = spread_weights(weight_row(q), grid_corner == p * S + c, seg)
+        else:
+            e = None
+            for r in range(per_point):
+                part = spread_weights(weight_row((q * P + p) * per_point + r),
+                                      grid_corner == c - r * D, seg)
+                e = part if e is None else e + part
+        return [e[l:l + 1] for l in range(L)]
+
+    def merged(rows):
+        # each head's lanes from its own row
+        out = rows[0]
+        for h in range(1, G):
+            out = jnp.where(mine[h], rows[h], out)
+        return out
+
+    def keep(l, c, p, row):
+        if save:
+            row_scratch[pl.ds(saved_slot(l, c, p, P), 1), :] = row
+
+    def point_step(q, t, carry):
+        # points t*k .. t*k + k-1 of every chain, each point's four
+        # corners as two row pairs; ``base`` == idx_slot(0, q, 0, t*k)
+        base, accs, spread = carry
         accs = list(accs)
-        for l, geom in enumerate(levels):
-            kept = [zero] * 4  # the level's corners, each head on its lanes
-            for h in range(G):
-                i = idx_ref[base + idx_slot(h, 0, l, 0, qb, L, P)]
-                rows = fetch_pair(slab_ref, geom, i) + fetch_pair(
-                    slab_ref, geom, i + geom[1])
-                acc = accs[l * G + h]
-                for c, row in enumerate(rows):
-                    w = w_ref[base + w_slot(h, 0, l, c, 0, qb, L, P)]
-                    acc = acc + w * row
-                    if save:
-                        kept[c] = jnp.where(heads == h, row, kept[c])
-                accs[l * G + h] = acc
-            if save:
+        ws = [point_weights(spread, i) for i in range(k)]
+        spread = step_weights(jnp.minimum(q * steps + t + 1,
+                                          qb * steps - 1))
+        for i in range(k):
+            pbase = base + idx_slot(0, 0, 0, i, qb, L, P)
+            for l, geom in enumerate(levels):
+                corners = [[], [], [], []]
+                for h in range(G):
+                    at = idx_ref[pbase + idx_slot(h, 0, l, 0, qb, L, P)]
+                    rows = fetch_pair(slab_ref, geom, at) + fetch_pair(
+                        slab_ref, geom, at + geom[1])
+                    for c, row in enumerate(rows):
+                        corners[c].append(row)
+                acc = accs[l]
                 for c in range(4):
-                    row_scratch[pl.ds(saved_slot(l, c, p, P), 1), :] = kept[c]
-        return base + idx_slot(0, 0, 0, 1, qb, L, P), tuple(accs)
+                    row = merged(corners[c])
+                    acc = acc + ws[i][4 * l + c] * row
+                    keep(l, c, t * k + i, row)
+                accs[l] = acc
+        return base + idx_slot(0, 0, 0, k, qb, L, P), tuple(accs), spread
 
     def corner_step(q, c, carry):
-        # corner c of every point of every chain (the corner-major walk);
-        # ``base`` == w_slot(0, q, 0, c, 0)
-        base, accs = carry
+        # corner c of every point of every chain (the corner-major walk)
+        base, accs, spread = carry
         accs = list(accs)
-        for l, geom in enumerate(levels):
-            for p in range(P):
-                kept = zero
-                for h in range(G):
-                    i = idx_ref[idx_slot(h, q, l, p, qb, L, P)]
-                    row = fetch_row(slab_ref, geom,
-                                    i + corner_offset(c, geom[1]))
-                    accs[l * G + h] = accs[l * G + h] + w_ref[
-                        base + w_slot(h, 0, l, 0, p, qb, L, P)] * row
-                    if save:
-                        kept = jnp.where(heads == h, row, kept)
-                if save:
-                    row_scratch[pl.ds(saved_slot(l, c, p, P), 1), :] = kept
-        return base + w_slot(0, 0, 0, 1, 0, qb, L, P), tuple(accs)
+        for p in range(P):
+            ws = corner_weights(q, p, c)
+            for l, geom in enumerate(levels):
+                row = merged([fetch_row(
+                    slab_ref, geom, idx_ref[idx_slot(h, q, l, p, qb, L, P)]
+                    + corner_offset(c, geom[1])) for h in range(G)])
+                accs[l] = accs[l] + ws[l] * row
+                keep(l, c, p, row)
+        return base, tuple(accs), spread
 
-    step, n = (point_step, P) if fuse_gather else (corner_step, 4)
+    if fuse_gather:
+        step, n, whole = point_step, steps, steps == 1
+    else:
+        step, n, whole = corner_step, 4, L * G * P * 4 <= STEP_ROWS
 
-    def body(q, carry):
-        _, accs = static_loop(n, functools.partial(step, q),
-                              (q, (zero,) * (L * G)), not rolled)
-        # each head's lanes take that head's level sums, added to the
-        # total in level order: the adds the per-level launches' outputs
-        # see outside (tier parity)
+    def body(q, spread):
+        # ``spread``: the weights of the query's first step, spread by
+        # the step before
+        _, accs, spread = static_loop(
+            n, functools.partial(step, q), (q, (zero,) * L, spread),
+            unroll and whole)
+        # the level sums added to the total in level order: the adds the
+        # per-level launches' outputs see outside (tier parity)
         total = zero
-        for l in range(L):
-            part = zero
-            for h in range(G):
-                part = jnp.where(heads == h, accs[l * G + h], part)
-            total = total + part
+        for acc in accs:
+            total = total + acc
         out_ref[pl.ds(q, 1), :] = total
         if save:
             saved_ref[row_block(q, L * 4 * P), :] = row_scratch[...].astype(
                 saved_ref.dtype)
-        return carry
+        return spread
 
-    jax.lax.fori_loop(0, qb, body, 0)
+    jax.lax.fori_loop(0, qb, body, step_weights(0) if fuse_gather else ())
 
 
 def compiler_params(vmem_limit: int):
@@ -324,23 +498,18 @@ def table_block(n: int) -> int:
     return -(-n // SMEM_TILE) * SMEM_TILE
 
 
-def table_specs(qb: int, G: int, L: int, P: int, NG: int, nq: int):
-    """SMEM BlockSpecs of the corner-row and weight tables.
+def table_spec(n: int, NG: int, nq: int):
+    """SMEM BlockSpec of a flat table with ``n`` entries per query block.
 
-    The tables are flat: one chunk per (batch, head group, query block),
-    each padded to :func:`table_block`.  Mosaic tiles the last two dims
-    of an SMEM block like VMEM ones, so neither a squeezed (batch,
-    group) prefix nor a chunk off the 1-D tiling would lower.
+    One chunk per (batch, head group, query block), each padded to
+    :func:`table_block`.  Mosaic tiles the last two dims of an SMEM
+    block like VMEM ones, so neither a squeezed (batch, group) prefix
+    nor a chunk off the 1-D tiling would lower.
     """
-    n = G * qb * L * P
-
     def index(b, g, q):
         return ((b * NG + g) * nq + q,)
 
-    return [
-        pl.BlockSpec((table_block(n),), index, memory_space=pltpu.SMEM),
-        pl.BlockSpec((table_block(4 * n),), index, memory_space=pltpu.SMEM),
-    ]
+    return pl.BlockSpec((table_block(n),), index, memory_space=pltpu.SMEM)
 
 
 def resident(shape, index_map):
@@ -353,7 +522,7 @@ def resident(shape, index_map):
 def msda_gather(
     slab: jax.Array,   # (B, NG, R, G*D) fp32, zero-padded levels packed
     idx: jax.Array,    # flat int32 table: top-left corner rows
-    w: jax.Array,      # flat fp32 table: corner weights (see table_specs)
+    w: jax.Array,      # (B, NG, Qp*R, G*D) fp32 weight rows (weight_layout)
     *,
     levels: Tuple[LevelGeom, ...],
     num_points: int,
@@ -374,11 +543,12 @@ def msda_gather(
     order.  ``first_level`` is the pyramid index of ``levels[0]``, for
     the kernel's metadata only.
     """
-    B, NG, R, GD = slab.shape
+    B, NG, rows, GD = slab.shape
     L, P, D = len(levels), num_points, head_dim
     G = GD // D
-    nq = idx.shape[0] // (B * NG * table_block(block_q * G * L * P))
-    qp = nq * block_q
+    R = weight_layout(L, P, D)[0]
+    qp = w.shape[2] // R
+    nq = qp // block_q
     K = L * 4 * P
     kernel = functools.partial(
         _gather_kernel, levels=tuple(levels), P=P, G=G, D=D, qb=block_q,
@@ -397,10 +567,13 @@ def msda_gather(
         kernel = functools.partial(_nosave_wrap, kernel)
     outs = pl.pallas_call(
         kernel,
-        grid=(B, NG, qp // block_q),
-        in_specs=table_specs(block_q, G, L, P, NG, nq) + [
+        grid=(B, NG, nq),
+        in_specs=[
+            table_spec(G * block_q * L * P, NG, nq),
+            pl.BlockSpec((None, None, block_q * R, GD),
+                         lambda b, g, q: (b, g, q, 0)),
             # slab: same block for every q -> resident across the block walk
-            resident((None, None, R, GD), lambda b, g, q: (b, g, 0, 0)),
+            resident((None, None, rows, GD), lambda b, g, q: (b, g, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shapes,

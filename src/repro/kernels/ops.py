@@ -44,9 +44,9 @@ Shapes = Tuple[Tuple[int, int], ...]
 # none.  Plans carry an explicit per-device budget on the spec
 # (plan.default_vmem_budget), which also becomes Mosaic's VMEM limit.
 VMEM_BUDGET = 32 * 2**20
-# SMEM the double-buffered corner-row + weight table chunks of one query
-# step may take (of the 1 MiB a v5e TensorCore has; the rest is left to
-# Mosaic's own scalars)
+# SMEM the double-buffered table chunks of one query step may take (of
+# the 1 MiB a v5e TensorCore has; the rest is left to Mosaic's own
+# scalars): the corner rows, and the weights the backward still reads
 SMEM_BUDGET = 768 * 1024
 _SUBLANE = 8
 _LANES = 128
@@ -79,32 +79,39 @@ def per_query_bytes(num_points: int, head_dim: int, *, train: bool = False,
                     heads: int = 1) -> int:
     """Per-query VMEM of one launch's pipelined step blocks.
 
-    Inference: the double-buffered fp32 output row.  ``train=True``
-    plans the larger backward step: the double-buffered gout row, saved
-    corners (``levels*4P`` rows in the slab dtype) and weight grads,
-    plus the fp32 copy of the corners that phase 1 reduces.  ``heads``
-    is the launch's head group (its lanes).
+    Inference: the forward's double-buffered fp32 output row and weight
+    rows (``msda_fwd.weight_layout``).  ``train=True`` plans the larger
+    of the training forward (those plus the saved corners it writes,
+    ``levels*4P`` rows in the slab dtype) and the backward step: the
+    double-buffered gout row, saved corners and weight grads, plus the
+    fp32 copy of the corners that phase 1 reduces.  ``heads`` is the
+    launch's head group (its lanes).
 
     Single source of truth for the paper's occupancy model — used by the
     block planner below and by ``MsdaPlan.level_report``.
     """
     lanes = lane_width(heads, head_dim)
     io = lanes * 4
+    weights = msda_fwd.weight_layout(levels, num_points, head_dim)[0] * io
     if not train:
-        return 2 * io
+        return 2 * (io + weights)
     k = levels * 4 * num_points
     saved = k * lanes * slab_itemsize
     grads = heads * k * 4
-    return 2 * (io + saved + grads) + k * lanes * 4
+    return max(2 * (io + weights + saved),
+               2 * (io + saved + grads) + k * lanes * 4)
 
 
-def smem_block_cap(num_points: int, *, levels: int = 1,
-                   heads: int = 1) -> int:
-    """Largest query block whose double-buffered SMEM table chunks (one
-    int32 corner row and four fp32 weights per head, level and point)
-    fit :data:`SMEM_BUDGET`, less a tiling chunk of slack per table."""
-    per_q = 2 * 5 * heads * levels * num_points * 4
-    return max(_SUBLANE, (SMEM_BUDGET - 2 * 2 * 1024 * 4) // per_q)
+def smem_block_cap(num_points: int, *, levels: int = 1, heads: int = 1,
+                   train: bool = False) -> int:
+    """Largest query block whose double-buffered SMEM table chunks fit
+    :data:`SMEM_BUDGET`, less a tiling chunk of slack per table: the
+    forward's int32 corner row per head, level and point, and for a
+    training plan the backward's four fp32 weights besides."""
+    tables = 2 if train else 1
+    per_q = 2 * (1 + 4 * (tables - 1)) * heads * levels * num_points * 4
+    slack = 2 * tables * msda_fwd.SMEM_TILE * 4
+    return max(_SUBLANE, (SMEM_BUDGET - slack) // per_q)
 
 
 def pyramid_row_offsets(spatial_shapes: Shapes) -> Tuple[Tuple[int, ...], int]:
@@ -231,7 +238,8 @@ def plan_blocks(
     only.
     """
     def _clamp(bq: int, levels: int) -> int:
-        bq = min(bq, smem_block_cap(num_points, levels=levels, heads=heads))
+        bq = min(bq, smem_block_cap(num_points, levels=levels, heads=heads,
+                                    train=train))
         bq = max(_SUBLANE, min(2048, (bq // _SUBLANE) * _SUBLANE))
         return min(bq, _round_up(num_queries, _SUBLANE))
 
@@ -471,17 +479,38 @@ def _chunked(x: jax.Array, block_q: int) -> jax.Array:
     return _flat_table(jnp.transpose(x, (1, 2, 5, 3, 0, 4, 6)))
 
 
+def _weight_rows(w: jax.Array, head_dim: int) -> jax.Array:
+    """(Ll, B, NG, G, 4*P, Qp) level weights of one launch -> the
+    forward's VMEM weight rows (B, NG, Qp*R, G*D), each weight in its
+    ``msda_fwd.weight_lane``.  Data movement only: the slots are ordered
+    and padded with the query minor, then one transpose brings the query
+    major and the heads' slots onto the lanes."""
+    Ll, B, NG, G, n, qp = w.shape
+    P, D = n // 4, head_dim
+    R, stride = msda_fwd.weight_layout(Ll, P, D)
+    x = jnp.transpose(w.reshape(Ll, B, NG, G, 4, P, qp), (1, 2, 3, 5, 0, 4, 6))
+    x = x.reshape(B, NG, G, P, 4 * Ll, qp)
+    x = jnp.pad(x, ((0, 0),) * 4 + ((0, stride - 4 * Ll), (0, 0)))
+    x = jnp.pad(x.reshape(B, NG, G, P * stride, qp),
+                ((0, 0),) * 3 + ((0, R * D - P * stride), (0, 0)))
+    x = jnp.transpose(x.reshape(B, NG, G, R, D, qp), (0, 1, 5, 3, 2, 4))
+    return x.reshape(B, NG, qp * R, G * D)
+
+
 def _corner_tables(p: MSDAParams, loc, attn, head_dim: int):
-    """Per launch, the SMEM tables ``(idx, w)`` the kernels consume:
+    """Per launch, the tables ``(idx, wrows, w)`` the kernels consume:
     :func:`_level_tables` cut to the launch's levels and padded queries,
-    corner rows lifted by the levels' row offsets in the launch slab,
-    chunked by :func:`_chunked`.  Data movement only."""
+    corner rows lifted by the levels' row offsets in the launch slab and
+    chunked by :func:`_chunked` into the SMEM table; the weights as the
+    forward's VMEM rows (:func:`_weight_rows`) and as the backward's
+    SMEM table.  A program that takes no gradient never reads the last,
+    and XLA drops it.  Data movement only."""
     B, Q, H, L, P, _ = loc.shape
     G = msda_fwd.head_group(H, head_dim)
     launches = plan_launches(p)
     qmax = max(_round_up(Q, launch.block_q) for launch in launches)
     idx_all, w_all = _level_tables(p, loc, attn, G, qmax)
-    idxs, ws = [], []
+    idxs, wrows, ws = [], [], []
     for launch in launches:
         geoms, _ = _launch_geometry(p, launch)
         lo, hi = launch.levels[0], launch.levels[-1] + 1
@@ -489,8 +518,9 @@ def _corner_tables(p: MSDAParams, loc, attn, head_dim: int):
         offs = jnp.asarray([g[0] for g in geoms], jnp.int32)
         idx = idx_all[lo:hi, ..., :qp] + offs[:, None, None, None, None, None]
         idxs.append(_chunked(idx, launch.block_q))
+        wrows.append(_weight_rows(w_all[lo:hi, ..., :qp], head_dim))
         ws.append(_chunked(w_all[lo:hi, ..., :qp], launch.block_q))
-    return tuple(idxs), tuple(ws)
+    return tuple(idxs), tuple(wrows), tuple(ws)
 
 
 def _launch_slab(p: MSDAParams, launch: Launch, value_g, operand_dtype):
@@ -522,12 +552,16 @@ def _unpack_grad(gslab, p: MSDAParams, launch: Launch, geoms):
 
 
 def _kernel_gather(p: MSDAParams, dims, operand_dtype):
-    """Custom-VJP weighted corner gather ``(value, idxs, ws) -> out``.
+    """Custom-VJP weighted corner gather ``(value, idxs, wrows, ws) ->
+    out``.
 
     ``dims`` = (B, S, H, D, Q, P) are static.  The forward runs the
-    gather kernel per launch (saving corners in train mode); the
-    backward runs the scatter kernel per launch (regathering corners
-    when none were saved), which also returns the weight-table grads.
+    gather kernel per launch on the weight rows ``wrows`` (saving
+    corners in train mode); the backward runs the scatter kernel per
+    launch on the weight table ``ws`` (regathering corners when none
+    were saved), which also returns that table's grads.  Both weight
+    layouts hold the same weights, so the gradient reaches them through
+    ``ws`` alone and ``wrows`` gets none.
     """
     B, S, H, D, Q, P = dims
     G = msda_fwd.head_group(H, D)
@@ -537,14 +571,14 @@ def _kernel_gather(p: MSDAParams, dims, operand_dtype):
     def grouped_value(value):
         return jnp.transpose(value.reshape(B, S, NG, G * D), (0, 2, 1, 3))
 
-    def run_fwd(value, idxs, ws, save):
+    def run_fwd(value, idxs, wrows, save):
         with jax.named_scope(scopes.MSDA_FWD):
             with jax.named_scope(scopes.MSDA_SLAB):
                 value_g = grouped_value(value)
             with jax.named_scope(scopes.MSDA_REDUCE):
                 out = jnp.zeros((B, NG, Q, G * D), jnp.float32)
             slabs, saved = [], []
-            for launch, idx, w in zip(launches, idxs, ws):
+            for launch, idx, w in zip(launches, idxs, wrows):
                 geoms, _ = _launch_geometry(p, launch)
                 with jax.named_scope(scopes.MSDA_SLAB):
                     slab = _launch_slab(p, launch, value_g, operand_dtype)
@@ -566,11 +600,11 @@ def _kernel_gather(p: MSDAParams, dims, operand_dtype):
             return out, (tuple(slabs), tuple(saved))
 
     @jax.custom_vjp
-    def gather(value, idxs, ws):
-        return run_fwd(value, idxs, ws, save=False)[0]
+    def gather(value, idxs, wrows, ws):
+        return run_fwd(value, idxs, wrows, save=False)[0]
 
-    def fwd(value, idxs, ws):
-        out, (slabs, saved) = run_fwd(value, idxs, ws, p.save_sampled)
+    def fwd(value, idxs, wrows, ws):
+        out, (slabs, saved) = run_fwd(value, idxs, wrows, p.save_sampled)
         # train plans keep the corners, inference plans the slabs
         return out, (None if p.save_sampled else slabs,
                      saved if p.save_sampled else None, idxs, ws)
@@ -604,7 +638,7 @@ def _kernel_gather(p: MSDAParams, dims, operand_dtype):
                 gvalue = jnp.transpose(gvalue, (0, 2, 1, 3)).reshape(B, S, H, D)
                 gvalue = gvalue.astype(operand_dtype)
             gidx = tuple(np.zeros(i.shape, jax.dtypes.float0) for i in idxs)
-            return gvalue, gidx, tuple(gws)
+            return gvalue, gidx, None, tuple(gws)
 
     gather.defvjp(fwd, bwd)
     return gather
@@ -627,9 +661,9 @@ def build_kernel_op(p: MSDAParams):
         # is the backward's grad-loc / grad-attn (scopes.layer_of)
         with jax.named_scope(scopes.MSDA_FWD), \
                 jax.named_scope(scopes.MSDA_TABLES):
-            idxs, ws = _corner_tables(p, loc, attn, D)
+            idxs, wrows, ws = _corner_tables(p, loc, attn, D)
         return _kernel_gather(p, (B, S, H, D, Q, P), value.dtype)(
-            value, idxs, ws)
+            value, idxs, wrows, ws)
 
     return jax.jit(op)
 

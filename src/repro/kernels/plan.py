@@ -1967,6 +1967,31 @@ class MsdaPlan:
             _VMEM_GAUGE.set(predicted, level=l, kind="predicted")
         return rows
 
+    def weight_report(self) -> List[Dict[str, Any]]:
+        """Per forward launch, its VMEM weight block: the levels it
+        covers, its ``block_q``, the weight rows a query takes
+        (``msda_fwd.weight_layout``) and the double-buffered block's
+        bytes.  Every Pallas gather reads its weights so; no launch
+        (``[]``) where no Pallas gather runs."""
+        if self.backend != "pallas" or self.tuning.sparsity == "topk":
+            return []
+        from repro.kernels import msda_fwd, ops
+
+        s = self.local_spec
+        k = self.fuse_prefix
+        launches = ([tuple(range(k))] if k else []) + [
+            (l,) for l in range(k, s.num_levels)]
+        lanes = ops.lane_width(s.heads_per_launch, s.head_dim)
+        out = []
+        for levels in launches:
+            bq = self.tuning.block_q[levels[0]]
+            rows = msda_fwd.weight_layout(len(levels), s.num_points,
+                                          s.head_dim)[0]
+            out.append({"levels": levels, "block_q": bq,
+                        "rows_per_query": rows,
+                        "vmem_bytes": 2 * bq * rows * lanes * 4})
+        return out
+
     def sharding_report(self) -> Dict[str, Any]:
         """Structured record of the committed distribution.
 
@@ -2100,6 +2125,12 @@ class MsdaPlan:
             f"  vmem_budget={s.vmem_budget / 2**20:.1f} MiB  "
             f"interpret={self.tuning.interpret}\n"
         )
+        for w in self.weight_report():
+            lv = w["levels"]
+            span = f"{lv[0]}-{lv[-1]}" if len(lv) > 1 else f"{lv[0]}"
+            head += (f"  weights lvl {span}: {w['rows_per_query']} rows/query"
+                     f" x block_q {w['block_q']} = {w['vmem_bytes']} B VMEM"
+                     f" (2 buffers)\n")
         lines = [head,
                  "  lvl  hw         slab_rows  slab_KiB   slab_dt   block_q  steps  gather      vmem%  pred%"]
         for r in self.level_report():

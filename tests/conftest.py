@@ -5,11 +5,18 @@ import os
 # the distribution tests can build real 2x2 / 1x4 / 4x1 meshes and run
 # shard_map + ppermute collectives for the 2D (dp x tp) sharding mode;
 # everything else still executes on device 0 as before.
+#
+# XLA:CPU is held to AVX so that it never contracts a multiply and an add
+# into a fused multiply-add: v5e rounds every product on its own (its
+# bundles hold separate vmul and vadd ops), and the kernels' tests hold
+# the interpreter to that bit for bit, whatever the shape of the walk.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=4").strip()
+    _flags += " --xla_force_host_platform_device_count=4"
+if "xla_cpu_max_isa" not in _flags:
+    _flags += " --xla_cpu_max_isa=AVX"
+os.environ["XLA_FLAGS"] = _flags.strip()
 
 import jax
 import numpy as np

@@ -7,7 +7,7 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops
+from repro.kernels import msda_fwd, ops
 from repro.kernels import plan as plan_mod
 from repro.kernels.ref import msda_ref
 
@@ -127,18 +127,50 @@ def test_planned_block_q_respects_vmem_model(args):
     bqs = plan_mod._heuristic_block_q(spec)
     per_q = ops.per_query_bytes(P, D, train=train,
                                 slab_itemsize=spec.slab_itemsize, heads=G)
+    rows = msda_fwd.weight_layout(1, P, D)[0]
+    forward = 2 * (lanes * 4 + rows * lanes * 4)
     if train:
-        assert per_q == (2 * (lanes * 4 + 4 * P * lanes * spec.slab_itemsize
-                              + G * 4 * P * 4) + 4 * P * lanes * 4)
+        saved = 4 * P * lanes * spec.slab_itemsize
+        assert per_q == max(
+            forward + 2 * saved,
+            2 * (lanes * 4 + saved + G * 4 * P * 4) + 4 * P * lanes * 4)
+    else:
+        assert per_q == forward
     for hw, bq in zip(levels, bqs):
         assert bq % 8 == 0 and 8 <= bq <= 2048
         assert bq <= _round_up8(Q)
-        assert bq <= max(8, ops.smem_block_cap(P, heads=G))
+        assert bq <= max(8, ops.smem_block_cap(P, heads=G, train=train))
         # the resident slab is fp32 over the head group's lanes
         resident = ops.slab_rows(hw) * lanes * 4
         # the documented model: per-step bytes fit what the budget leaves
         # after the resident slab, floored at a 1 MiB working set
         assert bq * per_q <= max(budget - resident, 1 * _MIB) or bq == 8
+
+
+@pytest.mark.parametrize("levels,P,heads", [(1, 4, 4), (5, 4, 4), (4, 8, 4),
+                                            (3, 2, 8)])
+def test_smem_block_cap_counts_the_tables_in_smem(levels, P, heads):
+    """The forward keeps only its int32 corner-row table in SMEM (its
+    weights are VMEM rows); a training plan's backward still keeps its
+    four fp32 weights there too.  Each table double-buffered, less a
+    tiling chunk of slack per table."""
+    chunk = 2 * ops.msda_fwd.SMEM_TILE * 4  # a tile of int32 or fp32, twice
+    idx = 2 * heads * levels * P * 4
+    assert ops.smem_block_cap(P, levels=levels, heads=heads) == max(
+        8, (ops.SMEM_BUDGET - chunk) // idx)
+    assert ops.smem_block_cap(P, levels=levels, heads=heads, train=True) == (
+        max(8, (ops.SMEM_BUDGET - 2 * chunk) // (5 * idx)))
+
+
+@pytest.mark.parametrize("levels,P,D,heads", [
+    (1, 4, 32, 4), (5, 4, 32, 4), (4, 8, 32, 4), (5, 2, 16, 8), (1, 4, 16, 8)])
+def test_per_query_bytes_counts_weight_rows(levels, P, D, heads):
+    """An inference step holds, per query, its output row and its weight
+    rows (msda_fwd.weight_layout), each double-buffered."""
+    lanes = ops.lane_width(heads, D)
+    rows = msda_fwd.weight_layout(levels, P, D)[0]
+    assert ops.per_query_bytes(P, D, levels=levels, heads=heads) == (
+        2 * (1 + rows) * lanes * 4)
 
 
 @given(spec_dims)

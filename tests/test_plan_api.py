@@ -254,6 +254,34 @@ def test_describe_reports_per_level_decisions():
         assert r["slab_bytes"] > 0 and r["block_q"] >= 8
 
 
+@pytest.mark.parametrize("fuse", ["on", "off"])
+def test_describe_reports_each_launch_weight_block(fuse):
+    """One line per forward launch: the VMEM weight block's rows per
+    query and its double-buffered bytes."""
+    from repro.kernels import msda_fwd
+
+    value, loc, attn = _inputs()
+    plan = msda_plan(_spec(value, loc, fuse_levels=fuse), backend="pallas")
+    s = plan.spec
+    lanes = ops.lane_width(s.heads_per_launch, s.head_dim)
+    launches = ([tuple(range(len(LEVELS)))] if fuse == "on"
+                else [(l,) for l in range(len(LEVELS))])
+    report = plan.weight_report()
+    assert [w["levels"] for w in report] == launches
+    text = plan.describe()
+    for w, levels in zip(report, launches):
+        rows = msda_fwd.weight_layout(len(levels), s.num_points,
+                                      s.head_dim)[0]
+        bq = plan.block_q[levels[0]]
+        assert (w["rows_per_query"], w["block_q"]) == (rows, bq)
+        assert w["vmem_bytes"] == 2 * bq * rows * lanes * 4
+        span = (f"{levels[0]}-{levels[-1]}" if len(levels) > 1
+                else f"{levels[0]}")
+        assert (f"weights lvl {span}: {rows} rows/query x block_q {bq} = "
+                f"{w['vmem_bytes']} B VMEM (2 buffers)") in text
+    assert msda_plan(_spec(value, loc), backend="ref").weight_report() == []
+
+
 # --------------------------------------------------------------------------
 # dtype policy: the second planned axis (slab dtype + widened accumulator)
 # --------------------------------------------------------------------------
