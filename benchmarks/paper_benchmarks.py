@@ -10,10 +10,6 @@ what reproduces the paper is the *structure* of each comparison:
   (interpret executes the kernel body in Python per grid step — its
   wall-time is NOT a TPU prediction; its structural counters are what
   transfer, and the TPU-side roofline lives in EXPERIMENTS.md §Roofline).
-* Table 4 — ablations: adaptive vec-len, gather fusion, scatter fusion,
-  staggered/two-phase scatter — reported as kernel-structure counters
-  (gathers issued / average gather vector length / scatter conflicts)
-  plus interpret-mode wall time.
 * Fig 4/5 — gather/scatter micro-benchmarks vs granularity: the paper's
   "merging adjacent pixels doubles effective bandwidth" claim, measured
   with jnp gathers of (N, D) vs (N/2, 2D) layouts.
@@ -99,73 +95,6 @@ def table3_speedups(t2):
     row("table3.backward", tb_r, f"x{tb_b/tb_r:.2f}_vs_baseline (paper x8.90)")
     row("table3.train_fwd_bwd", tf_r + tb_r,
         f"x{(tf_b+tb_b)/(tf_r+tb_r):.2f}_vs_baseline (paper x7.29)")
-
-
-# --------------------------------------------------------------------------
-# Table 4: ablations (kernel structure + interpret wall time, small size)
-# --------------------------------------------------------------------------
-
-
-def _kernel_stats(levels, q, block_q, fuse_gather):
-    """Structural counters: gathers issued per grid step x steps, and the
-    average gather vector length (the quantity Fig. 4 says drives
-    throughput on the vector core)."""
-    gathers = 0
-    rows_total = 0
-    for l, (hh, ww) in enumerate(levels):
-        bq = block_q[l]
-        steps = -(-q // bq)
-        per_step = 1 if fuse_gather else 4
-        gathers += B * H * steps * per_step
-        rows_total += B * H * steps * (4 * bq * P)
-    return gathers, rows_total / max(gathers, 1)
-
-
-def table4_ablation():
-    print("# Table 4: ablations (interpret-mode wall time + structure)")
-    levels = ((16, 16), (8, 8))
-    q = 128
-    S = sum(h * w for h, w in levels)
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    value = jax.random.normal(ks[0], (B, S, H, D))
-    loc = jax.random.uniform(ks[1], (B, q, H, len(levels), P, 2))
-    attn = jax.nn.softmax(
-        jax.random.normal(ks[2], (B, q, H, len(levels), P)).reshape(B, q, H, -1)
-    ).reshape(B, q, H, len(levels), P)
-    gout = jax.random.normal(ks[3], (B, q, H * D))
-
-    # each ablation is one committed MsdaSpec -> MsdaPlan: tuning lives on
-    # the spec, the plan is built once, and timing loops just execute it
-    variants = {
-        "default": dict(fuse_gather=True, adaptive_block=True),
-        "-adaptive_veclen": dict(fuse_gather=True, adaptive_block=False),
-        "-gather_fusion": dict(fuse_gather=False, adaptive_block=True),
-        "-all": dict(fuse_gather=False, adaptive_block=False),
-    }
-    for name, kw in variants.items():
-        spec = plan_mod.MsdaSpec(
-            spatial_shapes=levels, num_heads=H, head_dim=D, num_points=P,
-            num_queries=q, dtype="float32", **kw)
-        p = plan_mod.msda_plan(spec, backend="pallas")
-        f = jax.jit(lambda v, l, a, p=p: p(v, l, a))
-        t = time_fn(lambda: f(value, loc, attn), warmup=1, iters=3)
-        g, veclen = _kernel_stats(levels, q, p.block_q, kw["fuse_gather"])
-        row(f"table4.fwd.{name}", t,
-            f"gathers={g};avg_vec_rows={veclen:.0f};block_q={p.block_q}")
-
-    # backward: scatter fusion ablation
-    for name, fuse in (("default", True), ("-scatter_fusion", False)):
-        spec = plan_mod.MsdaSpec(
-            spatial_shapes=levels, num_heads=H, head_dim=D, num_points=P,
-            num_queries=q, dtype="float32", fuse_scatter=fuse)
-        p = plan_mod.msda_plan(spec, backend="pallas")
-        f = jax.jit(jax.grad(lambda v, p=p: jnp.vdot(p(v, loc, attn), gout)))
-        t = time_fn(lambda: f(value), warmup=1, iters=3)
-        scatters = 1 if fuse else 4
-        row(f"table4.bwd.{name}", t, f"scatters_per_step={scatters}")
-    row("table4.bwd.two_phase_note", 0.0,
-        "staggered-write == per-shard partial grad slabs + psum (see "
-        "tests/test_sharding_dist.py::test_distributed_msda_grad_value_reduction)")
 
 
 # --------------------------------------------------------------------------
@@ -274,158 +203,6 @@ def backend_dtype_matrix():
     row("matrix.fwd.cpu.bf16_vs_fp32_slab", 0.0,
         f"x{dt['float32'] / dt['bfloat16']:.2f}")
     return {"fwd": fwd, "bwd": bwd, "dtype": dt}
-
-
-# --------------------------------------------------------------------------
-# fused whole-pyramid vs per-level launches (PR 5 tentpole ablation)
-# --------------------------------------------------------------------------
-
-
-def fused_vs_per_level(out_path=None):
-    """Fused single-launch pyramid vs per-level launches, fwd and train.
-
-    Interpret-mode wall time is NOT a TPU prediction (the kernel body
-    runs in Python per grid step); what transfers is the STRUCTURE this
-    row reports: launches per direction (1 vs L), gout streams in the
-    backward (1 vs L), and HBM round-trips of fp32 partial outputs
-    (0 vs L-1).  Writes the ``BENCH_kernels.json`` trajectory file at
-    the repo root (CI uploads it per commit) and prints the CSV rows.
-    """
-    import dataclasses
-
-    from repro.obs import bench as obs_bench
-
-    levels = ((16, 16), (8, 8), (4, 4))
-    q, b, h = 64, 1, 2
-    S = sum(hh * ww for hh, ww in levels)
-    L = len(levels)
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    value = jax.random.normal(ks[0], (b, S, h, D))
-    loc = jax.random.uniform(ks[1], (b, q, h, L, P, 2))
-    attn = jax.nn.softmax(
-        jax.random.normal(ks[2], (b, q, h, L, P)).reshape(b, q, h, -1)
-    ).reshape(b, q, h, L, P)
-    gout = jax.random.normal(ks[3], (b, q, h * D))
-
-    print("# Fused whole-pyramid vs per-level launches (interpret mode)")
-    results = {}
-    for train in (False, True):
-        spec = plan_mod.MsdaSpec(
-            spatial_shapes=levels, num_heads=h, head_dim=D, num_points=P,
-            num_queries=q, dtype="float32", train=train)
-        plans = {fuse: plan_mod.msda_plan(
-            dataclasses.replace(spec, fuse_levels=fuse), backend="pallas")
-            for fuse in ("on", "off")}
-        if train:
-            fns = {fuse: jax.jit(jax.grad(
-                lambda v, l, a, p=p: jnp.vdot(p(v, l, a), gout),
-                argnums=(0, 1, 2))) for fuse, p in plans.items()}
-        else:
-            fns = {fuse: jax.jit(lambda v, l, a, p=p: p(v, l, a))
-                   for fuse, p in plans.items()}
-        t = _time_interleaved(fns, (value, loc, attn), iters=3)
-        tag = "train" if train else "fwd"
-        for fuse, us in t.items():
-            mode = "fused" if fuse == "on" else "per_level"
-            launches = (2 if fuse == "on" else 2 * L) if train else (
-                1 if fuse == "on" else L)
-            results[f"{tag}.{mode}"] = {"us": us, "launches_per_call": launches}
-            row(f"kernels.{tag}.{mode}", us, f"launches={launches}")
-        row(f"kernels.{tag}.fused_speedup", 0.0,
-            f"x{t['off'] / t['on']:.2f}_vs_per_level")
-        results[f"{tag}.fused_speedup_x"] = t["off"] / t["on"]
-
-    if out_path is None:
-        out_path = obs_bench.bench_path("kernels")
-    obs_bench.write_bench(
-        out_path,
-        bench="fused_vs_per_level",
-        config={"levels": [list(hw) for hw in levels], "Q": q, "B": b,
-                "H": h, "D": D, "P": P},
-        note="interpret-mode wall time; structural counters transfer",
-        results=results,
-        gate=[
-            # launch counts are geometry-determined: any increase regresses
-            obs_bench.gate_rule("*.launches_per_call", "lower", 0.0),
-            # speedup ratios are same-machine relative -> moderately stable
-            obs_bench.gate_rule("*.fused_speedup_x", "higher", 0.5),
-            # raw interpret-mode timings vary across runner hardware
-            obs_bench.gate_rule("*.us", "lower", 4.0),
-        ])
-    print(f"# wrote {out_path}")
-    return results
-
-
-def fusion_tiers(out_path=None):
-    """Per-level vs strict-prefix vs whole-pyramid fusion tiers.
-
-    The partial-fusion tier is the middle rung ``fused_vs_per_level``
-    cannot see: one fused launch over the prefix [0:k) plus per-level
-    tail launches, ``L - k + 1`` per direction.  As above, interpret-
-    mode wall time is trend only; the launch schedule (read from
-    ``plan.launches_per_call()``, the same method the observability
-    gauge bills from) is the structural fact that transfers.  Writes
-    the ``BENCH_fusion_tiers.json`` trajectory file at the repo root.
-    """
-    import dataclasses
-
-    from repro.obs import bench as obs_bench
-
-    levels = ((16, 16), (8, 8), (4, 4))
-    q, b, h = 64, 1, 2
-    S = sum(hh * ww for hh, ww in levels)
-    L = len(levels)
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    value = jax.random.normal(ks[0], (b, S, h, D))
-    loc = jax.random.uniform(ks[1], (b, q, h, L, P, 2))
-    attn = jax.nn.softmax(
-        jax.random.normal(ks[2], (b, q, h, L, P)).reshape(b, q, h, -1)
-    ).reshape(b, q, h, L, P)
-    gout = jax.random.normal(ks[3], (b, q, h * D))
-
-    tiers = {"per_level": "off", "prefix2": "prefix:2", "full": "on"}
-    print("# Fusion tiers: per-level vs prefix [0:2) vs whole pyramid (interpret mode)")
-    results = {}
-    for train in (False, True):
-        spec = plan_mod.MsdaSpec(
-            spatial_shapes=levels, num_heads=h, head_dim=D, num_points=P,
-            num_queries=q, dtype="float32", train=train)
-        plans = {name: plan_mod.msda_plan(
-            dataclasses.replace(spec, fuse_levels=fuse), backend="pallas")
-            for name, fuse in tiers.items()}
-        if train:
-            fns = {name: jax.jit(jax.grad(
-                lambda v, l, a, p=p: jnp.vdot(p(v, l, a), gout),
-                argnums=(0, 1, 2))) for name, p in plans.items()}
-        else:
-            fns = {name: jax.jit(lambda v, l, a, p=p: p(v, l, a))
-                   for name, p in plans.items()}
-        t = _time_interleaved(fns, (value, loc, attn), iters=3)
-        tag = "train" if train else "fwd"
-        for name, us in t.items():
-            lp = plans[name].launches_per_call()
-            launches = lp["fwd"] + (lp["bwd"] if train else 0)
-            results[f"{tag}.{name}"] = {"us": us, "launches_per_call": launches}
-            row(f"fusion_tiers.{tag}.{name}", us, f"launches={launches}")
-
-    if out_path is None:
-        out_path = obs_bench.bench_path("fusion_tiers")
-    obs_bench.write_bench(
-        out_path,
-        bench="fusion_tiers",
-        config={"levels": [list(hw) for hw in levels], "Q": q, "B": b,
-                "H": h, "D": D, "P": P, "prefix_k": 2},
-        note="interpret-mode wall time is trend only; launch schedule transfers",
-        results=results,
-        gate=[
-            # the launch schedule is geometry-determined: any increase
-            # means a tier stopped fusing what it promised to fuse
-            obs_bench.gate_rule("*.launches_per_call", "lower", 0.0),
-            # raw interpret-mode timings vary across runner hardware
-            obs_bench.gate_rule("*.us", "lower", 4.0),
-        ])
-    print(f"# wrote {out_path}")
-    return results
 
 
 # --------------------------------------------------------------------------

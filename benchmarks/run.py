@@ -22,12 +22,10 @@ def main() -> None:
     t2 = pb.table2_overall()
     pb.table3_speedups(t2)
     pb.backend_dtype_matrix()
-    pb.fused_vs_per_level()  # emits BENCH_kernels.json at the repo root
     pb.sparsity_ablation()  # emits BENCH_sparsity.json at the repo root
     pb.fig4_gather_microbench()
     pb.fig5_scatter_microbench()
     if not args.fast:
-        pb.table4_ablation()
         pb.bench_detr_train()
     # roofline summary (reads the dry-run sweep if present)
     try:

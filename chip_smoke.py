@@ -15,9 +15,8 @@ every committed plan must be ``backend=pallas`` with ``interpret=False``
 and the compiled step must hold ``tpu_custom_call``.  Then it checks the
 Pallas kernels against the ``ref`` oracle on the chip, fwd and VJP, within
 the bf16 tolerances of ``tests/conformance.py``: the full pyramid over a
-query subset, the full 300-query decoder spec, and every kernel variant
-(fusion tiers, ablations, one-hot routing, mixed slab dtypes, regather) on
-a small pyramid.
+query subset, the full 300-query decoder spec, and an inference plan's
+regathering backward on a small pyramid.
 
 ``--four-chips`` runs only the sharded path: the same step on a 2x2 mesh
 (MSDA queries sharded over all four chips), compared loss by loss with a
@@ -147,8 +146,7 @@ def _operands(spec, seed: int, batch: int = 1):
     return value, loc, attn
 
 
-def against_ref(name: str, exec_fn, spec, seed: int, fwd_tol, vjp_tol,
-                bf16_levels=()):
+def against_ref(name: str, exec_fn, spec, seed: int, fwd_tol, vjp_tol):
     """Pallas executor vs the fp32 ``ref`` oracle: max abs errors of the
     forward and of each VJP output, held to the tolerances of the spec's
     operand dtype.
@@ -158,8 +156,7 @@ def against_ref(name: str, exec_fn, spec, seed: int, fwd_tol, vjp_tol,
     oracle gets the same values in fp32.  Both VJPs are pulled back
     through ONE cotangent, rounded to the executor's output dtype, so a
     bf16-rounded output cannot perturb what the gradients are checked
-    on.  ``bf16_levels`` are rounded to bf16 up front (a bf16 slab
-    level then rounds nothing)."""
+    on."""
     import jax
     import jax.numpy as jnp
 
@@ -167,13 +164,6 @@ def against_ref(name: str, exec_fn, spec, seed: int, fwd_tol, vjp_tol,
 
     value, loc, attn = _operands(spec, seed)
     levels = spec.spatial_shapes
-    start = 0
-    for l, (h, w) in enumerate(levels):
-        if l in bf16_levels:
-            lvl = value[:, start:start + h * w]
-            value = value.at[:, start:start + h * w].set(
-                lvl.astype(jnp.bfloat16).astype(jnp.float32))
-        start += h * w
     tier = str(jnp.dtype(spec.dtype))
     value, attn = value.astype(tier), attn.astype(tier)
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
@@ -204,14 +194,14 @@ def against_ref(name: str, exec_fn, spec, seed: int, fwd_tol, vjp_tol,
 
 def conformance(plans, seed: int):
     """Pallas vs ref on the chip: the encoder's full pyramid over a query
-    subset, the committed 300-query decoder plan, and every kernel
-    variant.  The model's plans run on their committed dtypes (bf16
+    subset, the committed 300-query decoder plan, and the regathering
+    backward of an inference plan.  The model's plans run on their
+    committed dtypes (bf16
     operands and slabs, bf16 output and grads) and are held to the bf16
     tolerances of ``tests/conformance.py``."""
     sys.path.insert(0, os.path.join(REPO, "tests"))
     from conformance import FWD_TOL, VJP_TOL
 
-    from repro.kernels import ops
     from repro.kernels.plan import msda_plan
 
     enc = plans["encoder"]
@@ -223,30 +213,14 @@ def conformance(plans, seed: int):
     against_ref("decoder, 300 queries (committed plan)", dec, dec.spec,
                 seed + 1, FWD_TOL, VJP_TOL)
 
-    # every kernel variant at a small pyramid, fp32 operands
+    # an inference plan's backward regathers its corners from the slab;
+    # a small pyramid, fp32 operands
     small = dataclasses.replace(
         enc.spec, spatial_shapes=((32, 32), (16, 16), (8, 8)),
-        num_queries=256, dtype="float32", slab_dtype="", train=True)
-    base = dict(spatial_shapes=small.spatial_shapes, block_q=(64,) * 3,
-                interpret=enc.tuning.interpret, save_sampled=True,
-                vmem_limit=small.vmem_budget)
-    variants = {
-        "per-level": {},
-        "fused pyramid": dict(fuse_levels=True),
-        "fused prefix [0:2)": dict(fuse_levels=True, fuse_prefix=2),
-        "fuse_gather/fuse_scatter off": dict(fuse_gather=False,
-                                             fuse_scatter=False),
-        "one-hot levels 1-2": dict(onehot_levels=(False, True, True)),
-        "regather (no saved corners)": dict(save_sampled=False),
-    }
-    for name, kw in variants.items():
-        op = ops.build_kernel_op(ops.MSDAParams(**{**base, **kw}))
-        against_ref(name, op, small, seed + 2, FWD_TOL, VJP_TOL)
-    mixed = ops.build_kernel_op(ops.MSDAParams(
-        **base, fuse_levels=True,
-        slab_dtypes=("float32", "bfloat16", "bfloat16")))
-    against_ref("fused, mixed fp32/bf16 slabs", mixed, small, seed + 2,
-                FWD_TOL, VJP_TOL, bf16_levels=(1, 2))
+        num_queries=256, dtype="float32", slab_dtype="", train=False)
+    against_ref("regather (no saved corners)",
+                msda_plan(small, backend="pallas", tune="heuristic"), small,
+                seed + 2, FWD_TOL, VJP_TOL)
 
 
 def one_chip(args, cache: CacheEvents) -> None:

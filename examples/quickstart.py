@@ -61,7 +61,8 @@ grads = jax.grad(
 )(value, loc, attn)
 print("grad shapes:", [g.shape for g in grads])
 
-# the adaptive block plan (paper Fig. 7): bigger levels -> smaller blocks
+# the adaptive block plan (paper Fig. 7): one block for the launch over
+# the packed pyramid; a bigger pyramid leaves room for a smaller block
 print("block plan:", plan.block_q)
 
 # -- the dtype-policy knob: mixed precision is a PLANNED axis -------------

@@ -99,12 +99,6 @@ class MSDAConfig:
     #              persists the winner per device kind
     # (mapped to spec fields by repro.kernels.plan.resolve_dtype_policy)
     dtype_policy: str = "follow"
-    # whole-pyramid kernel fusion — one pallas launch per direction with
-    # every level's slab packed into a single VMEM-resident super-slab:
-    #   'auto'  fuse when the packed pyramid fits the VMEM budget
-    #           (tune='autotune' races fused vs per-level instead)
-    #   'on'    force fusion, 'off' pin the per-level launches
-    fuse_levels: str = "auto"
     # top-k attention-weight point pruning (LOSSY — DEFA-style):
     #   'off'   dense MSDA, bitwise-identical to pre-sparsity plans
     #   'topk'  pin the pruned executor (keep sparsity_k cells/query)

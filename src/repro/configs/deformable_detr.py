@@ -23,7 +23,7 @@ CONFIG = register(ModelConfig(
     act="gelu",
     norm_type="layernorm",
     # plan/execute knobs: backend resolved once through the registry;
-    # tune="autotune" measures per-level block_q candidates and persists
+    # tune="autotune" measures the launch's block_q candidates and persists
     # winners per device kind (see repro.kernels.plan.msda_plan).
     # dtype_policy="auto" defers the per-level fp32-vs-bf16 slab choice
     # to the autotune race — which only runs under tune="autotune" (flip

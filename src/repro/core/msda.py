@@ -93,8 +93,8 @@ def attention_plan(
 ) -> plan_mod.MsdaPlan:
     """The module's :class:`MsdaPlan` for one static geometry (cached).
 
-    All hardware-aware decisions (backend, per-level block_q, slab
-    dtypes, MXU one-hot routing, shard_map wiring) are committed here,
+    All hardware-aware decisions (backend, block_q, slab dtypes,
+    shard_map wiring) are committed here,
     once; forwards just execute.  ``msda_cfg.tune`` selects heuristic vs
     autotuned block planning (``tune`` overrides it per call — the
     offline sweep CLI forces "autotune" on configs that default to the
@@ -103,9 +103,7 @@ def attention_plan(
     call) picks the mixed-precision plan variant — 'follow' | 'float32'
     | 'bfloat16' | 'auto' (see
     :func:`repro.kernels.plan.resolve_dtype_policy`).
-    ``msda_cfg.fuse_levels`` ('auto' | 'on' | 'off') commits the
-    whole-pyramid kernel-fusion rung (one pallas launch per direction
-    when the packed pyramid fits VMEM).  ``msda_cfg.sparsity`` /
+    ``msda_cfg.sparsity`` /
     ``sparsity_k`` / ``query_order`` commit the sparsity rungs — top-k
     point pruning (lossy, dense fallback) and the Morton query
     permutation (bitwise-neutral).  When a mesh is given,
@@ -126,7 +124,6 @@ def attention_plan(
         vmem_budget=getattr(msda_cfg, "vmem_budget", 0),
         slab_dtype=slab_dtype,
         accum_dtype=accum_dtype,
-        fuse_levels=getattr(msda_cfg, "fuse_levels", "auto"),
         sparsity=getattr(msda_cfg, "sparsity", "off"),
         sparsity_k=getattr(msda_cfg, "sparsity_k", 0),
         query_order=getattr(msda_cfg, "query_order", "identity"),

@@ -24,8 +24,7 @@ Paper mapping (xMSDA §4.2 -> TPU):
   the corners from the slab in the same launch.
 
 The grad slab is an fp32 accumulator whatever the slab dtype, so Q-many
-contributions never round through bf16.  ``fuse_scatter=False`` (the
-paper's "-Scatter Fusion" ablation) walks the corners corner-major.
+contributions never round through bf16.
 """
 from __future__ import annotations
 
@@ -46,8 +45,7 @@ from repro.obs import scopes
 
 def _scatter_kernel(idx_ref, w_ref, gout_ref, src_ref, gval_ref, gw_ref,
                     corner_scratch, *, levels: Tuple[LevelGeom, ...], P: int,
-                    G: int, D: int, qb: int, fuse_scatter: bool,
-                    regather: bool, unroll: bool):
+                    G: int, D: int, qb: int, regather: bool, unroll: bool):
     """One (batch, head group, query block) step of the backward.
 
     Phase 2: scatter-add weight x gout into the resident grad slab, one
@@ -71,33 +69,25 @@ def _scatter_kernel(idx_ref, w_ref, gout_ref, src_ref, gval_ref, gw_ref,
 
     def body(q, carry):
         g = gout_ref[pl.ds(q, 1), :]  # (1, G*D) fp32
-        for l, geom in enumerate(levels):
-            off, wp, nrows, onehot = geom
+        for l, (_, wp, _) in enumerate(levels):
 
-            def corner(p, c, carry, l=l, geom=geom):
+            def corner(p, c, carry, l=l, wp=wp):
                 def head(h, carry):
                     mine = heads == h
                     i = (idx_ref[idx_slot(h, q, l, p, qb, L, P)]
                          + corner_offset(c, wp))
                     w = w_ref[w_slot(h, q, l, c, p, qb, L, P)]
                     contrib = jnp.where(mine, w * g, 0.0)
-                    if onehot:
-                        # MXU route: the update as a one-hot outer product
-                        hot = (jax.lax.broadcasted_iota(
-                            jnp.int32, (nrows, 1), 0) == i - off).astype(
-                                jnp.float32)
-                        gval_ref[off:off + nrows, :] += hot * contrib
-                    else:
-                        gval_ref[pl.ds(i, 1), :] += contrib
+                    gval_ref[pl.ds(i, 1), :] += contrib
                     if regather:
                         keep_corner(corner_scratch,
                                     q * K + saved_slot(l, c, p, P), mine,
-                                    fetch_row(src_ref, geom, i))
+                                    fetch_row(src_ref, i))
                     return carry
 
                 return static_loop(G, head, carry, unroll)
 
-            corner_walk(P, fuse_scatter, unroll, corner, 0)
+            corner_walk(P, unroll, corner, 0)
         return carry
 
     jax.lax.fori_loop(0, qb, body, 0)
@@ -128,10 +118,8 @@ def msda_scatter(
     num_points: int,
     head_dim: int,
     block_q: int,
-    fuse_scatter: bool = True,
     interpret: bool,
     vmem_limit: int = 0,
-    first_level: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Backward over one slab: ``(grad_slab, grad_w)``.
 
@@ -139,8 +127,6 @@ def msda_scatter(
     the weight table ``w``, (B, NG, nq, G, L*4P, block_q) fp32 — the
     table's chunk layout.  ``src`` holds the corners: the forward's
     saved block, or (``regather``) the fp32 slab they are re-read from.
-    ``first_level`` is the pyramid index of ``levels[0]``, for the
-    kernel's metadata only.
     """
     B, NG, qp, GD = gout.shape
     L, P, D = len(levels), num_points, head_dim
@@ -150,7 +136,7 @@ def msda_scatter(
     K = L * 4 * P
     kernel = functools.partial(
         _scatter_kernel, levels=tuple(levels), P=P, G=G, D=D, qb=block_q,
-        fuse_scatter=fuse_scatter, regather=regather, unroll=not interpret)
+        regather=regather, unroll=not interpret)
     if regather:
         src_spec = resident((None, None, src.shape[2], GD),
                             lambda b, g, q: (b, g, 0, 0))
@@ -182,7 +168,7 @@ def msda_scatter(
         compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
         name=scopes.SCATTER_KERNEL,
-        metadata=kernel_metadata("scatter", first_level, L, block_q),
+        metadata=kernel_metadata("scatter", L, block_q),
     )(idx, w, gout, src)
     return gval, gw
 

@@ -2,9 +2,11 @@
 
 Paper mapping (xMSDA §4.1 -> TPU):
 
-* The level's (or the whole pyramid's) padded feature map is **resident
-  in VMEM** across all query blocks of one (batch, head group): the
-  paper's "feature map fits UB" insight.
+* The whole pyramid's padded feature maps, packed into one slab, are
+  **resident in VMEM** across all query blocks of one (batch, head
+  group): the paper's "feature map fits UB" insight.  One launch per
+  direction gathers every level; the corner rows are lifted by static
+  per-level row offsets outside the kernel.
 * **Scalar-addressed row gather.**  Mosaic has no general vector gather,
   so — like the paper's scalar-addressed pixel copies out of the on-chip
   buffer — the top-left corner row of every (query, head, level, point)
@@ -37,11 +39,6 @@ Paper mapping (xMSDA §4.1 -> TPU):
 * **Train mode** (``save_sampled``): the kernel also streams the raw
   gathered corners to HBM, so the backward's phase 1 (grad of the
   bilinear weights) issues no gathers.
-* **Fused whole pyramid**: one launch gathers every level from one
-  packed super-slab (the corner rows are lifted by static per-level row
-  offsets outside the kernel), accumulating the per-level partials in
-  the same order the per-level launches sum theirs — so every fusion
-  tier is bitwise identical.
 
 The slab is stored in fp32 whatever the committed slab dtype: the values
 are first rounded to that dtype (so a bf16 plan computes on bf16 data),
@@ -49,11 +46,6 @@ and Mosaic cannot address single rows of a packed 16-bit tile.
 
 Grid: ``(B, head_groups, num_q_blocks)`` with ``q`` innermost, so the
 slab block (index independent of ``q``) stays resident.
-
-Ablation variants (they compile for v5e too; ``chip_smoke.py`` checks
-each against the oracle there): ``onehot`` levels fetch each row as a
-one-hot MXU matmul; ``fuse_gather=False`` walks the corners corner-major
-(four per-corner passes) instead of point-major.
 """
 from __future__ import annotations
 
@@ -70,8 +62,8 @@ from repro.obs import scopes
 # lane width of one vreg: the head group fills it when head_dim allows
 LANES = 128
 
-# (row offset in the slab, padded width Wp, slab rows, one-hot?) per level
-LevelGeom = Tuple[int, int, int, bool]
+# (row offset in the slab, padded width Wp, slab rows) per level
+LevelGeom = Tuple[int, int, int]
 
 
 def corner_indices(loc, H: int, W: int, Wp: int):
@@ -132,15 +124,11 @@ def static_loop(n: int, body, init, unroll: bool):
     return jax.lax.fori_loop(0, n, body, init)
 
 
-def corner_walk(P: int, fuse: bool, unroll: bool, body, init):
-    """Visit every (point, corner) of one head and level: point-major for
-    the fused walk, corner-major (four per-corner passes) for the
-    ablation.  ``body(p, c, carry) -> carry``."""
-    if fuse:
-        return static_loop(P, lambda p, a: static_loop(
-            4, lambda c, b: body(p, c, b), a, unroll), init, unroll)
-    return static_loop(4, lambda c, a: static_loop(
-        P, lambda p, b: body(p, c, b), a, unroll), init, unroll)
+def corner_walk(P: int, unroll: bool, body, init):
+    """Visit every (point, corner) of one head and level, point-major.
+    ``body(p, c, carry) -> carry``."""
+    return static_loop(P, lambda p, a: static_loop(
+        4, lambda c, b: body(p, c, b), a, unroll), init, unroll)
 
 
 def corner_offset(c, wp: int):
@@ -259,16 +247,8 @@ def row_block(q, k: int):
     return pl.ds(pl.multiple_of(q * k, k & -k), k)
 
 
-def fetch_row(slab_ref, geom: LevelGeom, i):
+def fetch_row(slab_ref, i):
     """One (1, lanes) fp32 slab row at dynamic row ``i``."""
-    off, _, rows, onehot = geom
-    if onehot:
-        # MXU route: the row as a one-hot matmul against the level rows
-        hot = (jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-               == i - off).astype(jnp.float32)
-        return jnp.dot(hot, slab_ref[off:off + rows, :],
-                       precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
     return slab_ref[pl.ds(i, 1), :]
 
 
@@ -284,12 +264,10 @@ def keep_corner(row_scratch, k, mine, row):
         mine, row, row_scratch[pl.ds(k, 1), :])
 
 
-def fetch_pair(slab_ref, geom: LevelGeom, i):
+def fetch_pair(slab_ref, i):
     """Rows ``i`` and ``i + 1`` (a corner pair along x) as two (1, lanes)
     fp32 rows: one two-row load and one address, the second row rotated
     down a sublane."""
-    if geom[3]:
-        return fetch_row(slab_ref, geom, i), fetch_row(slab_ref, geom, i + 1)
     two = slab_ref[pl.ds(i, 2), :]
     return two[0:1], two[1:2]
 
@@ -312,7 +290,7 @@ def step_points(L: int, G: int, P: int) -> int:
 
 def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                    *, levels: Tuple[LevelGeom, ...], P: int, G: int, D: int,
-                   qb: int, fuse_gather: bool, unroll: bool):
+                   qb: int, unroll: bool):
     """One (batch, head group, query block) step of the forward.
 
     ``idx_ref``: this block's chunk of the SMEM corner-row table (see
@@ -330,9 +308,6 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
     step's weights meanwhile, so no chain waits for the MXU; the steps
     of a query are a rolled loop.  The corner-row table is linear in its
     indices, so a read is a static offset from the step's base.
-    ``fuse_gather=False`` walks the corners corner-major instead, a
-    rolled step per corner when the query's rows exceed
-    :data:`STEP_ROWS`, spreading each point's weights as it goes.
     """
     L = len(levels)
     S = 4 * L  # weight slots of one point
@@ -349,9 +324,7 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
     k = P if packed else step_points(L, G, P)
     steps = P // k
     # the slot picks, built once per grid step
-    if not fuse_gather:
-        grid_corner = slot_grid(L, 4, D, GD)
-    elif packed:
+    if packed:
         pick_query = slot_grid(S * P, 1, D, GD) == 0
     else:
         pick_rows = [slot_grid(min(D, S - r * D), 1, D, GD) == 0
@@ -385,19 +358,6 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
             out += [e[base + j:base + j + 1] for j in range(n)]
         return out
 
-    def corner_weights(q, p, c):
-        # corner c of point p, one weight row per level: rows of the
-        # point that do not hold a level's slot spread zeros
-        if packed:
-            e = spread_weights(weight_row(q), grid_corner == p * S + c, seg)
-        else:
-            e = None
-            for r in range(per_point):
-                part = spread_weights(weight_row((q * P + p) * per_point + r),
-                                      grid_corner == c - r * D, seg)
-                e = part if e is None else e + part
-        return [e[l:l + 1] for l in range(L)]
-
     def merged(rows):
         # each head's lanes from its own row
         out = rows[0]
@@ -423,8 +383,8 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                 corners = [[], [], [], []]
                 for h in range(G):
                     at = idx_ref[pbase + idx_slot(h, 0, l, 0, qb, L, P)]
-                    rows = fetch_pair(slab_ref, geom, at) + fetch_pair(
-                        slab_ref, geom, at + geom[1])
+                    rows = fetch_pair(slab_ref, at) + fetch_pair(
+                        slab_ref, at + geom[1])
                     for c, row in enumerate(rows):
                         corners[c].append(row)
                 acc = accs[l]
@@ -435,33 +395,13 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                 accs[l] = acc
         return base + idx_slot(0, 0, 0, k, qb, L, P), tuple(accs), spread
 
-    def corner_step(q, c, carry):
-        # corner c of every point of every chain (the corner-major walk)
-        base, accs, spread = carry
-        accs = list(accs)
-        for p in range(P):
-            ws = corner_weights(q, p, c)
-            for l, geom in enumerate(levels):
-                row = merged([fetch_row(
-                    slab_ref, geom, idx_ref[idx_slot(h, q, l, p, qb, L, P)]
-                    + corner_offset(c, geom[1])) for h in range(G)])
-                accs[l] = accs[l] + ws[l] * row
-                keep(l, c, p, row)
-        return base, tuple(accs), spread
-
-    if fuse_gather:
-        step, n, whole = point_step, steps, steps == 1
-    else:
-        step, n, whole = corner_step, 4, L * G * P * 4 <= STEP_ROWS
-
     def body(q, spread):
         # ``spread``: the weights of the query's first step, spread by
         # the step before
         _, accs, spread = static_loop(
-            n, functools.partial(step, q), (q, (zero,) * L, spread),
-            unroll and whole)
-        # the level sums added to the total in level order: the adds the
-        # per-level launches' outputs see outside (tier parity)
+            steps, functools.partial(point_step, q), (q, (zero,) * L, spread),
+            unroll and steps == 1)
+        # the level sums added to the total in level order
         total = zero
         for acc in accs:
             total = total + acc
@@ -471,7 +411,7 @@ def _gather_kernel(idx_ref, w_ref, slab_ref, out_ref, saved_ref, row_scratch,
                 saved_ref.dtype)
         return spread
 
-    jax.lax.fori_loop(0, qb, body, step_weights(0) if fuse_gather else ())
+    jax.lax.fori_loop(0, qb, body, step_weights(0))
 
 
 def compiler_params(vmem_limit: int):
@@ -484,12 +424,11 @@ def compiler_params(vmem_limit: int):
 SMEM_TILE = 1024
 
 
-def kernel_metadata(kind: str, first_level: int, levels: int,
-                    block_q: int) -> dict:
+def kernel_metadata(kind: str, levels: int, block_q: int) -> dict:
     """The metadata an MSDA kernel carries into its HLO custom call
     (``frontend_attributes={kernel_metadata={...}}``) and trace event."""
     return {"msda": kind,
-            "levels": f"{first_level}-{first_level + levels - 1}",
+            "levels": f"0-{levels - 1}",
             "block_q": str(block_q)}
 
 
@@ -520,7 +459,7 @@ def resident(shape, index_map):
 
 
 def msda_gather(
-    slab: jax.Array,   # (B, NG, R, G*D) fp32, zero-padded levels packed
+    slab: jax.Array,   # (B, NG, rows, G*D) fp32, zero-padded levels packed
     idx: jax.Array,    # flat int32 table: top-left corner rows
     w: jax.Array,      # (B, NG, Qp*R, G*D) fp32 weight rows (weight_layout)
     *,
@@ -528,11 +467,9 @@ def msda_gather(
     num_points: int,
     head_dim: int,
     block_q: int,
-    fuse_gather: bool = True,
     save_dtype=None,
     interpret: bool,
     vmem_limit: int = 0,
-    first_level: int = 0,
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Weighted corner gather over one slab: ``(out, saved)``.
 
@@ -540,8 +477,7 @@ def msda_gather(
     levels, points and corners of weight x corner row.  With
     ``save_dtype`` the raw corners also come back as (B, NG, Qp*L*4P,
     G*D) in that dtype: each query's L*4P rows in :func:`saved_slot`
-    order.  ``first_level`` is the pyramid index of ``levels[0]``, for
-    the kernel's metadata only.
+    order.
     """
     B, NG, rows, GD = slab.shape
     L, P, D = len(levels), num_points, head_dim
@@ -552,7 +488,7 @@ def msda_gather(
     K = L * 4 * P
     kernel = functools.partial(
         _gather_kernel, levels=tuple(levels), P=P, G=G, D=D, qb=block_q,
-        fuse_gather=fuse_gather, unroll=not interpret)
+        unroll=not interpret)
     out_shapes = [jax.ShapeDtypeStruct((B, NG, qp, GD), jnp.float32)]
     out_specs = [pl.BlockSpec((None, None, block_q, GD),
                               lambda b, g, q: (b, g, q, 0))]
@@ -581,7 +517,7 @@ def msda_gather(
         compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
         name=scopes.GATHER_KERNEL,
-        metadata=kernel_metadata("gather", first_level, L, block_q),
+        metadata=kernel_metadata("gather", L, block_q),
     )(idx, w, slab)
     if save_dtype is not None:
         return outs[0], outs[1]

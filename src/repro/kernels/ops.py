@@ -6,16 +6,13 @@ The *planning* surface lives in ``repro.kernels.plan`` (``MsdaSpec`` →
 
 * the layout/padding contract and the kernel driver
   (``build_kernel_op``) the pallas backend builder compiles into an
-  executor.  One launch schedule (:func:`plan_launches`) covers every
-  fusion tier: one launch per level, a fused prefix (levels packed into
-  one super-slab by ``_pack_pyramid`` / ``pyramid_row_offsets``) plus a
-  per-level tail, or the whole pyramid in ONE launch per direction,
+  executor: one launch per direction over the whole pyramid, its levels
+  packed into one slab (``_pack_pyramid`` / ``pyramid_row_offsets``),
 * the heuristic block planner (``plan_blocks`` — the paper's adaptive
-  vec-len model, Fig. 7) and the MXU one-hot routing rule
-  (``plan_onehot``), both invoked once per plan, and
+  vec-len model, Fig. 7), invoked once per plan, and
 * ``msda(...)``: a thin compatibility shim that builds a spec, fetches
   the cached plan, and executes it.  Per-call tuning kwargs
-  (``block_q``, ``fuse_gather``, …) are deprecated — commit them on the
+  (``block_q``, ``interpret``) are deprecated — commit them on the
   spec / plan instead.
 
 The layout/padding contract between the wrapper and the kernels:
@@ -129,79 +126,44 @@ def pyramid_row_offsets(spatial_shapes: Shapes) -> Tuple[Tuple[int, ...], int]:
     return tuple(offs), total
 
 
-def _per_level_itemsizes(spatial_shapes: Shapes, value_itemsize) -> Tuple[int, ...]:
-    """Normalise a scalar-or-per-level itemsize spec to a per-level tuple."""
-    if isinstance(value_itemsize, (tuple, list)):
-        assert len(value_itemsize) == len(spatial_shapes), (
-            value_itemsize, spatial_shapes)
-        return tuple(int(i) for i in value_itemsize)
-    return (int(value_itemsize),) * len(spatial_shapes)
-
-
-def fused_resident_bytes(spatial_shapes: Shapes, head_dim: int, *,
-                         heads: int = 1) -> int:
-    """VMEM-resident bytes of a fused launch over ``spatial_shapes``: the
-    packed super-slab (:func:`resident_bytes` over every level's rows).
-    The ONE definition of the packed pyramid's residency: the fitting
-    rung, the fused block planner and ``MsdaPlan.level_report`` all read
-    it from here."""
+def pyramid_resident_bytes(spatial_shapes: Shapes, head_dim: int, *,
+                           heads: int = 1) -> int:
+    """VMEM-resident bytes of a launch over ``spatial_shapes``: the packed
+    pyramid slab (:func:`resident_bytes` over every level's rows).  The
+    block planner, the plan-time fit check and ``MsdaPlan.level_report``
+    all read it from here."""
     _, total = pyramid_row_offsets(spatial_shapes)
     return resident_bytes(total, head_dim, heads=heads)
 
 
-def fusion_prefix(
+def _step_bytes(spatial_shapes: Shapes, num_points: int, head_dim: int, *,
+                value_itemsize, train: bool, heads: int) -> int:
+    """Per-query VMEM of a launch over the whole pyramid, its saved
+    corners at the widest committed slab itemsize (``value_itemsize``:
+    a scalar, or one per level)."""
+    if isinstance(value_itemsize, (tuple, list)):
+        value_itemsize = max(value_itemsize)
+    return per_query_bytes(num_points, head_dim, train=train,
+                           slab_itemsize=int(value_itemsize),
+                           levels=len(spatial_shapes), heads=heads)
+
+
+def min_launch_bytes(
     spatial_shapes: Shapes,
     num_points: int,
     head_dim: int,
     *,
     value_itemsize=4,
     train: bool = True,
-    vmem_budget: int = VMEM_BUDGET,
     heads: int = 1,
 ) -> int:
-    """The planner's partial-fusion occupancy model.
-
-    Returns the largest level prefix length ``k`` such that the packed
-    super-slab of levels ``[0..k)`` (:func:`fused_resident_bytes`) PLUS a
-    minimal one-sublane query step's working set over those ``k`` levels
-    (saved corners at the widest committed itemsize) fits
-    ``vmem_budget`` — ``k == len(spatial_shapes)`` means the whole
-    pyramid fuses, ``0`` means not even a single level does.  The fused
-    launch covers ``[0..k)`` and the tail runs per-level, so launches
-    per direction drop from ``L`` to ``L - k + 1``.
-    """
-    L = len(spatial_shapes)
-    items = _per_level_itemsizes(spatial_shapes, value_itemsize)
-    for k in range(L, 0, -1):
-        resident = fused_resident_bytes(spatial_shapes[:k], head_dim,
-                                        heads=heads)
-        per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=max(items[:k]), levels=k,
-                                heads=heads)
-        if resident + _SUBLANE * per_q <= vmem_budget:
-            return k
-    return 0
-
-
-def fused_pyramid_fits(
-    spatial_shapes: Shapes,
-    num_points: int,
-    head_dim: int,
-    *,
-    value_itemsize=4,
-    train: bool = True,
-    vmem_budget: int = VMEM_BUDGET,
-    heads: int = 1,
-) -> bool:
-    """Whole-pyramid fitting rung: does the FULL prefix fit?
-
-    Thin compatibility wrapper over :func:`fusion_prefix` — fused-all
-    exactly when the largest fitting prefix is the whole pyramid.
-    """
-    return fusion_prefix(
-        spatial_shapes, num_points, head_dim, value_itemsize=value_itemsize,
-        train=train, vmem_budget=vmem_budget,
-        heads=heads) == len(spatial_shapes)
+    """VMEM the smallest launch needs: the packed pyramid slab plus one
+    sublane step of queries.  A budget below it cannot hold the pyramid,
+    and the planner refuses the spec."""
+    return (pyramid_resident_bytes(spatial_shapes, head_dim, heads=heads)
+            + _SUBLANE * _step_bytes(
+                spatial_shapes, num_points, head_dim,
+                value_itemsize=value_itemsize, train=train, heads=heads))
 
 
 def plan_blocks(
@@ -213,16 +175,13 @@ def plan_blocks(
     value_itemsize=4,
     train: bool = True,
     vmem_budget: int = VMEM_BUDGET,
-    adaptive: bool = True,
-    fused: bool = False,
     heads: int = 1,
-) -> Tuple[int, ...]:
-    """Per-level query-block sizes (the paper's adaptive vec-len, Fig. 7).
+) -> int:
+    """Query block of the launch (the paper's adaptive vec-len, Fig. 7).
 
-    Larger levels leave less VMEM for per-step tensors, so their blocks
-    shrink; tiny levels get wide blocks (long vectors).  ``adaptive=False``
-    reproduces the "-Adaptive VecLen" ablation (fixed minimal block).
-    Every block also respects the SMEM cap of its table chunks
+    The packed pyramid slab stays resident; the block takes the VMEM it
+    leaves, so smaller pyramids get wider blocks (long vectors).  The
+    block also respects the SMEM cap of its table chunks
     (:func:`smem_block_cap`).
 
     ``value_itemsize`` is the itemsize of the committed slab dtype — a
@@ -230,103 +189,44 @@ def plan_blocks(
     it sizes the train-mode saved corners (the resident slab is fp32
     whatever the dtype, see :func:`resident_bytes`).  ``heads`` is the
     head group one launch holds on its lanes.
-
-    ``fused=True`` plans the whole-pyramid kernel instead: the resident
-    set is the PACKED super-slab and one shared block serves every level
-    — returned replicated per level so the tuple shape stays uniform.
-    To plan a partial-fusion prefix, pass the prefix's shapes/itemsizes
-    only.
     """
-    def _clamp(bq: int, levels: int) -> int:
-        bq = min(bq, smem_block_cap(num_points, levels=levels, heads=heads,
-                                    train=train))
-        bq = max(_SUBLANE, min(2048, (bq // _SUBLANE) * _SUBLANE))
-        return min(bq, _round_up(num_queries, _SUBLANE))
-
-    items = _per_level_itemsizes(spatial_shapes, value_itemsize)
-    if fused:
-        L = len(spatial_shapes)
-        if not adaptive:
-            return (_SUBLANE,) * L
-        resident = fused_resident_bytes(spatial_shapes, head_dim, heads=heads)
-        avail = max(vmem_budget - resident, 1 * 2**20)
-        per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=max(items), levels=L,
-                                heads=heads)
-        return (int(_clamp(avail // per_q, L)),) * L
-
-    out = []
-    for hw, it in zip(spatial_shapes, items):
-        if not adaptive:
-            out.append(_SUBLANE)
-            continue
-        resident = resident_bytes(slab_rows(hw), head_dim, heads=heads)
-        avail = max(vmem_budget - resident, 1 * 2**20)
-        per_q = per_query_bytes(num_points, head_dim, train=train,
-                                slab_itemsize=it, heads=heads)
-        out.append(int(_clamp(avail // per_q, 1)))
-    return tuple(out)
+    L = len(spatial_shapes)
+    resident = pyramid_resident_bytes(spatial_shapes, head_dim, heads=heads)
+    avail = max(vmem_budget - resident, 1 * 2**20)
+    per_q = _step_bytes(spatial_shapes, num_points, head_dim,
+                        value_itemsize=value_itemsize, train=train,
+                        heads=heads)
+    bq = min(avail // per_q, smem_block_cap(num_points, levels=L, heads=heads,
+                                            train=train))
+    bq = max(_SUBLANE, min(2048, (bq // _SUBLANE) * _SUBLANE))
+    return int(min(bq, _round_up(num_queries, _SUBLANE)))
 
 
 @dataclass(frozen=True)
 class MSDAParams:
-    """Static (hashable) kernel configuration."""
+    """Static (hashable) kernel configuration: one launch per direction
+    over the packed pyramid."""
 
     spatial_shapes: Shapes
-    block_q: Tuple[int, ...]
+    block_q: int
     # True runs the kernels in the Pallas interpreter (CPU tests).  No
     # default: a TPU build must say it compiles them with Mosaic.
     interpret: bool
-    fuse_gather: bool = True
-    fuse_scatter: bool = True
     save_sampled: bool = False
-    # per-level: fetch and update rows through one-hot MXU matmuls
-    # (beyond-paper ablation of the row loop)
-    onehot_levels: Tuple[bool, ...] = ()
     # mixed precision: per-level dtype the value slab is rounded to
     # ('' entries / empty tuple -> keep the operand dtype); the kernels
     # accumulate outputs and the grad slab in fp32
     slab_dtypes: Tuple[str, ...] = ()
-    # fused whole-pyramid kernels: levels packed into ONE super-slab,
-    # one pallas launch per direction with a single shared block_q
-    # (block_q[0]; the planner replicates it across the fused levels)
-    fuse_levels: bool = False
-    # partial fusion: number of levels in the fused prefix [0..k).
-    # 0 means "all levels" when fuse_levels is set (whole-pyramid
-    # fusion); 0 < k < L runs ONE fused launch over the prefix plus
-    # per-level launches for the tail, summed into the same accumulator.
-    fuse_prefix: int = 0
     # Mosaic's scoped-VMEM limit for every launch (the plan's budget);
     # 0 leaves the compiler default
     vmem_limit: int = 0
 
-    def slab_dtype(self, level: int) -> str:
-        if self.slab_dtypes and self.slab_dtypes[level]:
-            return self.slab_dtypes[level]
-        return ""
-
-    def fused_prefix_len(self) -> int:
-        """Committed fused prefix length k: L when fully fused, 0 when
-        per-level, else the strict prefix ``0 < k < L``."""
-        L = len(self.spatial_shapes)
-        if not self.fuse_levels:
-            return 0
-        return min(self.fuse_prefix, L) if self.fuse_prefix else L
-
-    def fused_slab_dtypes(self, operand_dtype) -> Tuple[str, ...]:
+    def level_dtypes(self, operand_dtype) -> Tuple[str, ...]:
         """Per-level slab dtypes: the committed one, else the operand
-        dtype.  A packed super-slab rounds each level to its own dtype
-        (see :func:`_pack_pyramid`)."""
-        return tuple(self.slab_dtype(l) or str(jnp.dtype(operand_dtype))
-                     for l in range(len(self.spatial_shapes)))
-
-
-# levels with padded slabs up to this many rows use the MXU one-hot path
-ONEHOT_MAX_ROWS = 1152
-
-
-def plan_onehot(spatial_shapes: Shapes) -> Tuple[bool, ...]:
-    return tuple(slab_rows(hw) <= ONEHOT_MAX_ROWS for hw in spatial_shapes)
+        dtype.  The packed slab rounds each level to its own dtype (see
+        :func:`_pack_pyramid`)."""
+        dts = self.slab_dtypes or ("",) * len(self.spatial_shapes)
+        return tuple(d or str(jnp.dtype(operand_dtype)) for d in dts)
 
 
 def _pad_level(value_t: jax.Array, offset: int, hw: Tuple[int, int]) -> jax.Array:
@@ -380,36 +280,12 @@ def _pack_pyramid(value_t: jax.Array, spatial_shapes: Shapes,
     return jnp.concatenate(parts, axis=2)
 
 
-@dataclass(frozen=True)
-class Launch:
-    """One kernel launch of a plan: the levels it covers, its query
-    block, and whether it runs over a packed super-slab (the fused
-    prefix or whole pyramid) or over one level's own slab."""
-
-    levels: Tuple[int, ...]
-    block_q: int
-    packed: bool
-
-
-def plan_launches(p: MSDAParams) -> Tuple[Launch, ...]:
-    """The launch schedule of ``p``, the same in both directions: the
-    whole pyramid, a fused prefix plus a per-level tail, or one launch
-    per level."""
-    L = len(p.spatial_shapes)
-    k = p.fused_prefix_len()
-    out = [Launch(tuple(range(k)), p.block_q[0], True)] if k else []
-    out += [Launch((l,), p.block_q[l], False) for l in range(k, L)]
-    return tuple(out)
-
-
-def _launch_geometry(p: MSDAParams, launch: Launch):
-    """((row offset, Wp, slab rows, one-hot?) per level, total rows)."""
-    hws = [p.spatial_shapes[l] for l in launch.levels]
-    offs, total = pyramid_row_offsets(hws)
-    geoms = tuple(
-        (off, hw[1] + 2, slab_rows(hw),
-         bool(p.onehot_levels[l]) if p.onehot_levels else False)
-        for off, hw, l in zip(offs, hws, launch.levels))
+def _pyramid_geometry(p: MSDAParams):
+    """((row offset, Wp, slab rows) per level, total rows) of the packed
+    pyramid slab."""
+    offs, total = pyramid_row_offsets(p.spatial_shapes)
+    geoms = tuple((off, hw[1] + 2, slab_rows(hw))
+                  for off, hw in zip(offs, p.spatial_shapes))
     return geoms, total
 
 
@@ -424,11 +300,13 @@ def _level_tables(p: MSDAParams, loc, attn, G: int, qmax: int):
     corner weights (corner-major) with the validity mask and the
     attention weight folded in.  Queries past Q are zero-weight padding.
 
-    A scan over levels: the loop body (and its transpose) is compiled
-    apart from whatever a fusion tier does with the tables, so XLA
-    cannot fuse — and FMA-contract — the weight math differently per
-    tier (tier parity is bitwise).  The query axis is minor-most, so the
-    TPU layouts of the tables are dense and cheap to transpose.
+    A scan over levels, whose loop body (and its transpose) XLA compiles
+    apart from the table layouts that follow.  Nothing binds it to a
+    scan any more: every plan runs one launch over the packed pyramid,
+    so no second schedule has to see the same weight bits.  Building
+    the tables in the kernel's layout in one fusion is the open
+    performance work.  The query axis is minor-most, so the TPU layouts
+    of the tables are dense and cheap to transpose.
     """
     B, Q, H, L, P, _ = loc.shape
     NG = H // G
@@ -470,18 +348,17 @@ def _flat_table(x: jax.Array) -> jax.Array:
 
 
 def _chunked(x: jax.Array, block_q: int) -> jax.Array:
-    """(Ll, B, NG, G, n, Qp) level tables of one launch -> its flat SMEM
-    table, chunks laid out (head, level, n, query) — see
-    ``msda_fwd.idx_slot`` / ``w_slot``.  The transpose keeps the
-    query-block axis minor."""
+    """(L, B, NG, G, n, Qp) level tables -> the flat SMEM table, chunks
+    laid out (head, level, n, query) — see ``msda_fwd.idx_slot`` /
+    ``w_slot``.  The transpose keeps the query-block axis minor."""
     Ll, B, NG, G, n, qp = x.shape
     x = x.reshape(Ll, B, NG, G, n, qp // block_q, block_q)
     return _flat_table(jnp.transpose(x, (1, 2, 5, 3, 0, 4, 6)))
 
 
 def _weight_rows(w: jax.Array, head_dim: int) -> jax.Array:
-    """(Ll, B, NG, G, 4*P, Qp) level weights of one launch -> the
-    forward's VMEM weight rows (B, NG, Qp*R, G*D), each weight in its
+    """(L, B, NG, G, 4*P, Qp) level weights -> the forward's VMEM weight
+    rows (B, NG, Qp*R, G*D), each weight in its
     ``msda_fwd.weight_lane``.  Data movement only: the slots are ordered
     and padded with the query minor, then one transpose brings the query
     major and the heads' slots onto the lanes."""
@@ -498,147 +375,108 @@ def _weight_rows(w: jax.Array, head_dim: int) -> jax.Array:
 
 
 def _corner_tables(p: MSDAParams, loc, attn, head_dim: int):
-    """Per launch, the tables ``(idx, wrows, w)`` the kernels consume:
-    :func:`_level_tables` cut to the launch's levels and padded queries,
-    corner rows lifted by the levels' row offsets in the launch slab and
-    chunked by :func:`_chunked` into the SMEM table; the weights as the
-    forward's VMEM rows (:func:`_weight_rows`) and as the backward's
-    SMEM table.  A program that takes no gradient never reads the last,
-    and XLA drops it.  Data movement only."""
+    """The tables ``(idx, wrows, w)`` the kernels consume:
+    :func:`_level_tables` over the padded queries, corner rows lifted by
+    the levels' row offsets in the packed slab and chunked by
+    :func:`_chunked` into the SMEM table; the weights as the forward's
+    VMEM rows (:func:`_weight_rows`) and as the backward's SMEM table.
+    A program that takes no gradient never reads the last, and XLA
+    drops it.  Data movement only."""
     B, Q, H, L, P, _ = loc.shape
     G = msda_fwd.head_group(H, head_dim)
-    launches = plan_launches(p)
-    qmax = max(_round_up(Q, launch.block_q) for launch in launches)
-    idx_all, w_all = _level_tables(p, loc, attn, G, qmax)
-    idxs, wrows, ws = [], [], []
-    for launch in launches:
-        geoms, _ = _launch_geometry(p, launch)
-        lo, hi = launch.levels[0], launch.levels[-1] + 1
-        qp = _round_up(Q, launch.block_q)
-        offs = jnp.asarray([g[0] for g in geoms], jnp.int32)
-        idx = idx_all[lo:hi, ..., :qp] + offs[:, None, None, None, None, None]
-        idxs.append(_chunked(idx, launch.block_q))
-        wrows.append(_weight_rows(w_all[lo:hi, ..., :qp], head_dim))
-        ws.append(_chunked(w_all[lo:hi, ..., :qp], launch.block_q))
-    return tuple(idxs), tuple(wrows), tuple(ws)
+    qp = _round_up(Q, p.block_q)
+    idx, w = _level_tables(p, loc, attn, G, qp)
+    offs, _ = pyramid_row_offsets(p.spatial_shapes)
+    idx = idx + jnp.asarray(offs, jnp.int32)[:, None, None, None, None, None]
+    return (_chunked(idx, p.block_q), _weight_rows(w, head_dim),
+            _chunked(w, p.block_q))
 
 
-def _launch_slab(p: MSDAParams, launch: Launch, value_g, operand_dtype):
-    """The fp32 slab one launch keeps resident: one level's padded slab,
-    or the packed super-slab of a fused launch."""
-    dts = p.fused_slab_dtypes(operand_dtype)
-    if launch.packed:
-        k = len(launch.levels)
-        return _pack_pyramid(value_g, p.spatial_shapes[:k], dts[:k])
-    (l,) = launch.levels
-    start = sum(h * w for h, w in p.spatial_shapes[:l])
-    lvl = _pad_level(value_g, start, p.spatial_shapes[l])
-    return lvl.astype(dts[l]).astype(jnp.float32)
-
-
-def _corner_dtype(p: MSDAParams, launch: Launch, operand_dtype):
-    """Dtype the raw corners of a launch are kept in: the widest slab
-    dtype among its levels (every corner is already rounded to its own
-    level's dtype, so this is exact)."""
-    dts = p.fused_slab_dtypes(operand_dtype)
-    return max((jnp.dtype(dts[l]) for l in launch.levels),
+def _corner_dtype(p: MSDAParams, operand_dtype):
+    """Dtype the raw corners are kept in: the widest slab dtype among the
+    levels (every corner is already rounded to its own level's dtype, so
+    this is exact)."""
+    return max((jnp.dtype(d) for d in p.level_dtypes(operand_dtype)),
                key=lambda d: d.itemsize)
 
 
-def _unpack_grad(gslab, p: MSDAParams, launch: Launch, geoms):
-    """Grad slab of one launch -> per-level (B, NG, h*w, G*D) grads."""
-    return [_unpad_grad(gslab[:, :, off:off + rows], p.spatial_shapes[l])
-            for l, (off, _, rows, _) in zip(launch.levels, geoms)]
-
-
 def _kernel_gather(p: MSDAParams, dims, operand_dtype):
-    """Custom-VJP weighted corner gather ``(value, idxs, wrows, ws) ->
+    """Custom-VJP weighted corner gather ``(value, idx, wrows, w) ->
     out``.
 
     ``dims`` = (B, S, H, D, Q, P) are static.  The forward runs the
-    gather kernel per launch on the weight rows ``wrows`` (saving
-    corners in train mode); the backward runs the scatter kernel per
-    launch on the weight table ``ws`` (regathering corners when none
-    were saved), which also returns that table's grads.  Both weight
-    layouts hold the same weights, so the gradient reaches them through
-    ``ws`` alone and ``wrows`` gets none.
+    gather kernel over the packed pyramid on the weight rows ``wrows``
+    (saving corners in train mode); the backward runs the scatter kernel
+    on the weight table ``w`` (regathering corners when none were
+    saved), which also returns that table's grads.  Both weight layouts
+    hold the same weights, so the gradient reaches them through ``w``
+    alone and ``wrows`` gets none.
     """
     B, S, H, D, Q, P = dims
     G = msda_fwd.head_group(H, D)
     NG = H // G
-    launches = plan_launches(p)
+    geoms, rows = _pyramid_geometry(p)
+    qp = _round_up(Q, p.block_q)
 
     def grouped_value(value):
         return jnp.transpose(value.reshape(B, S, NG, G * D), (0, 2, 1, 3))
 
-    def run_fwd(value, idxs, wrows, save):
+    def run_fwd(value, idx, wrows, save):
         with jax.named_scope(scopes.MSDA_FWD):
             with jax.named_scope(scopes.MSDA_SLAB):
                 value_g = grouped_value(value)
             with jax.named_scope(scopes.MSDA_REDUCE):
                 out = jnp.zeros((B, NG, Q, G * D), jnp.float32)
-            slabs, saved = [], []
-            for launch, idx, w in zip(launches, idxs, wrows):
-                geoms, _ = _launch_geometry(p, launch)
-                with jax.named_scope(scopes.MSDA_SLAB):
-                    slab = _launch_slab(p, launch, value_g, operand_dtype)
-                with jax.named_scope(scopes.MSDA_KERNEL):
-                    o, s = msda_fwd.msda_gather(
-                        slab, idx, w, levels=geoms, num_points=P, head_dim=D,
-                        block_q=launch.block_q, fuse_gather=p.fuse_gather,
-                        save_dtype=(_corner_dtype(p, launch, operand_dtype)
-                                    if save else None),
-                        interpret=p.interpret, vmem_limit=p.vmem_limit,
-                        first_level=launch.levels[0])
-                with jax.named_scope(scopes.MSDA_REDUCE):
-                    out = out + o[:, :, :Q]
-                slabs.append(slab)
-                saved.append(s)
+            with jax.named_scope(scopes.MSDA_SLAB):
+                slab = _pack_pyramid(value_g, p.spatial_shapes,
+                                     p.level_dtypes(operand_dtype))
+            with jax.named_scope(scopes.MSDA_KERNEL):
+                o, saved = msda_fwd.msda_gather(
+                    slab, idx, wrows, levels=geoms, num_points=P, head_dim=D,
+                    block_q=p.block_q,
+                    save_dtype=(_corner_dtype(p, operand_dtype)
+                                if save else None),
+                    interpret=p.interpret, vmem_limit=p.vmem_limit)
             with jax.named_scope(scopes.MSDA_REDUCE):
+                # added onto zeros, so a -0.0 sum leaves as +0.0
+                out = out + o[:, :, :Q]
                 out = jnp.transpose(out, (0, 2, 1, 3)).reshape(B, Q, H * D)
                 out = out.astype(operand_dtype)
-            return out, (tuple(slabs), tuple(saved))
+            return out, (slab, saved)
 
     @jax.custom_vjp
-    def gather(value, idxs, wrows, ws):
-        return run_fwd(value, idxs, wrows, save=False)[0]
+    def gather(value, idx, wrows, w):
+        return run_fwd(value, idx, wrows, save=False)[0]
 
-    def fwd(value, idxs, wrows, ws):
-        out, (slabs, saved) = run_fwd(value, idxs, wrows, p.save_sampled)
-        # train plans keep the corners, inference plans the slabs
-        return out, (None if p.save_sampled else slabs,
-                     saved if p.save_sampled else None, idxs, ws)
+    def fwd(value, idx, wrows, w):
+        out, (slab, saved) = run_fwd(value, idx, wrows, p.save_sampled)
+        # train plans keep the corners, inference plans the slab
+        return out, (None if p.save_sampled else slab,
+                     saved if p.save_sampled else None, idx, w)
 
     def bwd(res, gout):
         with jax.named_scope(scopes.MSDA_BWD):
-            slabs, saved, idxs, ws = res
+            slab, saved, idx, w = res
             with jax.named_scope(scopes.MSDA_SLAB):
                 gout_g = jnp.transpose(
                     gout.astype(jnp.float32).reshape(B, Q, NG, G * D),
                     (0, 2, 1, 3))
-            gvals, gws = [], []
-            for i, (launch, idx, w) in enumerate(zip(launches, idxs, ws)):
-                geoms, rows = _launch_geometry(p, launch)
-                qp = _round_up(Q, launch.block_q)
-                with jax.named_scope(scopes.MSDA_SLAB):
-                    gout_p = _pad_q(gout_g, 2, qp, 0.0)
-                with jax.named_scope(scopes.MSDA_KERNEL):
-                    gslab, gw = msda_bwd.msda_scatter(
-                        gout_p, idx, w,
-                        slabs[i] if saved is None else saved[i],
-                        regather=saved is None, rows=rows, levels=geoms,
-                        num_points=P, head_dim=D, block_q=launch.block_q,
-                        fuse_scatter=p.fuse_scatter, interpret=p.interpret,
-                        vmem_limit=p.vmem_limit, first_level=launch.levels[0])
-                with jax.named_scope(scopes.MSDA_GRAD_UNPACK):
-                    gvals += _unpack_grad(gslab, p, launch, geoms)
-                    gws.append(_flat_table(gw))
+                gout_p = _pad_q(gout_g, 2, qp, 0.0)
+            with jax.named_scope(scopes.MSDA_KERNEL):
+                gslab, gw = msda_bwd.msda_scatter(
+                    gout_p, idx, w, slab if saved is None else saved,
+                    regather=saved is None, rows=rows, levels=geoms,
+                    num_points=P, head_dim=D, block_q=p.block_q,
+                    interpret=p.interpret, vmem_limit=p.vmem_limit)
             with jax.named_scope(scopes.MSDA_GRAD_UNPACK):
+                gvals = [_unpad_grad(gslab[:, :, off:off + n], hw)
+                         for (off, _, n), hw in zip(geoms, p.spatial_shapes)]
+                gw = _flat_table(gw)
                 gvalue = jnp.concatenate(gvals, axis=2)  # (B,NG,S,G*D)
                 gvalue = jnp.transpose(gvalue, (0, 2, 1, 3)).reshape(B, S, H, D)
                 gvalue = gvalue.astype(operand_dtype)
-            gidx = tuple(np.zeros(i.shape, jax.dtypes.float0) for i in idxs)
-            return gvalue, gidx, None, tuple(gws)
+            gidx = np.zeros(idx.shape, jax.dtypes.float0)
+            return gvalue, gidx, None, gw
 
     gather.defvjp(fwd, bwd)
     return gather
@@ -661,9 +499,9 @@ def build_kernel_op(p: MSDAParams):
         # is the backward's grad-loc / grad-attn (scopes.layer_of)
         with jax.named_scope(scopes.MSDA_FWD), \
                 jax.named_scope(scopes.MSDA_TABLES):
-            idxs, wrows, ws = _corner_tables(p, loc, attn, D)
+            idx, wrows, w = _corner_tables(p, loc, attn, D)
         return _kernel_gather(p, (B, S, H, D, Q, P), value.dtype)(
-            value, idxs, wrows, ws)
+            value, idx, wrows, w)
 
     return jax.jit(op)
 
@@ -699,15 +537,10 @@ def msda(
     backend: str = "auto",
     train: bool = False,
     dtype_policy: str = "follow",
-    fuse_levels: str = "auto",
     sparsity: str = "off",
     sparsity_k: int = 0,
     query_order: str = "identity",
     block_q=_UNSET,
-    fuse_gather=_UNSET,
-    fuse_scatter=_UNSET,
-    adaptive_block=_UNSET,
-    onehot_small_levels=_UNSET,
     interpret=_UNSET,
 ) -> jax.Array:
     """Multi-scale deformable attention (differentiable) — compat shim.
@@ -721,29 +554,19 @@ def msda(
     identical spec never re-run block planning.  ``dtype_policy``
     ('follow' | 'float32' | 'bfloat16' | 'auto') commits the
     mixed-precision plan variant (bf16 slab + fp32 accumulate; see
-    ``plan.resolve_dtype_policy``).  ``fuse_levels``
-    ('auto' | 'on' | 'off') commits the whole-pyramid kernel fusion
-    rung (one pallas launch per direction when the packed pyramid fits
-    VMEM).  ``sparsity`` / ``sparsity_k`` / ``query_order`` commit the
-    sparsity rungs: DEFA-style top-k point pruning (lossy, dense
-    fallback — see ``kernels/msda_sparse.py``) and the bitwise-neutral
-    Morton query permutation.  The per-call tuning kwargs
-    (``block_q``, ``fuse_gather``, ``fuse_scatter``,
-    ``adaptive_block``, ``onehot_small_levels``, ``interpret``) are
-    deprecated; put them on the spec / plan instead.
+    ``plan.resolve_dtype_policy``).  ``sparsity`` / ``sparsity_k`` /
+    ``query_order`` commit the sparsity rungs: DEFA-style top-k point
+    pruning (lossy, dense fallback — see ``kernels/msda_sparse.py``)
+    and the bitwise-neutral Morton query permutation.  The per-call
+    tuning kwargs (``block_q``, ``interpret``) are deprecated; put them
+    on the spec / plan instead.
     """
     from repro.kernels import plan as plan_mod
 
     slab_dtype, accum_dtype = plan_mod.resolve_dtype_policy(dtype_policy)
     overrides = {"slab_dtype": slab_dtype, "accum_dtype": accum_dtype,
-                 "fuse_levels": fuse_levels, "sparsity": sparsity,
-                 "sparsity_k": sparsity_k, "query_order": query_order}
-    for name, val in (("fuse_gather", fuse_gather), ("fuse_scatter", fuse_scatter),
-                      ("adaptive_block", adaptive_block),
-                      ("onehot_small_levels", onehot_small_levels)):
-        if val is not _UNSET:
-            _deprecated_kwarg(name)
-            overrides[name] = val
+                 "sparsity": sparsity, "sparsity_k": sparsity_k,
+                 "query_order": query_order}
     plan_kwargs = {}
     for name, val in (("block_q", block_q), ("interpret", interpret)):
         if val is not _UNSET:

@@ -3,11 +3,10 @@
 Contract (the one rule of the system — ``docs/architecture.md``):
 every hardware-aware decision is committed HERE, at plan time, and
 execution only executes.  ``MsdaSpec`` (frozen geometry) resolves via
-``msda_plan`` into an ``MsdaPlan`` carrying the backend, per-level
-blocks + slab dtypes, the whole-pyramid fusion decision
-(``fuse_levels`` — one pallas launch per direction when the packed
-pyramid fits VMEM; heuristic fitting model or autotuned race, winners
-persisted per device kind), and — when a mesh is given — the sharding
+``msda_plan`` into an ``MsdaPlan`` carrying the backend, the query
+block of its one Pallas launch per direction over the packed pyramid
+(heuristic fitting model or autotuned race, winners persisted per
+device kind), the per-level slab dtypes, and — when a mesh is given — the sharding
 mode: the 1D query/head/batch ladder or the 2D dp x tp query tiling
 with ring-reduced grad_value slabs, plus the raced ring-vs-psum
 grad_value reduction (``docs/sharding.md``).  Plans live in a bounded
@@ -15,9 +14,9 @@ LRU; ``plan.describe()`` states everything that was committed.
 
 The paper's central observation is that MSDA gets fast only when the
 *static* problem geometry — level shapes, points, head dim, the VMEM
-budget — is exploited ahead of time: adaptive vec-len planning (Fig. 7),
-gather/scatter fusion and the MXU one-hot routing are all compile-time
-decisions.  This module makes those decisions a first-class artifact:
+budget — is exploited ahead of time: adaptive vec-len planning (Fig. 7)
+is a compile-time decision.  This module makes those decisions a
+first-class artifact:
 
 * :class:`MsdaSpec` — frozen, hashable description of one MSDA problem
   (spatial shapes, heads, head dim, points, queries, dtype, train flag,
@@ -32,8 +31,8 @@ decisions.  This module makes those decisions a first-class artifact:
   :class:`MsdaPlan`.
 * :class:`MsdaPlan` — the executable artifact: ``plan(value, loc, attn)``
   runs the op (differentiable; the custom VJP was built at plan time) and
-  ``plan.describe()`` reports per-level ``block_q``, slab bytes, the
-  committed slab dtype, VMEM occupancy and the chosen gather path.
+  ``plan.describe()`` reports the launch's ``block_q``, per-level slab
+  bytes, the committed slab dtype and VMEM occupancy.
 
 Plans are cached in an explicit, bounded LRU (:func:`clear_plans`,
 :func:`plan_cache_info`) — repeated calls with an identical spec return
@@ -73,21 +72,6 @@ Shapes = Tuple[Tuple[int, int], ...]
 
 _SUBLANE = 8
 
-FUSE_LEVELS_CHOICES = ("auto", "on", "off")
-
-
-def _is_prefix_pin(value: Any) -> bool:
-    """True for a ``"prefix:k"`` fuse_levels pin (k >= 1): commit the
-    partial-fusion tier with a fused prefix of exactly k levels."""
-    if not (isinstance(value, str) and value.startswith("prefix:")):
-        return False
-    try:
-        return int(value.split(":", 1)[1]) >= 1
-    except ValueError:
-        return False
-
-
-
 SPARSITY_CHOICES = ("off", "topk", "auto")
 QUERY_ORDER_CHOICES = ("identity", "morton", "auto")
 
@@ -116,9 +100,9 @@ DEVICE_VMEM_BUDGETS: Tuple[Tuple[str, int], ...] = (
     ("v3", 16 * 2**20),
     ("v2", 16 * 2**20),
 )
-# the Pallas interpreter (CPU hosts) has no VMEM; plans there keep a
-# nominal budget so their tiling stays comparable across hosts
-_INTERPRET_VMEM_BUDGET = 32 * 2**20
+# the Pallas interpreter (CPU hosts) has no VMEM; plans there keep the
+# v5e figure, so a pyramid the chip holds plans on a CPU too, tiled alike
+_INTERPRET_VMEM_BUDGET = 100 * 2**20
 
 
 def default_vmem_budget(device_kind: Optional[str] = None) -> int:
@@ -164,11 +148,6 @@ class MsdaSpec:
     dtype: str = "float32"
     train: bool = False
     vmem_budget: int = 0  # 0 -> per-device default
-    # tuning-surface flags (kept on the spec so ablations stay plannable)
-    fuse_gather: bool = True
-    fuse_scatter: bool = True
-    adaptive_block: bool = True
-    onehot_small_levels: bool = False
     # -- precision policy (the second planned axis) -----------------------
     # slab_dtype: dtype each level's values are ROUNDED to before they
     # enter the (always fp32) VMEM slab.  '' follows the operand dtype;
@@ -182,19 +161,7 @@ class MsdaSpec:
     # the Pallas kernels always accumulate in fp32.
     slab_dtype: str = ""
     accum_dtype: str = "float32"
-    # -- pyramid kernel fusion tiers (the third planned axis) -------------
-    # 'auto' plans the largest level prefix [0..k) whose packed
-    # super-slab + per-query working set fits the VMEM budget
-    # (ops.fusion_prefix): a full fit fuses the whole pyramid, a strict
-    # prefix commits the partial-fusion tier (one fused launch over the
-    # prefix + per-level tail launches), no useful prefix stays
-    # per-level.  tune="autotune" races full-fuse vs the model's prefix
-    # vs per-level instead of trusting the model.  'on'/'off' pin the
-    # whole-pyramid/per-level extremes; 'prefix:k' pins the tier.  Only
-    # kernel backends that understand fusion (pallas) honour any of
-    # this; others stay per-level.
-    fuse_levels: str = "auto"
-    # -- sparsity (the fourth planned axis) -------------------------------
+    # -- sparsity (the third planned axis) --------------------------------
     # 'off' executes dense MSDA exactly as before (bitwise-identical
     # plans); 'topk' pins the pruned executor — keep the sparsity_k
     # highest-weight (level, point) cells per query, renormalise, gather
@@ -206,7 +173,7 @@ class MsdaSpec:
     # cells kept per query under 'topk'; 0 -> ceil(L*P / 2), always
     # clamped to L*P (see resolved_sparsity_k)
     sparsity_k: int = 0
-    # -- query ordering (the fifth planned axis) --------------------------
+    # -- query ordering (the fourth planned axis) -------------------------
     # 'morton' permutes queries into reference-pixel Z-curve order at the
     # executor boundary (inverted on output) so near-in-space queries
     # gather near-in-slab corners (QUILL-style locality).  Bitwise-
@@ -222,11 +189,6 @@ class MsdaSpec:
         if self.slab_dtype not in ("", "auto"):
             object.__setattr__(self, "slab_dtype", str(jnp.dtype(self.slab_dtype)))
         object.__setattr__(self, "accum_dtype", str(jnp.dtype(self.accum_dtype)))
-        if (self.fuse_levels not in FUSE_LEVELS_CHOICES
-                and not _is_prefix_pin(self.fuse_levels)):
-            raise ValueError(
-                f"unknown fuse_levels {self.fuse_levels!r}; "
-                f"one of {FUSE_LEVELS_CHOICES} or 'prefix:k' (k >= 1)")
         if self.sparsity not in SPARSITY_CHOICES:
             raise ValueError(
                 f"unknown sparsity {self.sparsity!r}; "
@@ -275,12 +237,6 @@ class MsdaSpec:
 
         return msda_fwd.head_group(self.num_heads, self.head_dim)
 
-    def fuse_prefix_pin(self) -> int:
-        """The k of a ``"prefix:k"`` fuse_levels pin, else 0."""
-        if _is_prefix_pin(self.fuse_levels):
-            return int(self.fuse_levels.split(":", 1)[1])
-        return 0
-
     def resolved_sparsity_k(self) -> int:
         """Cells kept per query when the pruned executor runs (0 pins
         the half-the-cells default; always clamped to the cell count)."""
@@ -301,11 +257,20 @@ def spec_to_json(spec: MsdaSpec) -> Dict[str, Any]:
     return d
 
 
+# spec fields and winner keys of plan choices no build makes any more
+# (the fusion tiers, the corner-major walks, the one-hot row route and
+# the fixed-block ablation): stores and winner caches that carry them
+# are read with the keys dropped
+RETIRED_KEYS = ("fuse_levels", "fuse_prefix", "fuse_gather", "fuse_scatter",
+                "adaptive_block", "onehot_small_levels", "onehot_levels")
+
+
 def spec_from_json(d: Dict[str, Any]) -> MsdaSpec:
-    """Inverse of :func:`spec_to_json`.  Unknown keys raise — the plan
-    store is versioned, so a field this build doesn't know means the
-    entry was written by a newer schema and must not be half-loaded."""
-    d = dict(d)
+    """Inverse of :func:`spec_to_json`.  :data:`RETIRED_KEYS` are
+    dropped; other unknown keys raise — the plan store is versioned, so
+    a field this build doesn't know means the entry was written by a
+    newer schema and must not be half-loaded."""
+    d = {k: v for k, v in d.items() if k not in RETIRED_KEYS}
     known = {f.name for f in dataclasses.fields(MsdaSpec)}
     unknown = sorted(set(d) - known)
     if unknown:
@@ -369,22 +334,14 @@ def spec_from_arrays(
 class PlanTuning:
     """Resolved per-plan tuning knobs handed to the backend builder."""
 
+    # the launch's query block, one entry per level (all equal: the one
+    # launch shares it across the packed pyramid)
     block_q: Tuple[int, ...]
-    onehot_levels: Tuple[bool, ...]
     interpret: bool
     source: str = "heuristic"  # heuristic | autotune | autotune-cache | override
     # per-level committed slab storage dtype; () -> the spec's resolved
     # slab dtype for every level (autotune may mix fp32/bf16 per level)
     slab_dtypes: Tuple[str, ...] = ()
-    # committed pyramid-fusion decision: one pallas launch per direction
-    # over the fused levels (the fused share of block_q is one shared
-    # value, replicated across those levels)
-    fuse_levels: bool = False
-    # committed fused-prefix length when fuse_levels is set: 0 fuses ALL
-    # levels (legacy whole-pyramid fusion), 0 < k < L commits the
-    # partial tier — one fused launch over levels [0..k) plus per-level
-    # launches for the tail
-    fuse_prefix: int = 0
     # committed sparsity decision: 'dense' runs the backend executor
     # unchanged; 'topk' swaps in the pruned top-k gather executor
     sparsity: str = "dense"
@@ -430,85 +387,22 @@ def _apply_sparsity_wrappers(exec_fn: Callable, spec: MsdaSpec,
     return exec_fn
 
 
-# backends whose builders understand the whole-pyramid fused kernels;
-# everyone else gets (truthful) per-level plans regardless of the policy
-_FUSABLE_BACKENDS = frozenset({"pallas"})
-
-
-def _fused_slab_itemsize(slab_dtypes: Tuple[str, ...]) -> int:
-    """Widest committed per-level slab itemsize — the per-query working
-    set of a fused launch is sized by its widest resident level."""
-    return max(jnp.dtype(d).itemsize for d in slab_dtypes)
-
-
-def _slab_itemsizes(slab_dtypes: Tuple[str, ...]) -> Tuple[int, ...]:
-    return tuple(jnp.dtype(d).itemsize for d in slab_dtypes)
-
-
-def _resolve_fuse_tier(spec: MsdaSpec, slab_dtypes: Tuple[str, ...],
-                       backend_name: str) -> Tuple[bool, int]:
-    """The planner's fusion rung (heuristic side): ``(fused, prefix)``.
-
-    ``prefix == 0`` means ALL levels (whole-pyramid fusion) when
-    ``fused``; ``0 < k < L`` commits the partial-fusion tier (one fused
-    launch over levels [0..k) plus a per-level tail).  ``'off'`` and
-    non-fusable backends resolve ``(False, 0)``; ``'on'`` pins
-    whole-pyramid fusion; ``'prefix:k'`` pins the tier (k >= L
-    degenerates to whole-pyramid).  ``'auto'`` plans the prefix from
-    the occupancy model (:func:`ops.fusion_prefix`) with the committed
-    per-level slab itemsizes: a full fit fuses everything, a strict
-    prefix of at least 2 levels commits the tier, anything shorter
-    stays per-level — a 1-level fused launch replaces exactly one
-    per-level launch, saving nothing.
-    """
+def check_fit(spec: MsdaSpec) -> None:
+    """Refuse, at plan time, a spec whose packed pyramid plus one
+    sublane step of queries does not fit its VMEM budget: the Pallas
+    kernels keep the whole pyramid resident, and there is no other
+    schedule to fall back to."""
     from repro.kernels import ops
 
-    if backend_name not in _FUSABLE_BACKENDS or spec.fuse_levels == "off":
-        return False, 0
-    L = spec.num_levels
-    pin = spec.fuse_prefix_pin()
-    if pin:
-        return (True, 0) if pin >= L else (True, pin)
-    if spec.fuse_levels == "on":
-        return True, 0
-    if L < 2:
-        return False, 0
-    k = ops.fusion_prefix(
+    need = ops.min_launch_bytes(
         spec.spatial_shapes, spec.num_points, spec.head_dim,
-        value_itemsize=_slab_itemsizes(slab_dtypes),
-        train=spec.train, vmem_budget=spec.vmem_budget,
+        value_itemsize=spec.slab_itemsize, train=spec.train,
         heads=spec.heads_per_launch)
-    if k == L:
-        return True, 0
-    if k >= 2:
-        return True, k
-    return False, 0
-
-
-def _tier_block_q(spec: MsdaSpec, slab_dtypes: Tuple[str, ...],
-                  prefix: int) -> Tuple[int, ...]:
-    """Heuristic block plan for a fusion tier: ONE shared block for the
-    fused prefix — planned against the prefix's packed residency and
-    replicated across the prefix levels so ``block_q`` keeps one entry
-    per level — plus per-level tail blocks at their own itemsizes.
-    ``prefix=0`` plans whole-pyramid fusion (no tail)."""
-    from repro.kernels import ops
-
-    k = prefix if prefix else spec.num_levels
-    items = _slab_itemsizes(slab_dtypes)
-    pre = ops.plan_blocks(
-        spec.spatial_shapes[:k], spec.num_points, spec.head_dim,
-        spec.num_queries, value_itemsize=items[:k], train=spec.train,
-        vmem_budget=spec.vmem_budget, adaptive=spec.adaptive_block,
-        heads=spec.heads_per_launch, fused=True)
-    bq = (pre[0],) * k
-    for hw, it in zip(spec.spatial_shapes[k:], items[k:]):
-        bq += (ops.plan_blocks(
-            (hw,), spec.num_points, spec.head_dim, spec.num_queries,
-            value_itemsize=it, train=spec.train,
-            vmem_budget=spec.vmem_budget, adaptive=spec.adaptive_block,
-            heads=spec.heads_per_launch)[0],)
-    return bq
+    if need > spec.vmem_budget:
+        raise ValueError(
+            f"the packed pyramid of {spec.spatial_shapes} and one query "
+            f"step need {need} bytes of VMEM, over the budget of "
+            f"{spec.vmem_budget} bytes: no launch fits")
 
 
 # --------------------------------------------------------------------------
@@ -536,15 +430,10 @@ def _build_pallas(spec: MsdaSpec, tuning: PlanTuning) -> Callable:
 
     params = ops.MSDAParams(
         spatial_shapes=spec.spatial_shapes,
-        block_q=tuple(tuning.block_q),
-        fuse_gather=spec.fuse_gather,
-        fuse_scatter=spec.fuse_scatter,
+        block_q=int(tuning.block_q[0]),
         save_sampled=spec.train,
         interpret=tuning.interpret,
-        onehot_levels=tuple(tuning.onehot_levels),
         slab_dtypes=tuple(tuning.slab_dtypes) or _default_slab_dtypes(spec),
-        fuse_levels=bool(tuning.fuse_levels),
-        fuse_prefix=int(tuning.fuse_prefix),
         vmem_limit=spec.vmem_budget,
     )
     return ops.build_kernel_op(params)
@@ -563,59 +452,26 @@ def _build_cpu(spec: MsdaSpec, tuning: PlanTuning) -> Callable:
 # --------------------------------------------------------------------------
 
 
-def _heuristic_block_q(spec: MsdaSpec, *, fused: bool = False,
-                       value_itemsize: Optional[int] = None) -> Tuple[int, ...]:
+def _heuristic_block_q(spec: MsdaSpec,
+                       slab_dtypes: Tuple[str, ...]) -> Tuple[int, ...]:
+    """The heuristic block of the launch with the committed per-level
+    slab dtypes, one entry per level."""
     from repro.kernels import ops
 
-    return ops.plan_blocks(
-        spec.spatial_shapes,
-        spec.num_points,
-        spec.head_dim,
+    bq = ops.plan_blocks(
+        spec.spatial_shapes, spec.num_points, spec.head_dim,
         spec.num_queries,
-        value_itemsize=(spec.slab_itemsize if value_itemsize is None
-                        else value_itemsize),
-        train=spec.train,
-        vmem_budget=spec.vmem_budget,
-        adaptive=spec.adaptive_block,
-        heads=spec.heads_per_launch,
-        fused=fused,
-    )
-
-
-def _blocks_for_slab_dtypes(spec: MsdaSpec, slab_dtypes: Tuple[str, ...]) -> Tuple[int, ...]:
-    """Heuristic block plan with PER-LEVEL slab itemsizes (a mixed
-    fp32/bf16 dtype commitment changes each level's VMEM residency)."""
-    from repro.kernels import ops
-
-    out = []
-    for hw, sdt in zip(spec.spatial_shapes, slab_dtypes):
-        out.append(ops.plan_blocks(
-            (hw,),
-            spec.num_points,
-            spec.head_dim,
-            spec.num_queries,
-            value_itemsize=jnp.dtype(sdt).itemsize,
-            train=spec.train,
-            vmem_budget=spec.vmem_budget,
-            adaptive=spec.adaptive_block,
-            heads=spec.heads_per_launch,
-        )[0])
-    return tuple(out)
-
-
-def _onehot_levels(spec: MsdaSpec) -> Tuple[bool, ...]:
-    from repro.kernels import ops
-
-    if not spec.onehot_small_levels:
-        return ()
-    return ops.plan_onehot(spec.spatial_shapes)
+        value_itemsize=tuple(jnp.dtype(d).itemsize for d in slab_dtypes),
+        train=spec.train, vmem_budget=spec.vmem_budget,
+        heads=spec.heads_per_launch)
+    return (bq,) * spec.num_levels
 
 
 # process-wide autotune activity counters.  "raced" counts specs whose
 # candidates were actually TIMED this process; a serving boot restored
 # from a plan store must keep it at zero (the CI smoke job asserts it).
 # "raced" counts every timing race; "raced_local" only the per-shard
-# block/dtype/onehot/fuse races, "raced_mesh" only the mesh-keyed
+# block/dtype/sparsity races, "raced_mesh" only the mesh-keyed
 # sharding / grad_reduce races — the elastic restore path asserts a
 # mesh-resized restart re-races EXACTLY the mesh-keyed axes
 # (raced_local == 0) against this split.
@@ -623,7 +479,7 @@ _AUTOTUNE_STATS = {
     "raced": _obs.counter("msda.autotune.raced",
                           help="autotune races actually timed"),
     "raced_local": _obs.counter("msda.autotune.raced_local",
-                                help="per-shard block/dtype/fuse races"),
+                                help="per-shard block/dtype races"),
     "raced_mesh": _obs.counter("msda.autotune.raced_mesh",
                                help="mesh-keyed sharding/grad_reduce races"),
     "cache_hits": _obs.counter("msda.winner_cache.hits",
@@ -751,54 +607,49 @@ _SLAB_DTYPE_CANDIDATES = ("float32", "bfloat16")
 
 
 # every field the winner-entry schema knows how to validate; anything
-# else a cache entry carries was written by a NEWER build and must ride
-# through this build's parse/rewrite cycle untouched (the "extras" dict)
-_WINNER_FIELDS = ("block_q", "slab_dtypes", "sharding", "onehot_levels",
-                  "fuse_levels", "fuse_prefix", "grad_reduce", "sparsity",
-                  "query_order")
+# else a cache entry carries (but :data:`RETIRED_KEYS`) was written by a
+# NEWER build and must ride through this build's parse/rewrite cycle
+# untouched (the "extras" dict)
+_WINNER_FIELDS = ("block_q", "slab_dtypes", "sharding", "grad_reduce",
+                  "sparsity", "query_order")
 
 
 def _parse_cache_entry(hit, spec: MsdaSpec) -> Optional[Dict[str, Any]]:
     """Decode a winner-cache entry into the normalised winner dict.
 
     Returns ``{"block_q": tuple, "slab_dtypes": tuple, "sharding":
-    None|'1d'|'2d'|'hybrid', "onehot_levels": None|tuple, "fuse_levels":
-    None|bool, "grad_reduce": None|'ring'|'psum', "sparsity":
-    None|'dense'|'topk', "query_order": None|'identity'|'morton',
-    "extras": dict}`` or ``None`` on a miss.  The
-    ``sharding``/``grad_reduce`` fields live on mesh-keyed entries (the
-    1D-vs-2D and ring-vs-psum races of distributed plans);
-    ``fuse_levels`` records the whole-pyramid fusion race;
-    ``onehot_levels`` the per-level MXU-routing race; ``sparsity`` /
-    ``query_order`` the pruned-vs-dense and Morton-vs-identity races;
-    ``fuse_prefix`` the partial-fusion tier a fused winner committed
-    (absent on whole-pyramid winners, so pre-tier entries mean "fuse
-    everything" exactly as they always did).
-    All are OPTIONAL, so every pre-existing entry still parses with
-    ``None`` there.  Keys this build does NOT know land in ``extras``
+    None|'1d'|'2d'|'hybrid', "grad_reduce": None|'ring'|'psum',
+    "sparsity": None|'dense'|'topk', "query_order":
+    None|'identity'|'morton', "extras": dict}`` or ``None`` on a miss.
+    The ``sharding``/``grad_reduce`` fields live on mesh-keyed entries
+    (the 1D-vs-2D and ring-vs-psum races of distributed plans);
+    ``sparsity`` / ``query_order`` record the pruned-vs-dense and
+    Morton-vs-identity races.  All are OPTIONAL, so every pre-existing
+    entry still parses with ``None`` there.  :data:`RETIRED_KEYS` are
+    dropped.  Other keys this build does NOT know land in ``extras``
     verbatim and :func:`_winner_entry` writes them back — a field
     persisted by a newer build survives an older build re-persisting
     the entry instead of being silently erased.  A flat ``[block_q...]``
     list is accepted for hand-authored caches (offline sweep tooling /
     the pre-dtype-policy format).  Anything malformed is treated as a
     miss, never an error: a corrupt cache file must degrade to
-    re-tuning.
+    re-tuning.  So is a ``block_q`` whose levels differ: it was raced
+    for per-level launches, and the one launch shares one block.
     """
     L = spec.num_levels
 
-    def _out(bq, dts, sharding=None, onehot=None, fused=None, gr=None,
-             sparsity=None, query_order=None, extras=None, fuse_prefix=None):
-        return {"block_q": bq, "slab_dtypes": dts, "sharding": sharding,
-                "onehot_levels": onehot, "fuse_levels": fused,
-                "fuse_prefix": fuse_prefix,
-                "grad_reduce": gr, "sparsity": sparsity,
-                "query_order": query_order, "extras": dict(extras or {})}
+    def _block_q(bq):
+        if not (isinstance(bq, list) and len(bq) == L and len(set(bq)) == 1):
+            return None
+        return tuple(int(b) for b in bq)
 
     try:
-        if isinstance(hit, list) and len(hit) == L:
-            return _out(tuple(int(b) for b in hit), _default_slab_dtypes(spec))
+        if isinstance(hit, list):
+            bq = _block_q(hit)
+            return None if bq is None else _winner(
+                bq, _default_slab_dtypes(spec))
         if isinstance(hit, dict):
-            bq = hit.get("block_q")
+            bq = _block_q(hit.get("block_q"))
             dts = hit.get("slab_dtypes")
             sharding = hit.get("sharding")
             if sharding is not None and sharding not in ("1d", "2d", "hybrid"):
@@ -812,30 +663,26 @@ def _parse_cache_entry(hit, spec: MsdaSpec) -> Optional[Dict[str, Any]]:
             qorder = hit.get("query_order")
             if qorder is not None and qorder not in ("identity", "morton"):
                 return None
-            if not (isinstance(bq, list) and len(bq) == L):
+            if bq is None:
                 return None
             if not (isinstance(dts, list) and len(dts) == L):
                 dts = _default_slab_dtypes(spec)
             dts = tuple(str(jnp.dtype(d)) for d in dts)
-            onehot = hit.get("onehot_levels")
-            if onehot is not None:
-                if not (isinstance(onehot, list) and len(onehot) == L):
-                    return None
-                onehot = tuple(bool(x) for x in onehot)
-            fused = hit.get("fuse_levels")
-            if fused is not None:
-                fused = bool(fused)
-            fp = hit.get("fuse_prefix")
-            if fp is not None:
-                fp = int(fp)
-                if fp < 0:
-                    return None
-            extras = {k: v for k, v in hit.items() if k not in _WINNER_FIELDS}
-            return _out(tuple(int(b) for b in bq), dts, sharding, onehot,
-                        fused, gr, sparsity, qorder, extras, fp)
+            extras = {k: v for k, v in hit.items()
+                      if k not in _WINNER_FIELDS and k not in RETIRED_KEYS}
+            return _winner(bq, dts, sharding, gr, sparsity, qorder, extras)
     except (TypeError, ValueError):  # hand-edited / corrupted entries
         return None
     return None
+
+
+def _winner(bq, dts, sharding=None, grad_reduce=None, sparsity=None,
+            query_order=None, extras=None) -> Dict[str, Any]:
+    """The normalised winner dict of :func:`_parse_cache_entry`."""
+    return {"block_q": tuple(bq), "slab_dtypes": tuple(dts),
+            "sharding": sharding, "grad_reduce": grad_reduce,
+            "sparsity": sparsity, "query_order": query_order,
+            "extras": dict(extras or {})}
 
 
 def mesh_token_from(axes, shape) -> str:
@@ -899,12 +746,6 @@ def _winner_entry(parsed: Dict[str, Any]) -> Dict[str, Any]:
            "slab_dtypes": list(parsed["slab_dtypes"])}
     if parsed.get("sharding") is not None:
         out["sharding"] = parsed["sharding"]
-    if parsed.get("onehot_levels") is not None:
-        out["onehot_levels"] = [bool(x) for x in parsed["onehot_levels"]]
-    if parsed.get("fuse_levels") is not None:
-        out["fuse_levels"] = bool(parsed["fuse_levels"])
-    if parsed.get("fuse_prefix"):  # only a committed STRICT tier is written
-        out["fuse_prefix"] = int(parsed["fuse_prefix"])
     if parsed.get("grad_reduce") is not None:
         out["grad_reduce"] = parsed["grad_reduce"]
     if parsed.get("sparsity") is not None:
@@ -960,32 +801,18 @@ def seed_autotune_winner(spec: MsdaSpec, backend: str, winner: Any,
 @_obs_trace.traced_span("autotune.race", level=3)
 def _autotune_plan(
     spec: MsdaSpec, backend_name: str, builder: Callable, interpret: bool
-) -> Tuple[Tuple[int, ...], Tuple[str, ...], Tuple[bool, ...], bool, int,
-           str, str, str]:
+) -> Tuple[Tuple[int, ...], Tuple[str, ...], str, str, str]:
     """Measure candidate plans; persist the winner per (device, spec).
 
-    Six raced axes:
+    Four raced axes:
 
-    * ``block_q`` — the heuristic plan scaled by {1/2, 1, 2} per level
-      (uniformly — the per-level cross product explodes), snapped to the
-      sublane multiple.  Skipped for blockless backends ("cpu").
+    * ``block_q`` — the heuristic block scaled by {1/2, 1, 2}, snapped
+      to the sublane multiple.  Skipped for blockless backends ("cpu").
     * slab dtype — under the ``slab_dtype="auto"`` policy, fp32 vs bf16
       is raced PER LEVEL (greedy marginal flips on the block winner): a
-      bf16 slab halves VMEM residency but pays cast/precision overhead,
-      and which side wins is level-size- and backend-dependent.
-    * MXU one-hot routing — under ``onehot_small_levels=True``, the
-      static ``ONEHOT_MAX_ROWS`` threshold is only the STARTING point:
-      each level's routing is raced with greedy flips, so a level moves
-      between the VPU gather and the MXU matmul on measurement, not on a
-      hand-picked row count.
-    * pyramid fusion tiers — under ``fuse_levels="auto"``, the
-      whole-pyramid fused plan (its own shared block, packed
-      super-slab) AND the occupancy model's partial tier (fused prefix
-      [0..k) + per-level tail, when the model proposes a strict one)
-      race the per-level incumbent three ways.  **Train specs time
-      forward + full VJP**: fusion changes the backward's launch count
-      and gout re-streaming, so a forward-only race would crown the
-      wrong side for training.
+      bf16 slab halves a train plan's saved corners but pays
+      cast/precision overhead, and which side wins is level-size- and
+      backend-dependent.
     * top-k point pruning — under ``sparsity="auto"``, the pruned
       executor (4k corner gathers per query instead of 4LP, LOSSY —
       see ``kernels/msda_sparse.py``) races the committed dense winner;
@@ -1000,42 +827,22 @@ def _autotune_plan(
     and a challenger must beat the incumbent by ``_AUTOTUNE_MARGIN`` —
     load jitter must never pick a winner.
 
-    Winners ``{"block_q", "slab_dtypes"}`` (+ optional ``onehot_levels``
-    / ``fuse_levels`` / ``fuse_prefix`` / ``sparsity`` /
+    Winners ``{"block_q", "slab_dtypes"}`` (+ optional ``sparsity`` /
     ``query_order``) are keyed by spec + device kind so a cache
     produced on one part never mis-tunes another.  Returns ``(block_q,
-    slab_dtypes, onehot_levels, fuse_levels, fuse_prefix, sparsity,
-    query_order, source)``.
+    slab_dtypes, sparsity, query_order, source)``.
     """
     from repro.kernels import msda_sparse
 
-    onehot = _onehot_levels(spec)
-    heur = _heuristic_block_q(spec)
     base_dts = _default_slab_dtypes(spec)
-    fusable = backend_name in _FUSABLE_BACKENDS
+    heur = _heuristic_block_q(spec, base_dts)
     key = autotune_winner_key(spec, backend_name)
     disk = _load_autotune_cache()
-    # an 'on' / 'prefix:k' pin fixes the tier; only 'auto' races it
-    pinned_tier = spec.fuse_levels == "on" or spec.fuse_prefix_pin() > 0
-    pin_fused, pin_prefix = (_resolve_fuse_tier(spec, base_dts, backend_name)
-                             if pinned_tier else (False, 0))
     parsed = _parse_cache_entry(disk.get(key), spec)
     if parsed is None:
         _WINNER_CACHE_MISSES.inc()
     if parsed is not None:
         _AUTOTUNE_STATS["cache_hits"].inc()
-        oh = parsed["onehot_levels"] if parsed["onehot_levels"] is not None else onehot
-        # entries without the field (hand-authored / pre-fusion schema)
-        # must not override an explicit 'on'/'prefix:k' pin
-        if parsed["fuse_levels"] is not None:
-            fused = bool(parsed["fuse_levels"])
-            # pre-tier fused entries carry no prefix: whole-pyramid,
-            # exactly what they committed when written
-            prefix = int(parsed["fuse_prefix"] or 0) if fused else 0
-            if prefix >= spec.num_levels:
-                prefix = 0
-        else:
-            fused, prefix = pin_fused, pin_prefix
         # field-less entries (older schema) resolve the sparsity rungs
         # the way a pin/heuristic would — never surprise-lossy
         sp = (parsed["sparsity"] if parsed["sparsity"] is not None
@@ -1044,76 +851,65 @@ def _autotune_plan(
               else _resolve_query_order(spec))
         if qo == "morton" and not msda_sparse.morton_eligible(spec):
             qo = "identity"  # entry from a differently-shaped past: ignore
-        return (parsed["block_q"], parsed["slab_dtypes"], oh, fused, prefix,
-                sp, qo, "autotune-cache")
+        return (parsed["block_q"], parsed["slab_dtypes"], sp, qo,
+                "autotune-cache")
 
     qcap = _round_up(spec.num_queries, _SUBLANE)
-    race_fuse = fusable and spec.fuse_levels == "auto" and spec.num_levels >= 2
     race_sparsity = spec.sparsity == "auto"
     race_qorder = (spec.query_order == "auto"
                    and msda_sparse.morton_eligible(spec))
     candidates = []
     if backend_name not in _BLOCKLESS_BACKENDS:
-        # pin_fused: the only plan family is the pinned tier, so the
-        # block race scales ITS geometry (shared prefix block + tail
-        # blocks) instead of the per-level ones
-        base_bq = (_tier_block_q(spec, base_dts, pin_prefix)
-                   if pin_fused else heur)
         for scale_num, scale_den in ((1, 2), (1, 1), (2, 1)):
             cand = tuple(
                 max(_SUBLANE, min(2048, qcap, (b * scale_num // scale_den) // _SUBLANE * _SUBLANE))
-                for b in base_bq
+                for b in heur
             )
             if cand not in candidates:
                 candidates.append(cand)
     else:
         candidates.append(heur)
     race_dtypes = spec.slab_dtype == "auto"
-    race_onehot = bool(onehot) and backend_name not in _BLOCKLESS_BACKENDS
-    if len(candidates) == 1 and not (race_dtypes or race_onehot or race_fuse
-                                     or race_sparsity or race_qorder):
-        return (candidates[0], base_dts, onehot, pin_fused, pin_prefix,
-                _resolve_sparsity(spec), _resolve_query_order(spec),
-                "autotune")
+    if len(candidates) == 1 and not (race_dtypes or race_sparsity
+                                     or race_qorder):
+        return (candidates[0], base_dts, _resolve_sparsity(spec),
+                _resolve_query_order(spec), "autotune")
 
     _AUTOTUNE_STATS["raced"].inc()
     _AUTOTUNE_STATS["raced_local"].inc()
     args = _autotune_inputs(spec)
     jit_cache: Dict[tuple, Callable] = {}
 
-    def get_fn(bq, dts, oh=None, fused=None, prefix=None, timed="fwd"):
+    def _warm(exec_fn, timed):
+        """Jit + warm an executor; ``timed``: 'fwd' times the forward,
+        'train' times forward + full VJP.  May raise."""
+        if timed == "train":
+            f = jax.jit(jax.grad(
+                lambda v, l, a, e=exec_fn: jnp.sum(e(v, l, a)),
+                argnums=(0, 1, 2)))
+        else:
+            f = jax.jit(exec_fn)
+        jax.block_until_ready(f(*args))
+        return f
+
+    def get_fn(bq, dts, timed="fwd"):
         """Jitted + warmed executor for one candidate, cached so incumbent
-        re-appearances across race rounds never recompile.  ``timed``:
-        'fwd' times the forward, 'train' times forward + full VJP."""
-        oh = onehot if oh is None else oh
-        fused = pin_fused if fused is None else fused
-        prefix = pin_prefix if prefix is None else prefix
-        ck = (bq, dts, oh, fused, prefix, timed)
+        re-appearances across race rounds never recompile."""
+        ck = (bq, dts, timed)
         if ck not in jit_cache:
-            tuning = PlanTuning(block_q=bq, onehot_levels=oh,
-                                interpret=interpret, source="autotune",
-                                slab_dtypes=dts, fuse_levels=fused,
-                                fuse_prefix=prefix)
-            exec_fn = builder(spec, tuning)
-            if timed == "train":
-                f = jax.jit(jax.grad(
-                    lambda v, l, a, e=exec_fn: jnp.sum(e(v, l, a)),
-                    argnums=(0, 1, 2)))
-            else:
-                f = jax.jit(exec_fn)
-            jax.block_until_ready(f(*args))  # compile + warm (may raise)
-            jit_cache[ck] = f
+            jit_cache[ck] = _warm(builder(spec, PlanTuning(
+                block_q=bq, interpret=interpret, source="autotune",
+                slab_dtypes=dts)), timed)
         return jit_cache[ck]
 
-    def race(variants: Dict[Any, tuple], timed="fwd"):
-        """Interleave-time variants {key: (bq, dts[, oh[, fused[,
-        prefix]]])}; a candidate that fails to build drops out, and if
-        every one fails the race raises with the compiler's message —
-        never a silently untimed plan."""
+    def race(variants: Dict[Any, tuple]):
+        """Interleave-time variants {key: (bq, dts)}; a candidate that
+        fails to build drops out, and if every one fails the race raises
+        with the compiler's message — never a silently untimed plan."""
         fns, errors = {}, []
         for k, v in variants.items():
             try:
-                fns[k] = get_fn(*v, timed=timed)
+                fns[k] = get_fn(*v)
             except Exception as e:  # candidate doesn't fit/compile: skip
                 errors.append(f"{k}: {type(e).__name__}: {e}")
         if not fns:
@@ -1130,102 +926,28 @@ def _autotune_plan(
         # greedy per-level flips against the committed block winner; each
         # round re-times incumbent vs challenger INTERLEAVED and the flip
         # must clear the noise margin, so a level goes bf16 only when its
-        # marginal saving genuinely beats its cast cost end-to-end
+        # marginal saving genuinely beats its cast cost end-to-end.  The
+        # packed slab rounds each level to its own committed dtype (see
+        # ops._pack_pyramid)
         wide, narrow = (str(jnp.dtype(d)) for d in _SLAB_DTYPE_CANDIDATES)
         current = (wide,) * spec.num_levels
-        # per-level flips even under a fused pin: the packed super-slab
-        # rounds each level to its own committed dtype (see
-        # ops._pack_pyramid)
-        for ls in [(l,) for l in range(spec.num_levels)]:
-            trial = tuple(narrow if l in ls else d
-                          for l, d in enumerate(current))
+        for l in range(spec.num_levels):
+            trial = tuple(narrow if i == l else d
+                          for i, d in enumerate(current))
             k, times = race({"cur": (best, current), "trial": (best, trial)})
             if (k == "trial"
                     and times["trial"] < times["cur"] * (1 - _AUTOTUNE_MARGIN)):
                 current = trial
         best_dts = current
         if best_dts != base_dts and backend_name not in _BLOCKLESS_BACKENDS:
-            # flipped levels halved their residency: re-plan blocks with
-            # the committed itemsizes (the 'bf16 frees VMEM -> wider
-            # vec-len' payoff — per-level itemsizes, or the pinned
-            # tier's packed residency) and keep the clear winner
-            rebq = (_tier_block_q(spec, best_dts, pin_prefix)
-                    if pin_fused else _blocks_for_slab_dtypes(spec, best_dts))
+            # flipped levels narrowed the saved corners: re-plan the block
+            # with the committed itemsizes and keep the clear winner
+            rebq = _heuristic_block_q(spec, best_dts)
             if rebq != best:
                 k, times = race({"cur": (best, best_dts), "re": (rebq, best_dts)})
                 if (k == "re"
                         and times["re"] < times["cur"] * (1 - _AUTOTUNE_MARGIN)):
                     best = rebq
-
-    best_onehot = onehot
-    if race_onehot:
-        # greedy per-level routing flips from the static-threshold start:
-        # the ONEHOT_MAX_ROWS heuristic proposes, the race disposes.
-        # Train specs time fwd+VJP — the routing also picks the
-        # backward's scatter path (onehot_scatter), where it matters most
-        timed = "train" if spec.train else "fwd"
-        current = onehot
-        for l in range(spec.num_levels):
-            trial = current[:l] + (not current[l],) + current[l + 1:]
-            k, times = race({"cur": (best, best_dts, current),
-                             "trial": (best, best_dts, trial)}, timed=timed)
-            if (k == "trial"
-                    and times["trial"] < times["cur"] * (1 - _AUTOTUNE_MARGIN)):
-                current = trial
-        best_onehot = current
-
-    best_fused, best_prefix = pin_fused, pin_prefix
-    if race_fuse:
-        # fusion-tier race, three ways: the per-level incumbent, the
-        # whole-pyramid fused challenger, and — when the occupancy
-        # model proposes a strict prefix — the partial tier at the
-        # model's k.  Every challenger runs at its OWN geometry (shared
-        # prefix block planned against the packed residency, per-level
-        # tail blocks) with the COMMITTED per-level slab dtypes (the
-        # packed super-slab rounds each level to its own).  Timed
-        # fwd+VJP for train specs — the backward is where fusion
-        # changes launch count and gout streaming the most.
-        from repro.kernels import ops
-
-        k_model = ops.fusion_prefix(
-            spec.spatial_shapes, spec.num_points, spec.head_dim,
-            value_itemsize=_slab_itemsizes(best_dts),
-            train=spec.train, vmem_budget=spec.vmem_budget,
-            heads=spec.heads_per_launch)
-        timed = "train" if spec.train else "fwd"
-        full_bq = _tier_block_q(spec, best_dts, 0)
-        tier_bqs = {"fused": (full_bq, 0)}
-        variants = {"per-level": (best, best_dts, best_onehot, False, 0),
-                    "fused": (full_bq, best_dts, best_onehot, True, 0)}
-        if 2 <= k_model < spec.num_levels:
-            pre_bq = _tier_block_q(spec, best_dts, k_model)
-            tier_bqs["prefix"] = (pre_bq, k_model)
-            variants["prefix"] = (pre_bq, best_dts, best_onehot, True, k_model)
-        k, times = race(variants, timed=timed)
-        if k is not None:
-            challengers = {n: t for n, t in times.items() if n != "per-level"}
-            if challengers:
-                champ = min(challengers, key=challengers.get)
-                inc = times.get("per-level")
-                # per-level stays incumbent: a fused tier wins only by
-                # clearing the noise margin (or when per-level itself
-                # failed to build)
-                if (inc is None
-                        or challengers[champ] < inc * (1 - _AUTOTUNE_MARGIN)):
-                    best, best_prefix = tier_bqs[champ]
-                    best_fused = True
-
-    def _warm(exec_fn, timed):
-        """Jit + warm an executor built OUTSIDE the (bq, dts, ...) tuning
-        space (the pruned / permuted challengers); may raise."""
-        if timed == "train":
-            f = jax.jit(jax.grad(
-                lambda v, l, a, e=exec_fn: jnp.sum(e(v, l, a)),
-                argnums=(0, 1, 2)))
-        else:
-            f = jax.jit(exec_fn)
-        jax.block_until_ready(f(*args))
-        return f
 
     best_sparsity = _resolve_sparsity(spec)
     if race_sparsity:
@@ -1236,8 +958,7 @@ def _autotune_plan(
         timed = "train" if spec.train else "fwd"
         try:
             fns = {
-                "dense": get_fn(best, best_dts, best_onehot, best_fused,
-                                best_prefix, timed=timed),
+                "dense": get_fn(best, best_dts, timed=timed),
                 "topk": _warm(msda_sparse.build_topk_exec(spec), timed),
             }
             times = _time_executors(fns, args)
@@ -1259,10 +980,8 @@ def _autotune_plan(
                 base_exec = msda_sparse.build_topk_exec(spec)
             else:
                 base_exec = builder(spec, PlanTuning(
-                    block_q=best, onehot_levels=best_onehot,
-                    interpret=interpret, source="autotune",
-                    slab_dtypes=best_dts, fuse_levels=best_fused,
-                    fuse_prefix=best_prefix))
+                    block_q=best, interpret=interpret, source="autotune",
+                    slab_dtypes=best_dts))
             wrapped = msda_sparse.wrap_query_permutation(
                 base_exec, spec.spatial_shapes)
             fns = {"identity": _warm(base_exec, timed),
@@ -1275,23 +994,12 @@ def _autotune_plan(
         except Exception:
             best_qorder = "identity"
 
-    parsed = {"block_q": best, "slab_dtypes": best_dts,
-              "sharding": None, "grad_reduce": None,
-              "onehot_levels": best_onehot if race_onehot else None,
-              "fuse_levels": best_fused if fusable else None,
-              # only a committed STRICT tier persists the field — full-
-              # fusion / per-level winners stay byte-identical to the
-              # pre-tier entry schema
-              "fuse_prefix": (best_prefix
-                              if fusable and best_fused and best_prefix
-                              else None),
-              "sparsity": best_sparsity if race_sparsity else None,
-              "query_order": best_qorder if race_qorder else None,
-              "extras": {}}
-    disk[key] = _winner_entry(parsed)
+    disk[key] = _winner_entry(_winner(
+        best, best_dts,
+        sparsity=best_sparsity if race_sparsity else None,
+        query_order=best_qorder if race_qorder else None))
     _store_autotune_cache(disk)
-    return (best, best_dts, best_onehot, best_fused, best_prefix,
-            best_sparsity, best_qorder, "autotune")
+    return best, best_dts, best_sparsity, best_qorder, "autotune"
 
 
 @_obs_trace.traced_span("autotune.race_sharding", level=3)
@@ -1399,17 +1107,9 @@ def _autotune_sharding(spec: MsdaSpec, backend_name: str, mesh,
         winner = min(times, key=times.get)
     t = built[winner][1]
     disk = _load_autotune_cache()
-    disk[key] = _winner_entry({
-        "block_q": t.block_q,
-        "slab_dtypes": t.slab_dtypes or _default_slab_dtypes(spec),
-        "sharding": winner,
-        "onehot_levels": None,
-        "fuse_levels": (t.fuse_levels
-                        if backend_name in _FUSABLE_BACKENDS else None),
-        "fuse_prefix": (t.fuse_prefix
-                        if (backend_name in _FUSABLE_BACKENDS
-                            and t.fuse_levels and t.fuse_prefix) else None),
-        "grad_reduce": None})
+    disk[key] = _winner_entry(_winner(
+        t.block_q, t.slab_dtypes or _default_slab_dtypes(spec),
+        sharding=winner))
     _store_autotune_cache(disk)
     return winner, built[winner]
 
@@ -1483,12 +1183,8 @@ def _autotune_grad_reduce(spec: MsdaSpec, backend_name: str, mesh,
     disk = _load_autotune_cache()
     prev = _parse_cache_entry(disk.get(key), spec)
     if prev is None:  # no sharding race ran (mode was pinned): start fresh
-        prev = {"block_q": tuning.block_q,
-                "slab_dtypes": tuning.slab_dtypes or _default_slab_dtypes(local_spec),
-                "sharding": None, "onehot_levels": None,
-                "fuse_levels": None, "fuse_prefix": None,
-                "grad_reduce": None,
-                "sparsity": None, "query_order": None, "extras": {}}
+        prev = _winner(tuning.block_q, tuning.slab_dtypes
+                       or _default_slab_dtypes(local_spec))
     prev["grad_reduce"] = choice
     disk[key] = _winner_entry(prev)
     _store_autotune_cache(disk)
@@ -1841,49 +1537,24 @@ class MsdaPlan:
     def launches_per_call(self) -> Dict[str, int]:
         """Static Pallas launch schedule for one plan call, by direction.
 
-        Whole-pyramid fused plans launch once per direction over the
-        packed super-slab; a partial-fusion tier with a fused prefix of
-        ``k`` levels launches ``L - k + 1`` times (one fused prefix
-        launch + the per-level tail); per-level plans launch once per
-        level.  The ref/cpu backends and the top-k pruned executor run
+        A Pallas plan launches once per direction over the packed
+        pyramid.  The ref/cpu backends and the top-k pruned executor run
         as plain XLA — zero Pallas launches.  ``bwd`` counts the
         custom-VJP backward a ``train`` plan carries (0 for inference
         plans).
         """
         if self.backend != "pallas" or self.tuning.sparsity == "topk":
             return {"fwd": 0, "bwd": 0}
-        L = self.local_spec.num_levels
-        k = self.fuse_prefix
-        per_dir = L if k == 0 else L - k + 1
-        return {"fwd": per_dir, "bwd": per_dir if self.spec.train else 0}
+        return {"fwd": 1, "bwd": 1 if self.spec.train else 0}
 
     # -- inspectability ---------------------------------------------------
-    @property
-    def fused(self) -> bool:
-        """True when this plan runs fused pyramid kernels (whole-pyramid
-        or a partial-fusion tier)."""
-        return bool(self.tuning.fuse_levels)
-
-    @property
-    def fuse_prefix(self) -> int:
-        """Effective committed fused-prefix length: 0 for per-level
-        plans, L for whole-pyramid fusion, else the strict tier
-        ``0 < k < L``."""
-        if not self.fused:
-            return 0
-        L = self.local_spec.num_levels
-        k = int(self.tuning.fuse_prefix)
-        return L if (k == 0 or k >= L) else k
-
     def level_report(self) -> List[Dict[str, Any]]:
         """Per-level planning facts (the numbers ``describe`` prints).
 
         Reported against ``local_spec`` — the per-shard geometry the
-        tuning was actually computed for.  ``vmem_frac`` is PER TIER:
-        levels inside the fused prefix report the packed prefix's
-        occupancy (every prefix slab resident at once, identical on
-        those rows); tail levels (and fully per-level plans) report
-        their own slab's.
+        tuning was actually computed for.  ``vmem_frac`` is the launch's:
+        the packed pyramid resident at once plus the block's step, the
+        same on every level's row.
         """
         from repro.kernels import ops
 
@@ -1892,20 +1563,26 @@ class MsdaPlan:
         resolved = tuple(
             dts[l] if l < len(dts) and dts[l] else s.resolved_slab_dtype()
             for l in range(s.num_levels))
-        items = _slab_itemsizes(resolved)
-        k = self.fuse_prefix  # 0 per-level, L whole-pyramid, else the tier
         heads = s.heads_per_launch
-        prefix_resident = 0
-        if k:
-            prefix_resident = ops.fused_resident_bytes(
-                s.spatial_shapes[:k], s.head_dim, heads=heads)
+        resident = ops.pyramid_resident_bytes(s.spatial_shapes, s.head_dim,
+                                              heads=heads)
+        # the step's saved corners are sized by the widest resident level
+        per_q = ops.per_query_bytes(
+            s.num_points, s.head_dim, train=s.train,
+            slab_itemsize=max(jnp.dtype(d).itemsize for d in resolved),
+            levels=s.num_levels, heads=heads)
         # what the occupancy model would have picked on its own, so the
-        # report carries predicted-vs-committed occupancy per level (a
-        # raced/overridden block plan can land far from the model)
-        if k:
-            heur_bq = _tier_block_q(s, resolved, self.tuning.fuse_prefix)
+        # report carries predicted-vs-committed occupancy (a raced or
+        # overridden block can land far from the model)
+        pred_bq = _heuristic_block_q(s, resolved)[0]
+        predicted = (resident + pred_bq * per_q) / max(s.vmem_budget, 1)
+        if self.tuning.sparsity == "topk":
+            # the pruned executor replaces the backend's gather path
+            # wholesale (XLA top-k gather) — report what runs
+            gather = "xla-topk"
         else:
-            heur_bq = _blocks_for_slab_dtypes(s, resolved)
+            gather = {"ref": "xla", "cpu": "cpu-fused"}.get(self.backend,
+                                                            "vpu")
         rows = []
         for l, hw in enumerate(s.spatial_shapes):
             slab = ops.slab_rows(hw)
@@ -1914,41 +1591,13 @@ class MsdaPlan:
                 # the oracle ignores the slab policy: pure fp32 compute,
                 # no resident slabs — report what actually executes
                 sdt = "float32"
-            in_prefix = l < k
             # the level's slab per head in its committed dtype (+ the
             # fp32 grad slab of a train plan): its HBM footprint
             slab_bytes = slab * s.head_dim * jnp.dtype(sdt).itemsize
             if s.train:
                 slab_bytes += slab * s.head_dim * s.accum_itemsize
             bq = self.tuning.block_q[l] if l < len(self.tuning.block_q) else 0
-            # the fused prefix's per-step working set is sized by its
-            # widest resident level, not by this level's own (possibly
-            # narrower) commitment
-            step_item = (_fused_slab_itemsize(resolved[:k]) if in_prefix
-                         else jnp.dtype(sdt).itemsize)
-            per_q = ops.per_query_bytes(
-                s.num_points, s.head_dim, train=s.train,
-                slab_itemsize=step_item,
-                levels=k if in_prefix else 1, heads=heads)
-            resident = (prefix_resident if in_prefix
-                        else ops.resident_bytes(slab, s.head_dim,
-                                                heads=heads))
             occupancy = (resident + bq * per_q) / max(s.vmem_budget, 1)
-            pred_bq = heur_bq[l] if l < len(heur_bq) else bq
-            predicted = (resident + pred_bq * per_q) / max(s.vmem_budget, 1)
-            onehot = bool(self.tuning.onehot_levels[l]) if self.tuning.onehot_levels else False
-            if self.tuning.sparsity == "topk":
-                # the pruned executor replaces the backend's gather path
-                # wholesale (XLA top-k gather) — report what runs
-                gather = "xla-topk"
-            elif self.backend == "ref":
-                gather = "xla"
-            elif self.backend == "cpu":
-                gather = "cpu-fused"
-            elif onehot:
-                gather = "mxu-onehot"
-            else:
-                gather = "vpu-fused" if s.fuse_gather else "vpu-4x"
             rows.append({
                 "level": l,
                 "hw": hw,
@@ -1961,36 +1610,28 @@ class MsdaPlan:
                 "vmem_frac": occupancy,
                 "block_q_predicted": pred_bq,
                 "vmem_frac_predicted": predicted,
-                "fused": in_prefix,
             })
             _VMEM_GAUGE.set(occupancy, level=l, kind="committed")
             _VMEM_GAUGE.set(predicted, level=l, kind="predicted")
         return rows
 
     def weight_report(self) -> List[Dict[str, Any]]:
-        """Per forward launch, its VMEM weight block: the levels it
-        covers, its ``block_q``, the weight rows a query takes
+        """The forward launch's VMEM weight block: the levels it covers,
+        its ``block_q``, the weight rows a query takes
         (``msda_fwd.weight_layout``) and the double-buffered block's
-        bytes.  Every Pallas gather reads its weights so; no launch
-        (``[]``) where no Pallas gather runs."""
+        bytes.  No launch (``[]``) where no Pallas gather runs."""
         if self.backend != "pallas" or self.tuning.sparsity == "topk":
             return []
         from repro.kernels import msda_fwd, ops
 
         s = self.local_spec
-        k = self.fuse_prefix
-        launches = ([tuple(range(k))] if k else []) + [
-            (l,) for l in range(k, s.num_levels)]
+        bq = self.tuning.block_q[0]
+        rows = msda_fwd.weight_layout(s.num_levels, s.num_points,
+                                      s.head_dim)[0]
         lanes = ops.lane_width(s.heads_per_launch, s.head_dim)
-        out = []
-        for levels in launches:
-            bq = self.tuning.block_q[levels[0]]
-            rows = msda_fwd.weight_layout(len(levels), s.num_points,
-                                          s.head_dim)[0]
-            out.append({"levels": levels, "block_q": bq,
-                        "rows_per_query": rows,
-                        "vmem_bytes": 2 * bq * rows * lanes * 4})
-        return out
+        return [{"levels": tuple(range(s.num_levels)), "block_q": bq,
+                 "rows_per_query": rows,
+                 "vmem_bytes": 2 * bq * rows * lanes * 4}]
 
     def sharding_report(self) -> Dict[str, Any]:
         """Structured record of the committed distribution.
@@ -2036,16 +1677,14 @@ class MsdaPlan:
     def describe(self) -> str:
         """Human-readable plan report.
 
-        The header states the resolved sharding MODE and the committed
-        fusion tier (``fuse=per-level`` / ``fuse=pyramid`` /
-        ``fuse=pyramid[0:k)+per-level`` for a partial tier, whose
-        ``fused prefix`` line carries the launch count and the prefix
-        super-slab extent); mesh-carrying plans add a ``mesh:`` line
+        The header states the resolved sharding MODE and the launches
+        per call; a Pallas plan adds its packed pyramid's rows and its
+        launch's weight block; mesh-carrying plans add a ``mesh:`` line
         with the topology, which mesh axes
         shard which operand dims, the per-shard geometry, and the
         committed grad_value reduction (``ring`` / ``psum`` / ``local``)
         — so the report is the full distribution contract, not just the
-        mode name.  Then one line per level with the committed
+        mode name.  Then one line per level with the launch's
         ``block_q``, slab bytes / VMEM occupancy, the gather path, and —
         the mixed-precision axis — the **chosen slab dtype variant** per
         level (``slab_dt`` column: fp32, or bf16 when the policy /
@@ -2073,24 +1712,6 @@ class MsdaPlan:
         if self.local_spec is not self.spec:
             shard_note += (f"  per-shard: Q={self.local_spec.num_queries} "
                            f"H={self.local_spec.num_heads} (levels below are per shard)\n")
-        fuse_note = ""
-        if self.fused:
-            from repro.kernels import ops
-
-            ls = self.local_spec
-            k = self.fuse_prefix
-            if k == ls.num_levels:
-                _, total = ops.pyramid_row_offsets(ls.spatial_shapes)
-                fuse_note = (
-                    f"  fused pyramid: 1 launch/direction  "
-                    f"super_slab_rows={total}  shared block_q={self.block_q[0]}\n")
-            else:
-                _, total = ops.pyramid_row_offsets(ls.spatial_shapes[:k])
-                fuse_note = (
-                    f"  fused prefix [0:{k}): {ls.num_levels - k + 1} "
-                    f"launches/direction  super_slab_rows={total}  "
-                    f"shared block_q={self.block_q[0]}  "
-                    f"tail levels {k}..{ls.num_levels - 1} per-level\n")
         sparse_note = ""
         if self.tuning.sparsity == "topk":
             ls = self.local_spec
@@ -2107,28 +1728,26 @@ class MsdaPlan:
                        + ("" if self.backend == "pallas"
                           else f"  (no pallas kernels on '{self.backend}')")
                        + "\n")
-        if not self.fused:
-            fuse_hdr = "per-level"
-        elif self.fuse_prefix == self.local_spec.num_levels:
-            fuse_hdr = "pyramid"
-        else:
-            fuse_hdr = f"pyramid[0:{self.fuse_prefix})+per-level"
         head = (
             f"MsdaPlan(backend={self.backend}, tune={self.tuning.source}, "
             f"sharding={self.sharding_mode}, "
-            f"fuse={fuse_hdr}, "
             f"train={s.train}, dtype={s.dtype}, "
             f"accum={s.accum_dtype})\n"
             f"  Q={s.num_queries} H={s.num_heads} D={s.head_dim} P={s.num_points} "
             f"levels={s.num_levels} S={s.total_pixels}\n"
-            + shard_note + fuse_note + sparse_note + launch_note +
+            + shard_note + sparse_note + launch_note +
             f"  vmem_budget={s.vmem_budget / 2**20:.1f} MiB  "
             f"interpret={self.tuning.interpret}\n"
         )
+        from repro.kernels import ops
+
         for w in self.weight_report():
             lv = w["levels"]
+            _, total = ops.pyramid_row_offsets(self.local_spec.spatial_shapes)
             span = f"{lv[0]}-{lv[-1]}" if len(lv) > 1 else f"{lv[0]}"
-            head += (f"  weights lvl {span}: {w['rows_per_query']} rows/query"
+            head += (f"  packed pyramid: super_slab_rows={total}  "
+                     f"block_q={w['block_q']}\n"
+                     f"  weights lvl {span}: {w['rows_per_query']} rows/query"
                      f" x block_q {w['block_q']} = {w['vmem_bytes']} B VMEM"
                      f" (2 buffers)\n")
         lines = [head,
@@ -2146,17 +1765,13 @@ class MsdaPlan:
     # -- degradation ladder -----------------------------------------------
     def rung_label(self) -> str:
         """Short human token for this plan's ladder rung, e.g.
-        ``"pallas/fused+topk"`` / ``"pallas/per-level"`` / ``"ref"``."""
-        if self.backend == "ref":
-            return "ref"
+        ``"pallas/topk+morton"`` / ``"pallas"`` / ``"ref"``."""
         traits = []
-        if self.fused:
-            traits.append("fused")
         if self.tuning.sparsity == "topk":
             traits.append("topk")
         if self.tuning.query_order == "morton":
             traits.append("morton")
-        return f"{self.backend}/{'+'.join(traits) if traits else 'per-level'}"
+        return "/".join([self.backend] + (["+".join(traits)] if traits else []))
 
     def fallback(self, *, mesh=None) -> Optional["MsdaPlan"]:
         """One rung down the degradation ladder (None at the bottom).
@@ -2165,17 +1780,16 @@ class MsdaPlan:
         decision at a time::
 
             sparse / reordered (topk, morton)  ->  dense identity, same backend
-            fused (whole-pyramid or prefix)    ->  per-level, same backend
-            per-level dense, non-ref backend   ->  the "ref" oracle
+            dense, non-ref backend             ->  the "ref" oracle
             ref                                ->  None (nothing below the oracle)
 
         A plan whose Pallas kernels are compiled (``interpret=False``:
         a TPU) has no oracle rung — falling back to XLA there would hide
-        a failing device kernel; its per-level rung is the bottom.
+        a failing device kernel; its dense rung is the bottom.
 
         Built RACE-FREE from the existing spec: the demoted plan pins
         the axes it drops (``sparsity="off"``, ``query_order=
-        "identity"``, ``fuse_levels="off"``) and is constructed with
+        "identity"``) and is constructed with
         ``tune="heuristic"`` — no autotune timing run executes and no
         winner is ever persisted, so a circuit-breaker demotion cannot
         poison the winner cache with panic-built plans (conformance:
@@ -2190,26 +1804,20 @@ class MsdaPlan:
                 "mesh= to build its fallback rung")
         s = self.spec
         if self.tuning.sparsity == "topk" or self.tuning.query_order == "morton":
-            ns = dataclasses.replace(s, sparsity="off", query_order="identity")
-            backend = self.backend
-        elif self.fused:
-            ns = dataclasses.replace(s, sparsity="off", query_order="identity",
-                                     fuse_levels="off")
             backend = self.backend
         elif self.backend != "ref" and not (self.backend == "pallas"
                                             and not self.tuning.interpret):
-            ns = dataclasses.replace(s, sparsity="off", query_order="identity",
-                                     fuse_levels="off")
             backend = "ref"
         else:
             return None
+        ns = dataclasses.replace(s, sparsity="off", query_order="identity")
         return msda_plan(ns, backend=backend, tune="heuristic", mesh=mesh,
                          query_parallel=self.query_parallel,
                          interpret=self.tuning.interpret)
 
     def fallback_chain(self, *, mesh=None) -> Tuple["MsdaPlan", ...]:
         """Every rung below this plan, top to bottom (ends at the ref
-        oracle, or at per-level Pallas for compiled kernels; empty for a
+        oracle, or at dense Pallas for compiled kernels; empty for a
         plan already on the bottom rung)."""
         chain: List[MsdaPlan] = []
         p = self.fallback(mesh=mesh)
@@ -2312,7 +1920,8 @@ def msda_plan(
     ``tune``: ``"heuristic"`` uses the paper's VMEM-occupancy model
     (Fig. 7); ``"autotune"`` times candidate block plans on synthetic
     operands and persists winners per (device kind, spec) on disk.
-    ``block_q`` overrides both (ablation hook).  ``mesh`` bakes the
+    ``block_q`` overrides both: one entry per level, all equal (the one
+    launch over the packed pyramid takes one block).  ``mesh`` bakes the
     shard_map wiring into the returned plan; ``sharding`` picks the
     distribution family — ``"auto"`` walks the ladder (and, under
     ``tune="autotune"``, RACES 1D vs 2D vs hybrid and persists the
@@ -2352,39 +1961,27 @@ def msda_plan(
     builder = registry.get_backend(backend_name)
 
     def _build_local_impl(s: MsdaSpec) -> Tuple[Callable, PlanTuning]:
+        if backend_name == "pallas":
+            check_fit(s)
         dts = _default_slab_dtypes(s)
-        onehot = _onehot_levels(s)
         sparsity, qorder = _resolve_sparsity(s), _resolve_query_order(s)
         if block_q is not None:
             if len(block_q) != s.num_levels:
                 raise ValueError(
                     f"block_q has {len(block_q)} entries for {s.num_levels} levels")
+            if len(set(block_q)) != 1:
+                raise ValueError(
+                    f"block_q {tuple(block_q)} differs by level: the one "
+                    "launch over the packed pyramid takes one block")
             bq, source = tuple(int(b) for b in block_q), "override"
-            # a NON-uniform override pins per-level blocks the fused
-            # kernel (one shared block) cannot honour — never silently
-            # reinterpret it; only a uniform override may still fuse
-            if len(set(bq)) == 1:
-                fused, prefix = _resolve_fuse_tier(s, dts, backend_name)
-            else:
-                fused, prefix = False, 0
         elif tune == "autotune" and backend_name != "ref":
-            (bq, dts, onehot, fused, prefix, sparsity, qorder,
-             source) = _autotune_plan(s, backend_name, builder, interpret)
+            bq, dts, sparsity, qorder, source = _autotune_plan(
+                s, backend_name, builder, interpret)
         else:
-            fused, prefix = _resolve_fuse_tier(s, dts, backend_name)
-            bq = (_tier_block_q(s, dts, prefix) if fused
-                  else _heuristic_block_q(s))
-            source = "heuristic"
-        if sparsity == "topk":
-            # the pruned executor is one XLA computation — it neither
-            # fuses pyramid launches nor routes through the MXU; the
-            # committed tuning must describe what actually runs
-            fused, prefix = False, 0
-        tuning = PlanTuning(block_q=bq, onehot_levels=onehot,
-                            interpret=interpret, source=source,
-                            slab_dtypes=dts, fuse_levels=fused,
-                            fuse_prefix=prefix,
-                            sparsity=sparsity, query_order=qorder)
+            bq, source = _heuristic_block_q(s, dts), "heuristic"
+        tuning = PlanTuning(block_q=bq, interpret=interpret, source=source,
+                            slab_dtypes=dts, sparsity=sparsity,
+                            query_order=qorder)
         # a pruned plan swaps in the top-k executor (the backend's dense
         # executor is the fallback every other decision still describes);
         # dense+identity is byte-identical to the pre-sparsity build

@@ -14,9 +14,9 @@ Builder protocol::
 Built-in backends (registered on first use, from ``repro.kernels.plan``):
 
 * ``"ref"``    — pure-jnp oracle (the conformance suite's ground truth).
-* ``"pallas"`` — the xMSDA Pallas kernels (fwd + custom-VJP bwd); tuning
-  decides per-level ``block_q``, slab dtypes and the MXU one-hot gather
-  routing.
+* ``"pallas"`` — the xMSDA Pallas kernels (fwd + custom-VJP bwd), one
+  launch per direction over the packed pyramid; tuning decides the
+  launch's ``block_q`` and the per-level slab dtypes.
 * ``"cpu"``    — CPU-vectorised fused-gather path (vmapped batched
   gather, no Pallas; see ``repro.kernels.msda_cpu``).
 

@@ -1,10 +1,10 @@
 """The one writer behind every ``BENCH_*.json`` trajectory file.
 
-Common schema (``"schema": 1``) shared by ``BENCH_kernels.json``,
-``BENCH_sparsity.json`` and ``BENCH_train.json``:
+Common schema (``"schema": 1``) shared by ``BENCH_sparsity.json``,
+``BENCH_train.json`` and ``BENCH_resilience.json``:
 
     {
-      "bench":        str,      # benchmark id ("fused_vs_per_level", ...)
+      "bench":        str,      # benchmark id ("sparsity_ablation", ...)
       "schema":       1,
       "config":       {...},    # geometry / run config the numbers depend on
       "note":         str,

@@ -579,8 +579,8 @@ class ServeEngine:
                      injector: Optional[FaultInjector] = None
                      ) -> GuardedExecutor:
         """The per-plan circuit breaker for warmed plan ``i`` —
-        retries, then demotes down ``MsdaPlan.fallback()`` (fused ->
-        per-level -> ref; sparse -> dense) and probes the primary on
+        retries, then demotes down ``MsdaPlan.fallback()`` (sparse ->
+        dense -> ref) and probes the primary on
         the half-open schedule.  Built on first use; clean runs build
         nothing."""
         if i not in self._plan_guards:

@@ -38,24 +38,21 @@ from repro.obs import trace as _obs_trace
 
 # v2 grew the optional per-entry "sharding" record (distributed plans:
 # mode, mesh axes/shape, query_parallel, grad_reduce) and the mesh-keyed
-# winner seeding that goes with it.  v3 grew the whole-pyramid fusion
-# decision: specs carry ``fuse_levels``, autotune winners the optional
-# ``fuse_levels`` / ``onehot_levels`` / ``grad_reduce`` fields — all
-# round-tripped so a restored plan keeps the raced decisions with zero
-# timing runs.  v4 grew the hybrid batch x query sharding mode
-# ('batchquery', with its ``batch_tile`` in the sharding record) and the
+# winner seeding that goes with it.  v3 grew the fusion and one-hot
+# routing decisions and the optional winner field ``grad_reduce``.  v4
+# grew the hybrid batch x query sharding mode ('batchquery', with its
+# ``batch_tile`` in the sharding record) and the
 # elastic restore path (``on_mesh_mismatch="rerace"``).  v5 grew the
 # sparsity axes: specs carry ``sparsity``/``sparsity_k``/``query_order``
 # and autotune winners the optional ``sparsity`` / ``query_order``
 # fields (pruned-vs-dense and Morton-vs-identity race decisions).
-# v6 grew the partial-fusion tier: specs may pin ``fuse_levels`` to
-# "prefix:k" and autotune winners carry the optional ``fuse_prefix``
-# field (the 3-way per-level / prefix / full-pyramid race's decision) —
-# absent means what it always meant, "fuse everything fuse_levels says
-# to", so every pre-tier winner keeps its exact historical semantics.
-# v1-v5 stores load unchanged; entries a NEWER schema writes still
-# degrade per entry, and unknown winner fields ride through the
-# parse/rewrite cycle untouched (``_winner_entry`` extras).
+# v6 grew the partial-fusion tier.  Every plan now runs one launch per
+# direction over the packed pyramid, so the fusion, walk and one-hot
+# keys of v3-v6 stores (``plan.RETIRED_KEYS``) are read and dropped,
+# and nothing writes them.  v1-v5 stores load unchanged otherwise;
+# entries a NEWER schema writes still degrade per entry, and unknown
+# winner fields ride through the parse/rewrite cycle untouched
+# (``_winner_entry`` extras).
 PLAN_STORE_VERSION = 6
 _READABLE_VERSIONS = (1, 2, 3, 4, 5, 6)
 
@@ -154,18 +151,7 @@ class PlanStore:
                 winner: Dict[str, Any] = {
                     "block_q": [int(b) for b in plan.tuning.block_q],
                     "slab_dtypes": list(plan.tuning.slab_dtypes),
-                    # the fusion race's decision rides along so a
-                    # restored plan re-commits it with zero timing runs
-                    "fuse_levels": bool(plan.tuning.fuse_levels),
                 }
-                # strict partial-fusion tier (0 < k < L): persisted only
-                # when the race actually chose one, so full-fusion and
-                # per-level winners stay byte-identical to pre-v6 stores
-                if plan.tuning.fuse_levels and plan.tuning.fuse_prefix:
-                    winner["fuse_prefix"] = int(plan.tuning.fuse_prefix)
-                if plan.spec.onehot_small_levels and plan.tuning.onehot_levels:
-                    winner["onehot_levels"] = [
-                        bool(x) for x in plan.tuning.onehot_levels]
                 # the sparsity rungs' raced decisions persist only when
                 # the axis actually raced ('auto') — pinned/off specs
                 # keep their pre-sparsity entry byte-identical
@@ -245,7 +231,7 @@ class PlanStore:
           recorded in ``report.skipped`` and that plan boots cold.
         * ``"rerace"`` (the elastic training path): the entry's LOCAL
           winner is re-seeded onto the per-shard geometry the NEW mesh
-          implies — so the block/dtype/fuse axes stay zero-timing cache
+          implies — so the block/dtype axes stay zero-timing cache
           hits — and the plan is rebuilt under ``sharding="auto"`` /
           ``grad_reduce="auto"``, which re-races EXACTLY the mesh-keyed
           axes (sharding mode, grad_value reduction) and persists the
@@ -319,7 +305,7 @@ class PlanStore:
                     # applies — re-key it onto the per-shard geometry
                     # the NEW mesh's auto ladder implies (blocks clamped
                     # to the new local query extent), so the rebuild's
-                    # block/dtype/fuse races are cache hits and only the
+                    # block/dtype races are cache hits and only the
                     # mesh-keyed axes re-race
                     qp = bool(shard.get("query_parallel"))
                     _, local_spec = plan_mod.resolve_sharding(

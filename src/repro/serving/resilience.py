@@ -17,8 +17,8 @@ something that degrades gracefully instead of falling over
   :class:`GuardedExecutor` wraps an executor callable: transient
   failures retry with backoff; ``breaker_threshold`` CONSECUTIVE
   exhausted calls open the breaker and demote one rung down the
-  ladder (for MSDA plans: ``MsdaPlan.fallback()`` — fused ->
-  per-level -> ref, sparse -> dense; built race-free, never persisted
+  ladder (for MSDA plans: ``MsdaPlan.fallback()`` — sparse -> dense
+  -> ref, interpreted kernels only; built race-free, never persisted
   as a winner); while demoted, the primary is probed on a half-open
   schedule every ``probe_interval`` calls and promoted back on
   success.

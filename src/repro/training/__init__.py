@@ -8,7 +8,7 @@ elastic plan-recovery rung (:mod:`~repro.training.elastic`) that
 re-races the mesh-keyed autotune axes when the topology changed under a
 restored ``PlanStore``, and a step-time recorder
 (:mod:`~repro.training.telemetry`) emitting ``BENCH_train.json`` in the
-same trajectory format as ``BENCH_kernels.json``.
+same trajectory format as ``BENCH_sparsity.json``.
 """
 from repro.training.elastic import ElasticPlanReport, recover_plans  # noqa: F401
 from repro.training.faults import (  # noqa: F401

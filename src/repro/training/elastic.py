@@ -9,7 +9,7 @@ worse, ``restore(mesh=...)`` refused the store outright.
 * topology matches  -> plain restore, zero timing runs (unchanged);
 * topology differs  -> ``restore(..., on_mesh_mismatch="rerace")``
   re-keys each entry's LOCAL autotune winner onto the new per-shard
-  geometry (block/dtype/fuse axes stay cache hits) and re-races ONLY
+  geometry (block/dtype axes stay cache hits) and re-races ONLY
   the mesh-keyed axes — the sharding mode (1d / 2d / hybrid) and the
   grad_value reduction (ring / psum) — then **persists the new
   winners** back to the store, so the NEXT restart on this topology is

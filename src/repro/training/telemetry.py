@@ -1,7 +1,7 @@
 """Step-time telemetry -> ``BENCH_train.json``.
 
 The training counterpart of ``benchmarks/paper_benchmarks.py``'s
-``BENCH_kernels.json``: one recorder object rides the harness, collects
+``BENCH_sparsity.json``: one recorder object rides the harness, collects
 the per-step wall-time trajectory plus the runtime's discrete events
 (recoveries, re-plans), and writes a single JSON payload in the same
 ``{"bench", "config", "note", "results", ...}`` shape, so the CI

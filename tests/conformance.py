@@ -2,17 +2,15 @@
 
 Every backend returned by ``registry.list_backends()`` is parametrized
 against the ``"ref"`` oracle for forward and VJP parity, under every
-dtype policy — so any future ``register_backend(...)`` call is
-automatically covered the moment it lands (collection re-reads the
-registry).  CI shards the matrix via two env vars:
+dtype policy and every geometry form of the benchmark cells' MSDA calls
+— so any future ``register_backend(...)`` call is automatically covered
+the moment it lands (collection re-reads the registry).  CI shards the
+matrix via env vars:
 
 * ``REPRO_CONFORMANCE_BACKENDS`` — comma list restricting the backends
   (e.g. ``"ref,cpu"`` for the Pallas-free CPU lane),
 * ``REPRO_CONFORMANCE_POLICIES`` — comma list restricting the dtype
   policies (``"float32"`` / ``"bfloat16"``),
-* ``REPRO_CONFORMANCE_FUSE`` — comma list restricting the fusion
-  tiers (``"off"`` per-level / ``"prefix"`` partial fusion /
-  ``"full"`` whole pyramid),
 * ``REPRO_CONFORMANCE_SPARSITY`` — comma list restricting the sparsity
   variants (``"off"`` / ``"topk"``).
 
@@ -21,21 +19,12 @@ Tolerance tiers (documented, per dtype policy):
 * ``float32`` policy on the ``"ref"`` backend: **bit-identical** — the
   plan executes the oracle itself, so any difference is a planning bug.
 * ``float32`` policy elsewhere: ``2e-5`` fwd / ``5e-4`` VJP — fp32
-  reassociation only (fused vs per-corner gather order).
+  reassociation only (the kernels' gather order vs the oracle's).
 * ``bfloat16`` policy (bf16 slab, fp32 accumulation): ``3e-2`` fwd /
   ``1e-1`` VJP against the *fp32* oracle — one bf16 rounding of the
   value slab (8-bit mantissa => ~4e-3 relative per element, amplified
   by the P*L-term reduction); accumulation error does NOT grow with Q
   because the accumulator stays fp32.
-
-Fusion tiers add **no tolerance of their own** — the same per-policy
-tiers above apply to every ``fuse`` variant, mixed-dtype prefixes
-included.  The packed super-slab rounds each level to its own
-committed dtype and stores it in fp32 (exact), so a fused-prefix plan
-reads bit-identical level data to the per-level plan under the same
-dtype policy: the only
-numeric difference between tiers is gather order inside one fp32
-accumulation, which the fp32 reassociation tier already budgets for.
 
 Sparsity tier (``sparsity="topk"`` — lossy BY DESIGN): the pruned plan
 is conformance-checked against the *masked-renormalised* oracle
@@ -46,8 +35,9 @@ executor computes in fp32 end to end.  ``sparsity="off"`` and
 equal to the dense plan on every backend x policy (lossy modes are
 never picked untimed).
 
-Also here: finite-difference gradcheck of the backward path on small
-geometries, including sampling locations at and outside the [0, 1]
+Also here: a mutation self-test of the oracle comparison (one perturbed
+pixel of the packed slab must trip it), finite-difference gradcheck of
+the backward path on small geometries, including sampling locations at and outside the [0, 1]
 border where bilinear corner weights zero out — plus the pruned plan
 with well-separated attention weights (so eps-perturbations cannot
 flip the top-k selection AD differentiates through frozen).
@@ -59,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import ops
 from repro.kernels import plan as plan_mod
 from repro.kernels import registry
 from repro.kernels.plan import MsdaSpec, msda_plan
@@ -66,6 +57,18 @@ from repro.kernels.ref import msda_ref
 
 LEVELS = ((10, 6), (5, 3))
 B, Q, H, D, P = 2, 21, 2, 8, 3
+
+# the geometry forms of the benchmark cells' MSDA calls, in their head
+# layout (8 heads of 32), as (levels, points, queries): DETR's encoder
+# (5 levels x 4 points), BEVFormer's SCA (4 x 8), TSA and the decoders
+# (1 x 4), and an odd form whose query count is no multiple of the block
+FORM_H, FORM_D = 8, 32
+FORMS = {
+    "L5P4": (((8, 8), (4, 4), (3, 3), (2, 2), (1, 1)), 4, 16),
+    "L4P8": (((6, 10), (3, 5), (2, 3), (1, 2)), 8, 16),
+    "L1P4": (((10, 10),), 4, 16),
+    "L3P2-odd": (((10, 6), (5, 3), (3, 2)), 2, 21),
+}
 
 # documented per-policy tolerance tiers (see module docstring)
 FWD_TOL = {"float32": 2e-5, "bfloat16": 3e-2}
@@ -88,15 +91,6 @@ def _env_subset(env_var, names):
 
 BACKENDS = _env_subset("REPRO_CONFORMANCE_BACKENDS", registry.list_backends())
 POLICIES = _env_subset("REPRO_CONFORMANCE_POLICIES", ("float32", "bfloat16"))
-# fusion tiers: every backend is exercised per-level ('off'), with a
-# strict partial-fusion prefix ('prefix' — one fused launch over level 0
-# plus a per-level tail; k=1 is the only strict tier a 2-level pyramid
-# has) and with the whole-pyramid single launch ('full').  Fusion pins
-# are honoured only by fusable backends — elsewhere they're a no-op,
-# which this matrix proves.
-FUSES = _env_subset("REPRO_CONFORMANCE_FUSE", ("off", "prefix", "full"))
-# tier name -> the spec's fuse_levels pin that commits it
-_FUSE_PIN = {"off": "off", "prefix": "prefix:1", "full": "on"}
 SPARSITIES = _env_subset("REPRO_CONFORMANCE_SPARSITY", ("off", "topk"))
 
 
@@ -122,28 +116,27 @@ def _inputs(seed=0, levels=LEVELS, b=B, q=Q, h=H, d=D, p=P):
 
 
 def _spec(policy, *, train=False, levels=LEVELS, q=Q, h=H, d=D, p=P,
-          fuse="auto", sparsity="off", sparsity_k=0, query_order="identity"):
+          sparsity="off", sparsity_k=0, query_order="identity"):
     slab_dtype, accum_dtype = plan_mod.resolve_dtype_policy(policy)
     return MsdaSpec(spatial_shapes=levels, num_heads=h, head_dim=d,
                     num_points=p, num_queries=q, dtype="float32", train=train,
                     slab_dtype=slab_dtype, accum_dtype=accum_dtype,
-                    fuse_levels=fuse, sparsity=sparsity,
+                    sparsity=sparsity,
                     sparsity_k=sparsity_k, query_order=query_order)
 
 
-# --------------------------------------------------------------------------
-# fwd parity: every backend x dtype policy x fusion variant vs the oracle
-# --------------------------------------------------------------------------
+def _form(form):
+    """(levels, spec kwargs, operands) of a geometry form."""
+    levels, p, q = FORMS[form]
+    kw = dict(levels=levels, q=q, h=FORM_H, d=FORM_D, p=p)
+    return levels, kw, _inputs(b=1, **kw)
 
 
-@pytest.mark.parametrize("fuse", FUSES)
-@pytest.mark.parametrize("policy", POLICIES)
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_fwd_matches_ref_oracle(backend, policy, fuse):
-    value, loc, attn = _inputs()
-    plan = msda_plan(_spec(policy, fuse=_FUSE_PIN[fuse]), backend=backend)
+def _check_fwd(backend, policy, form):
+    levels, kw, (value, loc, attn) = _form(form)
+    plan = msda_plan(_spec(policy, **kw), backend=backend)
     out = plan(value, loc, attn)
-    ref = msda_ref(value, LEVELS, loc, attn)
+    ref = msda_ref(value, levels, loc, attn)
     assert out.shape == ref.shape and out.dtype == ref.dtype
     if backend == "ref" and policy == "float32":
         # the plan runs the oracle itself: bit-identical or planning bug
@@ -153,6 +146,52 @@ def test_fwd_matches_ref_oracle(backend, policy, fuse):
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
                                    atol=tol, rtol=tol)
+
+
+# --------------------------------------------------------------------------
+# fwd parity: every backend x dtype policy x geometry form vs the oracle
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("policy", POLICIES)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_fwd_matches_ref_oracle(backend, policy, form):
+    _check_fwd(backend, policy, form)
+
+
+# --------------------------------------------------------------------------
+# mutation self-test: the oracle comparison must be able to fail
+# --------------------------------------------------------------------------
+
+_MUTATED = ("pallas", "float32", "L3P2-odd")
+
+
+def test_mutated_packed_slab_breaks_parity(monkeypatch):
+    """Perturb ONE real pixel of the packed slab (every lane of its row)
+    and the fwd comparison must trip — proving the oracle comparison
+    actually constrains the kernels' data path."""
+    levels = FORMS[_MUTATED[2]][0]
+    # level 0's padded width is W + 2 and its real image origin sits at
+    # pixel (1, 1): this row is a real corner value, not a zero-pad row
+    # whose masked weight would null the perturbation
+    row = levels[0][1] + 2 + 1
+    orig = ops._pack_pyramid
+
+    def tampered(value_t, spatial_shapes, dtypes):
+        slab = orig(value_t, spatial_shapes, dtypes)
+        return slab.at[:, :, row, :].add(jnp.asarray(1.0, slab.dtype))
+
+    monkeypatch.setattr(ops, "_pack_pyramid", tampered)
+    with pytest.raises(AssertionError):
+        _check_fwd(*_MUTATED)
+
+
+def test_untampered_control_for_the_mutation():
+    """The mutation test's case, untampered: green.  Pairs with the
+    negative control so a failure there can only mean the perturbation
+    (not the case itself) broke parity."""
+    _check_fwd(*_MUTATED)
 
 
 @pytest.mark.parametrize("policy", POLICIES)
@@ -175,24 +214,24 @@ def test_bf16_policy_commits_bf16_slabs(backend, policy):
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fuse", FUSES)
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("policy", POLICIES)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_vjp_matches_ref_oracle(backend, policy, fuse):
-    value, loc, attn = _inputs()
-    plan = msda_plan(_spec(policy, train=True, fuse=_FUSE_PIN[fuse]),
-                     backend=backend)
+def test_vjp_matches_ref_oracle(backend, policy, form):
+    levels, kw, (value, loc, attn) = _form(form)
+    plan = msda_plan(_spec(policy, train=True, **kw), backend=backend)
 
     g = jax.grad(lambda v, l, a: jnp.sum(plan(v, l, a) ** 2),
                  argnums=(0, 1, 2))(value, loc, attn)
-    gr = jax.grad(lambda v, l, a: jnp.sum(msda_ref(v, LEVELS, l, a) ** 2),
+    gr = jax.grad(lambda v, l, a: jnp.sum(msda_ref(v, levels, l, a) ** 2),
                   argnums=(0, 1, 2))(value, loc, attn)
     tol = VJP_TOL[policy]
     for got, want, name in zip(g, gr, ("value", "loc", "attn")):
         assert got.dtype == want.dtype, name  # grad dtype == operand dtype
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
-            atol=tol, rtol=tol, err_msg=f"grad_{name} [{backend}/{policy}]")
+            atol=tol, rtol=tol,
+            err_msg=f"grad_{name} [{backend}/{policy}/{form}]")
 
 
 # --------------------------------------------------------------------------
@@ -420,10 +459,9 @@ def test_new_backend_is_auto_covered():
 # demotion costs numerically, per backend x policy (and, via BACKENDS,
 # auto-cover any future ``register_backend`` the moment it lands):
 #
-# * same-backend rungs (fused -> per-level, sparse -> dense identity
-#   with a keep-everything k) are **bitwise** — the rung reads the same
-#   slab bytes and accumulates in the same dtype, only launch structure
-#   changes;
+# * same-backend rungs (sparse -> dense identity with a keep-everything
+#   k) are **bitwise** — the rung reads the same slab bytes and
+#   accumulates in the same dtype;
 # * the terminal ``ref`` rung matches within the documented per-policy
 #   forward tiers (FWD_TOL) — same budget as any backend-vs-oracle gap;
 # * every rung is a heuristic build: zero autotune races, never
@@ -435,8 +473,7 @@ def test_new_backend_is_auto_covered():
 def test_fallback_ladder_rungs_are_consistent(backend, policy):
     value, loc, attn = _inputs()
     plan_mod.reset_autotune_stats()
-    primary = msda_plan(_spec(policy, fuse="on"), backend=backend,
-                        tune="heuristic")
+    primary = msda_plan(_spec(policy), backend=backend, tune="heuristic")
     chain = primary.fallback_chain()
     if primary.backend == "ref":
         assert not chain and primary.fallback() is None

@@ -1,23 +1,22 @@
-"""Plan-layer contracts for partial-fusion tiers (ISSUE 9).
+"""Plan-layer contracts of the one launch per direction.
 
-What the planner promises about a fusion tier, independent of kernel
-numerics (those live in ``test_fusion_differential.py``):
+What the planner promises, independent of kernel numerics:
 
 * ``launches_per_call()`` reports the static Pallas schedule by
-  direction — ``L`` per-level, ``1`` whole-pyramid, ``L - k + 1`` for a
-  strict ``prefix:k`` tier, with ``bwd`` zeroed on inference plans;
-* ``describe()`` names the tier (``fuse=pyramid[0:k)+per-level``) and
-  carries the launch schedule so an operator reads the launch bill from
-  the plan dump alone;
+  direction — one launch over the packed pyramid, with ``bwd`` zeroed
+  on inference plans and no launch for the non-Pallas backends;
+* ``describe()`` carries the launch schedule so an operator reads the
+  launch bill from the plan dump alone;
 * each ``plan(...)`` call feeds the ``msda.launches`` observability
   gauge by exactly its schedule (``execution_telemetry()``);
-* a VMEM-constrained ``fuse_levels="auto"`` spec commits a STRICT
-  prefix — partial fusion engages from the occupancy model, not only
-  from pins;
-* a strict-prefix autotune winner survives the PlanStore v6 round-trip:
-  restore rebuilds the tier with zero timing races and identical
-  ``describe()``.
+* every MSDA call of every benchmark configuration plans for a v5e
+  without a race, with one launch per direction and a block the SMEM
+  table chunks and the VMEM budget hold;
+* a spec whose packed pyramid does not fit its budget is refused at
+  plan time.
 """
+import dataclasses
+import json
 import os
 
 import jax
@@ -27,9 +26,10 @@ import pytest
 from repro.kernels import ops
 from repro.kernels import plan as plan_mod
 from repro.kernels.plan import MsdaSpec, msda_plan
-from repro.serving import persistence
 
-SHAPES = ((14, 14), (10, 10), (7, 7), (5, 5), (3, 3))
+SHAPES = ((14, 14), (10, 10), (7, 7))
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+V5E = "TPU v5 lite"
 
 
 @pytest.fixture(autouse=True)
@@ -43,11 +43,10 @@ def _isolated(tmp_path, monkeypatch):
     plan_mod.clear_plans()
 
 
-def _spec(fuse, *, levels=3, budget=0, train=True):
+def _spec(*, budget=0, train=True):
     return MsdaSpec(
-        spatial_shapes=SHAPES[:levels], num_heads=2, head_dim=8,
-        num_points=2, num_queries=32, train=train, fuse_levels=fuse,
-        vmem_budget=budget)
+        spatial_shapes=SHAPES, num_heads=2, head_dim=8, num_points=2,
+        num_queries=32, train=train, vmem_budget=budget)
 
 
 def _io(spec):
@@ -63,54 +62,31 @@ def _io(spec):
 
 
 # --------------------------------------------------------------------------
-# launches_per_call(): the static schedule, by tier and direction
+# launches_per_call(): the static schedule, by direction
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fuse,fwd", [("off", 3), ("on", 1), ("prefix:2", 2)])
-def test_pinned_tier_launch_schedule(fuse, fwd):
-    plan = msda_plan(_spec(fuse), backend="pallas")
-    assert plan.launches_per_call() == {"fwd": fwd, "bwd": fwd}
-
-
 def test_inference_plans_carry_no_backward_launches():
-    plan = msda_plan(_spec("prefix:2", train=False), backend="pallas")
-    assert plan.launches_per_call() == {"fwd": 2, "bwd": 0}
+    plan = msda_plan(_spec(train=False), backend="pallas")
+    assert plan.launches_per_call() == {"fwd": 1, "bwd": 0}
 
 
 def test_non_pallas_plans_report_zero_launches():
-    plan = msda_plan(_spec("off"), backend="cpu")
+    plan = msda_plan(_spec(), backend="cpu")
     assert plan.launches_per_call() == {"fwd": 0, "bwd": 0}
 
 
 # --------------------------------------------------------------------------
-# describe(): tier header, fuse note, launch bill
+# describe(): the launch bill
 # --------------------------------------------------------------------------
 
 
-def test_describe_names_the_strict_tier():
-    d = msda_plan(_spec("prefix:2"), backend="pallas").describe()
-    head = d.splitlines()[0]
-    assert "fuse=pyramid[0:2)+per-level" in head, head
-    assert "fused prefix [0:2): 2 launches/direction" in d, d
-    assert "tail levels 2..2 per-level" in d, d
-
-
-@pytest.mark.parametrize("fuse,line", [
-    ("off", "launches/call: fwd=3 bwd=3"),
-    ("on", "launches/call: fwd=1 bwd=1"),
-    ("prefix:2", "launches/call: fwd=2 bwd=2"),
-])
-def test_describe_carries_the_launch_bill(fuse, line):
-    assert line in msda_plan(_spec(fuse), backend="pallas").describe()
-
-
-def test_describe_tier_rows_show_fusion_membership():
-    plan = msda_plan(_spec("prefix:2"), backend="pallas")
-    rep = plan.level_report()
-    assert [r["fused"] for r in rep] == [True, True, False]
-    # prefix rows share the super-slab occupancy figure
-    assert rep[0]["vmem_frac"] == rep[1]["vmem_frac"]
+@pytest.mark.parametrize("train,line", [
+    (True, "launches/call: fwd=1 bwd=1"),
+    (False, "launches/call: fwd=1 bwd=0"),
+], ids=["on", "infer"])
+def test_describe_carries_the_launch_bill(train, line):
+    assert line in msda_plan(_spec(train=train), backend="pallas").describe()
 
 
 # --------------------------------------------------------------------------
@@ -119,78 +95,81 @@ def test_describe_tier_rows_show_fusion_membership():
 
 
 def test_launch_gauge_advances_by_the_schedule():
-    spec = _spec("prefix:2")
+    spec = _spec()
     plan = msda_plan(spec, backend="pallas")
     value, loc, attn = _io(spec)
     before = plan_mod.execution_telemetry()["launches"]
     plan(value, loc, attn)
     plan(value, loc, attn)
     after = plan_mod.execution_telemetry()["launches"]
-    assert after["fwd"] - before["fwd"] == 2 * 2  # 2 calls x (L - k + 1)
-    assert after["bwd"] - before["bwd"] == 2 * 2
+    assert after["fwd"] - before["fwd"] == 2  # 2 calls x 1 launch
+    assert after["bwd"] - before["bwd"] == 2
     assert after["plan_calls"] - before["plan_calls"] == 2
 
 
 # --------------------------------------------------------------------------
-# acceptance: a VMEM-constrained auto spec commits a strict prefix
+# every MSDA call of every benchmark configuration plans for a v5e
 # --------------------------------------------------------------------------
 
-
-def test_vmem_constrained_auto_spec_plans_strict_prefix():
-    L = len(SHAPES)
-    # roomy default budget: the occupancy model fuses the whole pyramid
-    roomy = msda_plan(_spec("auto", levels=L), backend="pallas")
-    assert roomy.fused and roomy.fuse_prefix == L
-    assert roomy.launches_per_call()["fwd"] == 1
-
-    # walk the budget down to where the model admits only a strict
-    # prefix, then confirm the PLANNER (not just the model) commits it
-    for b in range(20_000, 3_000_000, 10_000):
-        k = ops.fusion_prefix(SHAPES, 2, 8, value_itemsize=4,
-                              train=True, vmem_budget=b)
-        if 2 <= k < L:
-            break
-    else:  # pragma: no cover - occupancy model regressed
-        pytest.fail("no budget yields a strict prefix")
-    tight = msda_plan(_spec("auto", levels=L, budget=b), backend="pallas")
-    assert 0 < tight.fuse_prefix < L
-    assert tight.fuse_prefix == k
-    assert tight.launches_per_call()["fwd"] == L - k + 1
-    assert f"fuse=pyramid[0:{k})+per-level" in tight.describe()
+CALLS = ([("deformable-detr", n) for n in ("encoder", "decoder")]
+         + [("deformable-detr-coco800", n) for n in ("encoder", "decoder")]
+         + [("bevformer-base", f"sca.cam{c}") for c in range(6)]
+         + [("bevformer-base", n) for n in ("tsa", "decoder")])
 
 
-# --------------------------------------------------------------------------
-# PlanStore v6: strict-prefix winners restore with zero races
-# --------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def config_specs():
+    """{(configuration, call): spec} as the program's plan entry points
+    build them for each configuration file of the benchmark (read, not
+    edited), in inference mode."""
+    import sys
 
+    sys.path.insert(0, ROOT)
+    from chipbench import catalog
+    from repro.core import deformable_transformer as dt
+    from repro.models import bevformer as bf
 
-def test_plan_store_v6_strict_prefix_round_trip(tmp_path):
-    spec = _spec("auto")
-    plan_mod.seed_autotune_winner(spec, "pallas", {
-        "block_q": [16, 16, 16],
-        "slab_dtypes": ["float32"] * 3,
-        "fuse_levels": True,
-        "fuse_prefix": 2,
-    })
-    plan = msda_plan(spec, backend="pallas", tune="autotune")
-    assert plan.fused and plan.fuse_prefix == 2
-    assert plan_mod.autotune_stats()["raced"] == 0  # seeded, not timed
-    before = plan.describe()
-
-    store = persistence.PlanStore(str(tmp_path / "plans.json"))
-    assert store.save_plans([plan]) == 1
-
-    # simulated restart: plan cache gone, winner cache gone
+    out = {}
+    for name in sorted({c for c, _ in CALLS}):
+        with open(os.path.join(ROOT, "chipbench", "configs",
+                               f"{name}.json")) as f:
+            cfg = json.load(f)
+        mcfg = catalog.family(cfg).program_config(cfg)
+        plans = (bf.msda_plans(mcfg) if cfg["family"] == "bevformer"
+                 else dt.msda_plans(mcfg))
+        out.update({(name, n): p.spec for n, p in plans.items()})
     plan_mod.clear_plans()
-    os.environ["REPRO_MSDA_AUTOTUNE_CACHE"] = str(tmp_path / "autotune2.json")
-    plan_mod.reset_autotune_stats()
-    report = persistence.PlanStore(store.path).restore()
-    assert len(report.plans) == 1 and not report.skipped
-    assert report.describe_mismatches == []
-    restored = report.plans[0]
-    assert restored.fused and restored.fuse_prefix == 2
-    assert restored.launches_per_call() == {"fwd": 2, "bwd": 2}
-    assert (persistence._norm_describe(restored.describe())
-            == persistence._norm_describe(before))
-    assert plan_mod.autotune_stats()["raced"] == 0, \
-        "restore must not run autotune timing"
+    return out
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
+@pytest.mark.parametrize("config,call", CALLS,
+                         ids=[f"{c}.{n}" for c, n in CALLS])
+def test_every_config_call_plans_one_launch_for_v5e(config_specs, config,
+                                                    call, train):
+    spec = dataclasses.replace(
+        config_specs[(config, call)], train=train,
+        vmem_budget=plan_mod.default_vmem_budget(V5E))
+    plan = msda_plan(spec, backend="pallas", tune="heuristic")
+    assert plan.tuning.source == "heuristic"
+    assert plan_mod.autotune_stats()["raced"] == 0
+    assert plan.launches_per_call() == {"fwd": 1, "bwd": int(train)}
+    L = spec.num_levels
+    bq = plan.block_q[0]
+    assert plan.block_q == (bq,) * L
+    assert bq <= ops.smem_block_cap(spec.num_points, levels=L,
+                                    heads=spec.heads_per_launch, train=train)
+    assert all(r["vmem_frac"] <= 1.0 for r in plan.level_report())
+
+
+# --------------------------------------------------------------------------
+# a pyramid the budget cannot hold is refused at plan time
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
+def test_plan_refuses_a_pyramid_over_budget(train):
+    need = ops.min_launch_bytes(SHAPES, 2, 8, train=train, heads=2)
+    assert msda_plan(_spec(budget=need, train=train), backend="pallas")
+    with pytest.raises(ValueError, match=f"{need} bytes.*{need - 1} bytes"):
+        msda_plan(_spec(budget=need - 1, train=train), backend="pallas")

@@ -5,10 +5,10 @@ On a TPU ``msda_gather`` walks a query in steps of
 ``msda_fwd.STEP_ROWS`` rows, else a rolled loop of steps with every
 level's chain unrolled inside.  The interpreter rolls even a one-step
 walk, so the chip's form never runs on a CPU unless forced.  These tests
-force it (``unroll=True``) and hold it to the rolled form bitwise, to the
-other fusion tiers bitwise, and to the float32 oracle — fused, prefix and
-per-level, with and without the saved corners of the training forward,
-and for the corner-major ablation walk.  They also hold the gather to a
+force it (``unroll=True``) and hold it to the rolled form bitwise and to
+the float32 oracle — for the step shapes of DETR's encoder, BEVFormer's
+SCA and its one-level TSA, with and without the saved corners of the
+training forward.  They also hold the gather to a
 float32 numpy walk in the scalar-weight kernel's add order, bit for bit,
 over every weight-row layout and step shape, and the MXU weight spread to
 every float32 weight.  XLA:CPU is held to separate multiplies and adds
@@ -25,20 +25,24 @@ from repro.kernels import msda_fwd, ops
 from repro.kernels.ref import msda_ref
 
 # head_dim 16 puts all 8 heads in one group: a query step gathers
-# 3 * 8 * 3 * 4 = 288 rows fused, 96 per level: a rolled point loop in
-# every tier.  At 2 points a per-level step (64 rows) is unrolled whole.
+# 3 * 8 * 3 * 4 = 288 rows, a rolled point loop.  At 2 points a step
+# (192 rows) is the whole query, unrolled.
 LEVELS = ((8, 6), (4, 3), (2, 2))
 B, Q, H, D, P = 2, 19, 8, 16, 3
-TIERS = {
-    "fused": dict(fuse_levels=True),
-    "prefix-2": dict(fuse_levels=True, fuse_prefix=2),
-    "per-level": {},
+# (levels, points, head_dim) of the walk forms, 8 heads: this file's
+# rolled 3-level pyramid, BEVFormer's SCA (4 levels x 8 points in heads
+# of 32: four points a rolled step) and its one-level TSA (the whole
+# query one step)
+FORMS = {
+    "fused": (LEVELS, P, D),
+    "L4P8": (((6, 5), (3, 3), (2, 2), (1, 2)), 8, 32),
+    "L1P4": (((7, 6),), 4, 32),
 }
 
 
-def _inputs(seed=0, P=P):
-    S = sum(h * w for h, w in LEVELS)
-    L = len(LEVELS)
+def _inputs(seed=0, P=P, levels=LEVELS, D=D):
+    S = sum(h * w for h, w in levels)
+    L = len(levels)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     value = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
     # straddle the border: masked corners take the same walk
@@ -50,14 +54,14 @@ def _inputs(seed=0, P=P):
     return value, loc, attn
 
 
-def _results(tier, train, fuse_gather=True, seed=0, P=P):
+def _results(form, train, seed=0, P=None):
     """Forward, and for training plans the full VJP (whose weight grads
     read the forward's saved corners)."""
-    params = ops.MSDAParams(
-        spatial_shapes=LEVELS, block_q=(8,) * len(LEVELS), interpret=True,
-        save_sampled=train, fuse_gather=fuse_gather, **TIERS[tier])
+    levels, p, d = FORMS[form]
+    params = ops.MSDAParams(spatial_shapes=levels, block_q=8, interpret=True,
+                            save_sampled=train)
     op = ops.build_kernel_op(params)
-    value, loc, attn = _inputs(seed, P)
+    value, loc, attn = _inputs(seed, P or p, levels, d)
     out = [op(value, loc, attn)]
     if train:
         out += jax.grad(lambda v, l, a: jnp.sum(op(v, l, a) ** 2),
@@ -82,54 +86,31 @@ def chip_form(monkeypatch):
 def test_geometry_takes_both_chip_structures():
     G = msda_fwd.head_group(H, D)
     L = len(LEVELS)
-    assert msda_fwd.step_points(L, G, P) < P  # fused: a rolled walk
+    assert msda_fwd.step_points(L, G, P) < P  # three levels: a rolled walk
     assert msda_fwd.step_points(1, G, P) == P  # one level: a whole query
     assert msda_fwd.step_points(1, G, 2) == 2
 
 
 @pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
-@pytest.mark.parametrize("tier", sorted(TIERS))
-def test_chip_form_matches_rolled_form_and_oracle(tier, train, chip_form):
-    rolled = _results(tier, train)
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_chip_form_matches_rolled_form_and_oracle(form, train, chip_form):
+    rolled = _results(form, train)
     chip_form()
-    chip = _results(tier, train)
+    chip = _results(form, train)
     for name, a, b in zip(("out", "grad_value", "grad_loc", "grad_attn"),
                           chip, rolled):
         np.testing.assert_array_equal(a, b, err_msg=name)
-    value, loc, attn = _inputs()
-    ref = msda_ref(value, LEVELS, loc, attn)
-    np.testing.assert_allclose(chip[0], np.asarray(ref), atol=2e-5)
-
-
-@pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
-@pytest.mark.parametrize("tier", ["fused", "prefix-2"])
-def test_chip_form_tiers_bitwise(tier, train, chip_form):
-    chip_form()
-    fused = _results(tier, train, seed=1)
-    per_level = _results("per-level", train, seed=1)
-    for name, a, b in zip(("out", "grad_value", "grad_loc", "grad_attn"),
-                          fused, per_level):
-        np.testing.assert_array_equal(a, b, err_msg=name)
-
-
-@pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
-def test_chip_form_corner_major_walk(train, chip_form):
-    rolled = _results("fused", train, fuse_gather=False, seed=2)
-    chip_form()
-    chip = _results("fused", train, fuse_gather=False, seed=2)
-    for name, a, b in zip(("out", "grad_value", "grad_loc", "grad_attn"),
-                          chip, rolled):
-        np.testing.assert_array_equal(a, b, err_msg=name)
-    value, loc, attn = _inputs(seed=2)
-    ref = msda_ref(value, LEVELS, loc, attn)
+    levels, p, d = FORMS[form]
+    value, loc, attn = _inputs(0, p, levels, d)
+    ref = msda_ref(value, levels, loc, attn)
     np.testing.assert_allclose(chip[0], np.asarray(ref), atol=2e-5)
 
 
 @pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
 def test_unrolled_query_step(train, chip_form):
-    rolled = _results("per-level", train, seed=3, P=2)
+    rolled = _results("fused", train, seed=3, P=2)
     chip_form()
-    chip = _results("per-level", train, seed=3, P=2)
+    chip = _results("fused", train, seed=3, P=2)
     for name, a, b in zip(("out", "grad_value", "grad_loc", "grad_attn"),
                           chip, rolled):
         np.testing.assert_array_equal(a, b, err_msg=name)
@@ -163,7 +144,7 @@ def _walk_inputs(shapes, P, D, Q=13, qb=8, seed=0):
     L, GD = len(shapes), G * D
     rng = np.random.default_rng(seed)
     offs, rows = ops.pyramid_row_offsets(shapes)
-    geoms = tuple((o, w + 2, ops.slab_rows((h, w)), False)
+    geoms = tuple((o, w + 2, ops.slab_rows((h, w)))
                   for o, (h, w) in zip(offs, shapes))
     slab = rng.standard_normal((1, 1, rows, GD)).astype(np.float32)
     qp = -(-Q // qb) * qb
@@ -191,21 +172,18 @@ def _walk_inputs(shapes, P, D, Q=13, qb=8, seed=0):
     return geoms, slab, idx.reshape(-1), w, wrows, top
 
 
-def _parent_walk(geoms, slab, w, top, P, D, corner_major=False):
+def _parent_walk(geoms, slab, w, top, P, D):
     """Per query: every level's chain of ``acc + w * row``, heads on
-    their own lanes, points outer and corners inner (corners outer for
-    the corner-major walk); the level sums added in level order — the
-    scalar-weight kernel's adds, in float32.  Also each query's raw
-    corners in saved-slot order."""
+    their own lanes, points outer and corners inner; the level sums
+    added in level order — the scalar-weight kernel's adds, in float32.
+    Also each query's raw corners in saved-slot order."""
     qp, G, L = w.shape[0], w.shape[1], len(geoms)
     out = np.zeros((qp, G * D), np.float32)
     saved = np.zeros((qp * L * 4 * P, G * D), np.float32)
     order = [(p, c) for p in range(P) for c in range(4)]
-    if corner_major:
-        order = [(p, c) for c in range(4) for p in range(P)]
     for q in range(qp):
         total = np.zeros(G * D, np.float32)
-        for l, (_, wp, _, _) in enumerate(geoms):
+        for l, (_, wp, _) in enumerate(geoms):
             acc = np.zeros(G * D, np.float32)
             for p, c in order:
                 for h in range(G):
@@ -236,21 +214,6 @@ def test_gather_equals_scalar_weight_walk(walk, save, chip_form):
     np.testing.assert_array_equal(np.asarray(out)[0, 0], want)
     if save:
         np.testing.assert_array_equal(np.asarray(saved)[0, 0], want_saved)
-
-
-@pytest.mark.parametrize("walk", ["rolled-2-G4", "two-rows-G8", "packed-G8"])
-def test_corner_major_walk_equals_scalar_weight_walk(walk, chip_form):
-    shapes, P, D = WALKS[walk]
-    geoms, slab, idx, w, wrows, top = _walk_inputs(shapes, P, D, seed=1)
-    chip_form()
-    out, saved = msda_fwd.msda_gather(
-        jnp.asarray(slab), jnp.asarray(idx), jnp.asarray(wrows),
-        levels=geoms, num_points=P, head_dim=D, block_q=8,
-        fuse_gather=False, save_dtype=jnp.float32, interpret=True)
-    want, want_saved = _parent_walk(geoms, slab, w, top, P, D,
-                                    corner_major=True)
-    np.testing.assert_array_equal(np.asarray(out)[0, 0], want)
-    np.testing.assert_array_equal(np.asarray(saved)[0, 0], want_saved)
 
 
 @pytest.mark.parametrize("L,P,D", [(1, 4, 32), (5, 4, 32), (4, 8, 32),
@@ -331,8 +294,7 @@ def test_inference_builds_no_backward_weight_table():
     G = msda_fwd.head_group(H, D)
     n = B * (H // G) * (Qp // 8) * msda_fwd.table_block(4 * G * 8 * L * P)
     table = f"f32[{n}]"
-    params = ops.MSDAParams(spatial_shapes=LEVELS, block_q=(8,) * L,
-                            interpret=True, fuse_levels=True)
+    params = ops.MSDAParams(spatial_shapes=LEVELS, block_q=8, interpret=True)
     op = ops.build_kernel_op(params)
     value, loc, attn = _inputs()
     hlo = jax.jit(op).lower(value, loc, attn).compile().as_text()

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import msda_fwd, ops
 from repro.kernels.ref import msda_grid_sample_baseline, msda_ref
 
 CASES = [
@@ -34,14 +34,62 @@ def _inputs(B, Q, H, D, P, levels, dtype=jnp.float32, seed=0):
     return value, loc, attn, gout
 
 
+def _saved_corners(levels, value, loc, attn):
+    """The training forward's (out, saved corners) straight from the
+    gather kernel: (B, Q, H*D) and (B, NG, Qp*L*4P, G*D)."""
+    B, S, H, D = value.shape
+    Q, P = loc.shape[1], loc.shape[4]
+    G = msda_fwd.head_group(H, D)
+    p = ops.MSDAParams(spatial_shapes=levels, block_q=8, interpret=True,
+                       save_sampled=True)
+    idx, wrows, _ = ops._corner_tables(p, loc, attn, D)
+    value_g = jnp.transpose(value.reshape(B, S, H // G, G * D), (0, 2, 1, 3))
+    slab = ops._pack_pyramid(value_g, levels, p.level_dtypes(value.dtype))
+    geoms, _ = ops._pyramid_geometry(p)
+    out, saved = msda_fwd.msda_gather(
+        slab, idx, wrows, levels=geoms, num_points=P, head_dim=D, block_q=8,
+        save_dtype=jnp.float32, interpret=True)
+    out = jnp.transpose(out[:, :, :Q], (0, 2, 1, 3)).reshape(B, Q, H * D)
+    return np.asarray(out), np.asarray(saved)
+
+
+def _check_saved_corners(levels, value, loc, saved):
+    """Every corner a weight can reach (inside its level) holds that
+    pixel's value, in its saved slot."""
+    B, S, H, D = value.shape
+    Q, L, P = loc.shape[1], loc.shape[3], loc.shape[4]
+    G = msda_fwd.head_group(H, D)
+    K = L * 4 * P
+    value, loc = np.asarray(value), np.asarray(loc)
+    offs = np.cumsum([0] + [h * w for h, w in levels])
+    for l, (hh, ww) in enumerate(levels):
+        x0 = np.floor(loc[:, :, :, l, :, 0] * ww - 0.5).astype(int)
+        y0 = np.floor(loc[:, :, :, l, :, 1] * hh - 0.5).astype(int)
+        for b, q, h, pt in np.ndindex(B, Q, H, P):
+            for c in range(4):
+                x, y = x0[b, q, h, pt] + (c & 1), y0[b, q, h, pt] + (c >> 1)
+                if not (0 <= x < ww and 0 <= y < hh):
+                    continue
+                row = q * K + msda_fwd.saved_slot(l, c, pt, P)
+                got = saved[b, h // G, row, (h % G) * D:(h % G + 1) * D]
+                np.testing.assert_array_equal(
+                    got, value[b, offs[l] + y * ww + x, h],
+                    err_msg=f"corner {(b, q, h, l, pt, c)}")
+
+
 @pytest.mark.parametrize("case", CASES, ids=[str(c[:5]) for c in CASES])
-@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "unfused"])
-def test_fwd_matches_oracle(case, fuse):
+@pytest.mark.parametrize("train", [False, True], ids=["infer", "train"])
+def test_fwd_matches_oracle(case, train):
+    """Inference forward, and the training forward whose saved corners
+    must be the pixels the oracle samples."""
     B, Q, H, D, P, levels = case
     value, loc, attn, _ = _inputs(B, Q, H, D, P, levels)
     ref = msda_ref(value, levels, loc, attn)
-    out = ops.msda(value, levels, loc, attn, backend="pallas",
-                   fuse_gather=fuse, fuse_scatter=fuse)
+    if train:
+        out, saved = _saved_corners(levels, value, loc, attn)
+        _check_saved_corners(levels, value, loc, saved)
+    else:
+        out = ops.msda(value, levels, loc, attn, backend="pallas")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
@@ -81,20 +129,6 @@ def test_grads_match_oracle(case, train):
         )
 
 
-def test_unfused_scatter_matches():
-    B, Q, H, D, P, levels = 2, 16, 2, 8, 2, ((8, 8),)
-    value, loc, attn, gout = _inputs(B, Q, H, D, P, levels)
-
-    def loss(v, fuse):
-        return jnp.vdot(
-            ops.msda(v, levels, loc, attn, backend="pallas", fuse_scatter=fuse), gout
-        )
-
-    g1 = jax.grad(lambda v: loss(v, True))(value)
-    g2 = jax.grad(lambda v: loss(v, False))(value)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
-
-
 def test_baseline_equals_oracle():
     B, Q, H, D, P, levels = 2, 33, 4, 8, 3, ((14, 9), (7, 5), (3, 3))
     value, loc, attn, _ = _inputs(B, Q, H, D, P, levels)
@@ -128,12 +162,11 @@ def test_pixel_center_exactness():
 
 
 def test_plan_blocks_adaptive():
-    """Adaptive block planning: small levels get wide blocks (paper Fig. 7)."""
-    shapes = ((256, 256), (16, 16))
-    bq = ops.plan_blocks(shapes, 4, 32, 1000)
-    assert bq[1] >= bq[0]  # smaller level -> at least as much vec-len headroom
-    fixed = ops.plan_blocks(shapes, 4, 32, 1000, adaptive=False)
-    assert all(b == 8 for b in fixed)
+    """Adaptive block planning: smaller pyramids get wider blocks (paper
+    Fig. 7)."""
+    big = ops.plan_blocks(((256, 256), (16, 16)), 4, 32, 4096)
+    small = ops.plan_blocks(((16, 16),), 4, 32, 4096)
+    assert small > big
 
 
 def test_block_q_invariance():
@@ -141,33 +174,5 @@ def test_block_q_invariance():
     B, Q, H, D, P, levels = 1, 24, 2, 8, 2, ((8, 8), (4, 4))
     value, loc, attn, _ = _inputs(B, Q, H, D, P, levels)
     o1 = ops.msda(value, levels, loc, attn, backend="pallas", block_q=(8, 8))
-    o2 = ops.msda(value, levels, loc, attn, backend="pallas", block_q=(24, 16))
+    o2 = ops.msda(value, levels, loc, attn, backend="pallas", block_q=(24, 24))
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
-
-
-@pytest.mark.parametrize("case", CASES[:3], ids=[str(c[:5]) for c in CASES[:3]])
-def test_onehot_mxu_path_matches(case):
-    """Beyond-paper MXU one-hot gather/scatter == oracle (fwd + grads)."""
-    B, Q, H, D, P, levels = case
-    value, loc, attn, gout = _inputs(B, Q, H, D, P, levels)
-    ref = msda_ref(value, levels, loc, attn)
-    out = ops.msda(value, levels, loc, attn, backend="pallas",
-                   onehot_small_levels=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-
-    def loss(v):
-        return jnp.vdot(
-            ops.msda(v, levels, loc, attn, backend="pallas",
-                     onehot_small_levels=True), gout)
-
-    def loss_ref(v):
-        return jnp.vdot(msda_ref(v, levels, loc, attn), gout)
-
-    g = jax.grad(loss)(value)
-    gr = jax.grad(loss_ref)(value)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(gr), atol=5e-4)
-
-
-def test_onehot_plan_thresholds():
-    plan = ops.plan_onehot(((256, 256), (16, 16), (4, 4)))
-    assert plan == (False, True, True)  # big levels stay on the VPU gather

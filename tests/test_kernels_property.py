@@ -112,9 +112,11 @@ def _round_up8(x):
 def test_planned_block_q_respects_vmem_model(args):
     """For random specs — TRAIN ones included — heuristic block_q stays
     sublane(8)-aligned, never exceeds the query extent, the 2048 cap or
-    the SMEM cap of its table chunks, and under the VMEM model never
-    exceeds vmem_budget (unless already clamped at the 8-row floor / the
-    model's 1 MiB minimum working set).  The per-query working set of a
+    the SMEM cap of its table chunks, is one block for every level of
+    the launch, and under the VMEM model never exceeds vmem_budget once
+    the packed pyramid is resident (unless already clamped at the 8-row
+    floor / the model's 1 MiB minimum working set).  The per-query
+    working set of a
     train plan is the backward step: gout rows, the saved corners
     (block_q x 4P rows of the head group's lanes, slab dtype), weight
     grads and phase 1's fp32 copy of the corners."""
@@ -123,28 +125,34 @@ def test_planned_block_q_respects_vmem_model(args):
         spatial_shapes=levels, num_heads=2, head_dim=D, num_points=P,
         num_queries=Q, train=train, vmem_budget=budget, slab_dtype=slab)
     G = spec.heads_per_launch
+    L = len(levels)
     lanes = ops.lane_width(G, D)
-    bqs = plan_mod._heuristic_block_q(spec)
+    bqs = plan_mod._heuristic_block_q(
+        spec, plan_mod._default_slab_dtypes(spec))
     per_q = ops.per_query_bytes(P, D, train=train,
-                                slab_itemsize=spec.slab_itemsize, heads=G)
-    rows = msda_fwd.weight_layout(1, P, D)[0]
+                                slab_itemsize=spec.slab_itemsize, levels=L,
+                                heads=G)
+    rows = msda_fwd.weight_layout(L, P, D)[0]
     forward = 2 * (lanes * 4 + rows * lanes * 4)
     if train:
-        saved = 4 * P * lanes * spec.slab_itemsize
+        k = L * 4 * P
+        saved = k * lanes * spec.slab_itemsize
         assert per_q == max(
             forward + 2 * saved,
-            2 * (lanes * 4 + saved + G * 4 * P * 4) + 4 * P * lanes * 4)
+            2 * (lanes * 4 + saved + G * k * 4) + k * lanes * 4)
     else:
         assert per_q == forward
-    for hw, bq in zip(levels, bqs):
-        assert bq % 8 == 0 and 8 <= bq <= 2048
-        assert bq <= _round_up8(Q)
-        assert bq <= max(8, ops.smem_block_cap(P, heads=G, train=train))
-        # the resident slab is fp32 over the head group's lanes
-        resident = ops.slab_rows(hw) * lanes * 4
-        # the documented model: per-step bytes fit what the budget leaves
-        # after the resident slab, floored at a 1 MiB working set
-        assert bq * per_q <= max(budget - resident, 1 * _MIB) or bq == 8
+    bq = bqs[0]
+    assert bqs == (bq,) * L
+    assert bq % 8 == 0 and 8 <= bq <= 2048
+    assert bq <= _round_up8(Q)
+    assert bq <= max(8, ops.smem_block_cap(P, levels=L, heads=G,
+                                           train=train))
+    # the packed pyramid is resident in fp32 over the head group's lanes
+    resident = sum(ops.slab_rows(hw) for hw in levels) * lanes * 4
+    # the documented model: per-step bytes fit what the budget leaves
+    # after the resident pyramid, floored at a 1 MiB working set
+    assert bq * per_q <= max(budget - resident, 1 * _MIB) or bq == 8
 
 
 @pytest.mark.parametrize("levels,P,heads", [(1, 4, 4), (5, 4, 4), (4, 8, 4),
@@ -182,71 +190,10 @@ def test_bf16_slab_never_narrows_blocks(args):
     mk = lambda sdt: plan_mod.MsdaSpec(
         spatial_shapes=levels, num_heads=2, head_dim=D, num_points=P,
         num_queries=Q, train=train, vmem_budget=budget, slab_dtype=sdt)
-    wide = plan_mod._heuristic_block_q(mk("float32"))
-    narrow = plan_mod._heuristic_block_q(mk("bfloat16"))
+    wide = plan_mod._heuristic_block_q(mk("float32"), ("float32",) * len(levels))
+    narrow = plan_mod._heuristic_block_q(mk("bfloat16"),
+                                         ("bfloat16",) * len(levels))
     assert all(n >= w for n, w in zip(narrow, wide))
-
-
-@given(spec_dims)
-@settings(**SET)
-def test_fusion_tier_respects_vmem_fitting_model(args):
-    """The fusion tier's 'auto' decision is exactly the documented
-    prefix model: ``ops.fusion_prefix`` walks k from L down until the
-    packed prefix residency (+ train grad super-slab) plus one minimal
-    query step's working set fits the budget — k == L fully fuses,
-    2 <= k < L commits a strict prefix, k < 2 falls back to per-level.
-    'on'/'off'/'prefix:k' pin the tier regardless."""
-    levels, P, D, Q, budget, train, slab = args
-    L = len(levels)
-    mk = lambda fuse: plan_mod.MsdaSpec(
-        spatial_shapes=levels, num_heads=2, head_dim=D, num_points=P,
-        num_queries=Q, train=train, vmem_budget=budget, slab_dtype=slab,
-        fuse_levels=fuse)
-    spec = mk("auto")
-    dts = plan_mod._default_slab_dtypes(spec)
-    fused, prefix = plan_mod._resolve_fuse_tier(spec, dts, "pallas")
-    G = spec.heads_per_launch
-    k_model = ops.fusion_prefix(
-        levels, P, D, value_itemsize=plan_mod._slab_itemsizes(dts),
-        train=train, vmem_budget=spec.vmem_budget, heads=G)
-    if L >= 2:
-        if k_model == L:
-            assert (fused, prefix) == (True, 0)  # whole pyramid
-        elif k_model >= 2:
-            assert (fused, prefix) == (True, k_model)  # strict tier
-        else:
-            assert (fused, prefix) == (False, 0)  # per-level
-        # the k == L rung is the historical whole-pyramid fitting model
-        fits = ops.fused_pyramid_fits(
-            levels, P, D, value_itemsize=spec.slab_itemsize, train=train,
-            vmem_budget=spec.vmem_budget, heads=G)
-        assert (k_model == L) == fits
-        rows = sum(ops.slab_rows(hw) for hw in levels)
-        resident = rows * ops.lane_width(G, D) * 4  # fp32 super-slab
-        per_q = ops.per_query_bytes(P, D, train=train,
-                                    slab_itemsize=spec.slab_itemsize,
-                                    levels=L, heads=G)
-        assert fits == (resident + 8 * per_q <= spec.vmem_budget)
-        # every committed prefix actually fits its own residency model
-        if 0 < k_model:
-            kth = ops.fusion_prefix(
-                levels[:k_model], P, D,
-                value_itemsize=plan_mod._slab_itemsizes(dts[:k_model]),
-                train=train, vmem_budget=spec.vmem_budget, heads=G)
-            assert kth == k_model
-    else:
-        assert (fused, prefix) == (False, 0)  # single level: nothing to fuse
-    assert plan_mod._resolve_fuse_tier(mk("on"), dts, "pallas") == (True, 0)
-    assert plan_mod._resolve_fuse_tier(mk("off"), dts, "pallas") == (False, 0)
-    if L >= 3:
-        # a strict pin commits exactly that tier; k >= L degenerates to
-        # the whole pyramid (prefix 0 == "all levels")
-        assert plan_mod._resolve_fuse_tier(
-            mk(f"prefix:{L - 1}"), dts, "pallas") == (True, L - 1)
-    assert plan_mod._resolve_fuse_tier(
-        mk(f"prefix:{L + 3}"), dts, "pallas") == (True, 0)
-    # non-fusable backends never fuse, whatever the policy says
-    assert plan_mod._resolve_fuse_tier(mk("on"), dts, "cpu") == (False, 0)
 
 
 # --------------------------------------------------------------------------
@@ -259,22 +206,19 @@ cache_entries = st.dictionaries(
         st.lists(st.integers(8, 2048), min_size=1, max_size=5),  # legacy
         st.fixed_dictionaries(
             {
-                "block_q": st.lists(st.integers(8, 2048), min_size=2, max_size=2),
+                # one launch shares one block across the levels
+                "block_q": st.integers(8, 2048).map(lambda b: [b, b]),
                 "slab_dtypes": st.lists(
                     st.sampled_from(["float32", "bfloat16"]), min_size=2, max_size=2),
             },
             # entries grew OPTIONAL fields: "sharding"/"grad_reduce"
-            # (mesh-keyed race winners), "fuse_levels" / "fuse_prefix"
-            # (fusion-tier race), "onehot_levels" (MXU-routing race) and
-            # "sparsity"/"query_order" (pruning/Morton races) — any
+            # (mesh-keyed race winners) and "sparsity"/"query_order"
+            # (pruning/Morton races) — any
             # subset must keep parsing, pre-existing entries included.
             # Keys NO build knows ("future_field"...) must ride through
             # parse -> re-persist untouched (forward compat)
             optional={
                 "sharding": st.sampled_from(["1d", "2d"]),
-                "fuse_levels": st.booleans(),
-                "fuse_prefix": st.integers(1, 4),
-                "onehot_levels": st.lists(st.booleans(), min_size=2, max_size=2),
                 "grad_reduce": st.sampled_from(["ring", "psum"]),
                 "sparsity": st.sampled_from(["dense", "topk"]),
                 "query_order": st.sampled_from(["identity", "morton"]),
@@ -315,11 +259,6 @@ def test_autotune_cache_roundtrips_through_xdg_cache_home(tmp_path_factory, entr
                 assert parsed["slab_dtypes"] == tuple(hit["slab_dtypes"])
                 assert parsed["sharding"] == hit.get("sharding")
                 assert parsed["grad_reduce"] == hit.get("grad_reduce")
-                assert parsed["fuse_levels"] == hit.get("fuse_levels")
-                assert parsed["fuse_prefix"] == hit.get("fuse_prefix")
-                oh = hit.get("onehot_levels")
-                assert parsed["onehot_levels"] == (
-                    tuple(oh) if oh is not None else None)
                 assert parsed["sparsity"] == hit.get("sparsity")
                 assert parsed["query_order"] == hit.get("query_order")
                 assert parsed["extras"] == {
@@ -329,7 +268,8 @@ def test_autotune_cache_roundtrips_through_xdg_cache_home(tmp_path_factory, entr
                 # unknown keys included
                 assert plan_mod._parse_cache_entry(
                     plan_mod._winner_entry(parsed), spec) == parsed
-            elif len(hit) == spec.num_levels:  # legacy: level count must match
+            elif len(hit) == spec.num_levels and len(set(hit)) == 1:
+                # legacy: level count must match, one block for them all
                 assert parsed["block_q"] == tuple(hit)
                 assert parsed["slab_dtypes"] == ("float32",) * 2
                 assert parsed["sharding"] is None

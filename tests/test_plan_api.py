@@ -1,4 +1,5 @@
 """Plan/execute API: spec -> plan -> execute, registry, caches, shim parity."""
+import dataclasses
 import os
 import warnings
 
@@ -209,8 +210,8 @@ def test_plan_cache_eviction_bounded():
 def test_deprecated_tuning_kwargs_warn():
     value, loc, attn = _inputs()
     ops._WARNED_KWARGS.clear()
-    with pytest.warns(DeprecationWarning, match="fuse_gather"):
-        ops.msda(value, LEVELS, loc, attn, backend="pallas", fuse_gather=False)
+    with pytest.warns(DeprecationWarning, match="interpret"):
+        ops.msda(value, LEVELS, loc, attn, backend="pallas", interpret=True)
 
 
 # --------------------------------------------------------------------------
@@ -221,7 +222,8 @@ def test_deprecated_tuning_kwargs_warn():
 def test_vmem_budget_defaults_per_device_kind():
     assert plan_mod.default_vmem_budget("TPU v3") == 16 * 2**20
     assert plan_mod.default_vmem_budget("TPU v5p") == 64 * 2**20
-    assert plan_mod.default_vmem_budget("cpu") == 32 * 2**20
+    # the interpreter keeps the v5e figure
+    assert plan_mod.default_vmem_budget("cpu") == 100 * 2**20
     spec = MsdaSpec(spatial_shapes=LEVELS, num_heads=2, head_dim=8,
                     num_points=2, num_queries=64)
     assert spec.vmem_budget == plan_mod.default_vmem_budget()
@@ -244,28 +246,27 @@ def test_vmem_budget_drives_block_plan():
 
 def test_describe_reports_per_level_decisions():
     value, loc, attn = _inputs()
-    plan = msda_plan(_spec(value, loc, onehot_small_levels=True), backend="pallas")
+    plan = msda_plan(_spec(value, loc), backend="pallas")
     report = plan.level_report()
     assert len(report) == len(LEVELS)
-    assert all(r["gather"] == "mxu-onehot" for r in report)  # tiny levels
+    assert all(r["gather"] == "vpu" for r in report)
     text = plan.describe()
     assert "backend=pallas" in text and "block_q" in text and "vmem" in text
     for r in report:
         assert r["slab_bytes"] > 0 and r["block_q"] >= 8
 
 
-@pytest.mark.parametrize("fuse", ["on", "off"])
-def test_describe_reports_each_launch_weight_block(fuse):
-    """One line per forward launch: the VMEM weight block's rows per
-    query and its double-buffered bytes."""
+@pytest.mark.parametrize("train", [False, True], ids=["on", "train"])
+def test_describe_reports_each_launch_weight_block(train):
+    """The forward launch's line: the VMEM weight block's rows per query
+    and its double-buffered bytes."""
     from repro.kernels import msda_fwd
 
     value, loc, attn = _inputs()
-    plan = msda_plan(_spec(value, loc, fuse_levels=fuse), backend="pallas")
+    plan = msda_plan(_spec(value, loc, train=train), backend="pallas")
     s = plan.spec
     lanes = ops.lane_width(s.heads_per_launch, s.head_dim)
-    launches = ([tuple(range(len(LEVELS)))] if fuse == "on"
-                else [(l,) for l in range(len(LEVELS))])
+    launches = [tuple(range(len(LEVELS)))]
     report = plan.weight_report()
     assert [w["levels"] for w in report] == launches
     text = plan.describe()
@@ -375,8 +376,7 @@ def test_autotune_races_slab_dtypes_and_persists(tmp_path, monkeypatch):
     assert all(d in ("float32", "bfloat16") for d in plan.tuning.slab_dtypes)
     entry = next(iter(json.load(open(tmp_path / "tune.json")).values()))
     assert entry == {"block_q": list(plan.block_q),
-                     "slab_dtypes": list(plan.tuning.slab_dtypes),
-                     "fuse_levels": plan.fused}
+                     "slab_dtypes": list(plan.tuning.slab_dtypes)}
     plan_mod.clear_plans()
     plan2 = msda_plan(spec, backend="pallas", tune="autotune")
     assert plan2.tuning.source == "autotune-cache"
@@ -416,17 +416,21 @@ def test_peaks_unknown_device_kind_raises():
 
 
 def test_compiled_pallas_plan_has_no_oracle_rung():
-    """Compiled kernels (a TPU) never degrade to the XLA oracle: the
-    ladder stops at per-level Pallas.  Interpreted ones keep the rung."""
+    """Compiled kernels (a TPU) never degrade to the XLA oracle: a dense
+    compiled plan is the bottom of its ladder.  Interpreted ones keep the
+    oracle rung, and a pruned compiled plan still demotes to dense."""
     spec = MsdaSpec(spatial_shapes=LEVELS, num_heads=2, head_dim=8,
-                    num_points=2, num_queries=64, fuse_levels="on")
+                    num_points=2, num_queries=64)
     compiled = msda_plan(spec, backend="pallas", interpret=False)
-    chain = compiled.fallback_chain()
-    assert [p.backend for p in chain] == ["pallas"]
-    assert not chain[0].fused and chain[0].tuning.interpret is False
+    assert compiled.fallback() is None and compiled.fallback_chain() == ()
     interpreted = msda_plan(spec, backend="pallas", interpret=True)
-    assert [p.backend for p in interpreted.fallback_chain()] == [
-        "pallas", "ref"]
+    assert [p.backend for p in interpreted.fallback_chain()] == ["ref"]
+    pruned = msda_plan(dataclasses.replace(spec, sparsity="topk"),
+                       backend="pallas", interpret=False)
+    chain = pruned.fallback_chain()
+    assert [p.backend for p in chain] == ["pallas"]
+    assert chain[0].tuning.sparsity == "dense"
+    assert chain[0].tuning.interpret is False
 
 
 def test_autotune_raises_when_no_candidate_builds(tmp_path, monkeypatch):

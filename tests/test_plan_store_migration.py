@@ -5,8 +5,11 @@ schemas wrote them (v1 flat-list winners, v2 per-level slab dtypes, v3
 fusion + one-hot routing decisions, v4 heuristic entries, v5 sparsity
 axes + a newer-build extra field).  Each must restore on the current
 build with ZERO autotune timing runs and re-save as a version-6 store
-without dropping any winner decision — the compatibility promise the
-version-history comment in ``repro/serving/persistence.py`` makes.
+without dropping any winner decision this build still makes — the
+compatibility promise the version-history comment in
+``repro/serving/persistence.py`` makes.  The keys of retired plan
+choices (``plan.RETIRED_KEYS``: the fusion tiers, the corner-major walks,
+the one-hot row route, the fixed-block ablation) are read and dropped.
 """
 import json
 import os
@@ -60,10 +63,6 @@ def test_historic_store_restores_with_zero_races(version, tmp_path):
         assert list(plan.tuning.block_q) == winner["block_q"]
         if "slab_dtypes" in winner:
             assert list(plan.tuning.slab_dtypes) == winner["slab_dtypes"]
-        if "fuse_levels" in winner:
-            assert plan.fused == winner["fuse_levels"]
-        if "onehot_levels" in winner:
-            assert list(plan.tuning.onehot_levels) == winner["onehot_levels"]
         if "sparsity" in winner:
             assert plan.tuning.sparsity == winner["sparsity"]
 
@@ -79,13 +78,11 @@ def test_historic_store_restores_with_zero_races(version, tmp_path):
         if isinstance(winner, list):
             assert re_winner["block_q"] == winner
         else:
-            for field in ("block_q", "slab_dtypes", "fuse_levels",
-                          "onehot_levels", "sparsity"):
+            for field in ("block_q", "slab_dtypes", "sparsity"):
                 if field in winner:
                     assert re_winner[field] == winner[field], field
-        # pre-v6 winners never grow a fuse_prefix: absent keeps meaning
-        # "fuse everything fuse_levels says to"
-        assert "fuse_prefix" not in re_winner
+            assert not set(plan_mod.RETIRED_KEYS) & set(re_winner)
+    assert not set(plan_mod.RETIRED_KEYS) & set(resaved["entries"][0]["spec"])
 
     # the resaved v6 store round-trips again, still race-free
     plan_mod.clear_plans()
@@ -107,3 +104,43 @@ def test_newer_build_extras_survive_the_winner_cache(tmp_path):
     plan = report.plans[0]
     cached = plan_mod.get_autotune_winner(plan.spec, plan.backend)
     assert cached is not None and cached.get("fleet_epoch") == 3
+
+
+SPEC_KEYS = {"fuse_levels": "auto", "fuse_gather": False,
+             "fuse_scatter": False, "adaptive_block": False,
+             "onehot_small_levels": True}
+WINNER_KEYS = {"fuse_levels": True, "fuse_prefix": 2,
+               "onehot_levels": [False, True]}
+
+
+@pytest.mark.parametrize("where,key", (
+    [("spec", k) for k in SPEC_KEYS] + [("winner", k) for k in WINNER_KEYS]),
+    ids=lambda v: str(v))
+def test_store_drops_each_retired_key(where, key, tmp_path):
+    """A v6 store entry carrying one retired spec field or winner key
+    restores race-free without it, and is re-saved without it."""
+    assert key in plan_mod.RETIRED_KEYS
+    spec = {"spatial_shapes": [[8, 8], [4, 4]], "num_heads": 2,
+            "head_dim": 8, "num_points": 2, "num_queries": 32,
+            "dtype": "float32", "train": True}
+    winner = {"block_q": [16, 16], "slab_dtypes": ["float32", "float32"]}
+    (spec if where == "spec" else winner)[key] = (
+        SPEC_KEYS if where == "spec" else WINNER_KEYS)[key]
+    path = tmp_path / "store.json"
+    path.write_text(json.dumps({
+        "version": 6, "jax": "0.4.35", "device_kind": "cpu",
+        "created_unix": 0.0, "meta": {},
+        "entries": [{"spec": spec, "backend": "pallas", "tune": "autotune",
+                     "source": "autotune", "winner": winner}]}))
+    report = persistence.PlanStore(str(path)).restore()
+    assert not report.skipped and len(report.plans) == 1
+    assert plan_mod.autotune_stats()["raced"] == 0
+    plan = report.plans[0]
+    assert plan.block_q == (16, 16)
+    cached = plan_mod.get_autotune_winner(plan.spec, "pallas")
+    assert key not in cached
+    out = persistence.PlanStore(str(tmp_path / "resaved.json"))
+    assert out.save_plans(report.plans) == 1
+    with open(out.path) as f:
+        entry = json.load(f)["entries"][0]
+    assert key not in entry["spec"] and key not in entry["winner"]

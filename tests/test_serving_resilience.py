@@ -342,11 +342,13 @@ def test_corrupt_plan_store_missing_path_is_noop(tmp_path):
 def test_guard_plan_demotes_down_fallback_ladder():
     from repro.kernels.plan import MsdaSpec, msda_plan
 
+    # queries on the pixel grid (Q == S), so the Morton order engages:
+    # bitwise-neutral, and its demotion stays on the same backend
     spec = MsdaSpec(spatial_shapes=((6, 4), (3, 2)), num_heads=2, head_dim=8,
-                    num_points=2, num_queries=7, dtype="float32",
-                    fuse_levels="on")
+                    num_points=2, num_queries=30, dtype="float32",
+                    query_order="morton")
     plan = msda_plan(spec, backend="pallas", tune="heuristic")
-    assert plan.fused, "primary should be the fused plan"
+    assert plan.tuning.query_order == "morton", "primary should be Morton"
     inj = FaultInjector(FaultSchedule.from_spec("exec_raise@1"),
                         raise_target="p", raise_attempts=2)
     inj.begin_tick(1)
@@ -356,14 +358,14 @@ def test_guard_plan_demotes_down_fallback_ladder():
     rng = np.random.default_rng(0)
     S = sum(h * w for h, w in spec.spatial_shapes)
     v = rng.standard_normal((1, S, 2, 8)).astype(np.float32)
-    loc = rng.uniform(size=(1, 7, 2, 2, 2, 2)).astype(np.float32)
-    a = rng.uniform(size=(1, 7, 2, 2, 2)).astype(np.float32)
+    loc = rng.uniform(size=(1, 30, 2, 2, 2, 2)).astype(np.float32)
+    a = rng.uniform(size=(1, 30, 2, 2, 2)).astype(np.float32)
     with pytest.raises(ExecutorFailure):
         g.call(v, loc, a)  # injected raise, no retries -> failure 1
-    out = g.call(v, loc, a)  # failure 2 demotes; per-level rung serves
+    out = g.call(v, loc, a)  # failure 2 demotes; the identity rung serves
     assert g.rung == 1 and g.state == "open"
-    assert g.rung_labels() == ["pallas/fused", "pallas/per-level"]
-    # the demoted rung is race-free and bitwise vs the fused primary
+    assert g.rung_labels() == ["pallas/morton", "pallas"]
+    # the demoted rung is race-free and bitwise vs the Morton primary
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(plan(v, loc, a)))
     assert plan_mod.autotune_stats()["raced"] == 0

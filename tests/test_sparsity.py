@@ -11,7 +11,7 @@ The tentpole contract (ISSUE 7):
   which is allclose);
 * the pruned executor matches the masked-renormalised oracle
   (``topk_mask_weights`` + ``msda_ref``) and reports itself truthfully
-  (``xla-topk`` gather, never ``fuse=pyramid``);
+  (``xla-topk`` gather, no Pallas launch);
 * both axes are planned, autotuned, persisted properties: winners
   survive the winner cache AND a ``PlanStore`` v5 save/restore with
   zero timing runs and identical ``describe()``.
@@ -150,9 +150,10 @@ def test_topk_plan_reports_itself_truthfully():
     plan = msda_plan(_spec("topk", k=2, train=True, vmem_budget=64 * 2**20),
                      backend="pallas")
     assert plan.tuning.sparsity == "topk"
-    assert not plan.fused  # the pruned executor launches no pallas kernels
+    # the pruned executor launches no pallas kernels
+    assert plan.launches_per_call() == {"fwd": 0, "bwd": 0}
     d = plan.describe()
-    assert "sparsity: topk k=2/6" in d and "fuse=pyramid" not in d
+    assert "sparsity: topk k=2/6" in d and "packed pyramid" not in d
     assert all(r["gather"] == "xla-topk" for r in plan.level_report())
 
 
@@ -213,7 +214,7 @@ def test_winner_cache_preserves_unknown_keys():
     must survive this build's parse -> re-persist round trip."""
     spec = _spec()
     entry = {"block_q": [16, 16], "slab_dtypes": ["float32", "float32"],
-             "fuse_levels": True, "future_field": {"nested": [1, 2]},
+             "sparsity": "dense", "future_field": {"nested": [1, 2]},
              "another_unknown": "keep-me"}
     parsed = pm._parse_cache_entry(entry, spec)
     assert parsed is not None

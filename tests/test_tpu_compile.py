@@ -3,15 +3,18 @@
 For one described (not attached) v5e chip, compiles the forward and the
 gradient of the encoder (87,296 queries) and decoder (300 queries) plans
 the DETR training step commits — full width, bf16, Pallas kernels lowered
-by Mosaic (``interpret=False``) — and the gradient of every other kernel
-variant (fusion tiers, ablations, one-hot levels, mixed slab dtypes,
-regather) at a small pyramid.  Nothing runs: this catches what the
-chip's compiler would refuse (tiling, VMEM / SMEM overflow, unsupported
-ops) and a program that does not fit the chip's HBM, without a chip.
+by Mosaic (``interpret=False``) — and those of the
+``deformable-detr-coco800`` training configuration, BEVFormer-base's
+forward plans and its cross-attention's gradient, and the gradient of
+the mixed-slab-dtype and regathering kernels at a small pyramid.
+Nothing runs: this catches what the chip's compiler would refuse
+(tiling, VMEM / SMEM overflow, unsupported ops) and a program that does
+not fit the chip's HBM, without a chip.
 """
 import dataclasses
 import json
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,7 @@ from repro.core import deformable_transformer as dt
 from repro.kernels import plan as plan_mod
 
 V5E_HBM_BYTES = 16 * 2**30
+ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
 @pytest.fixture(scope="module")
@@ -65,19 +69,17 @@ def _v5e_plan(name: str):
     return plan_mod.msda_plan(spec, backend="pallas", interpret=False)
 
 
-@pytest.mark.parametrize("direction", ["fwd", "grad"])
-@pytest.mark.parametrize("name", ["encoder", "decoder"])
-def test_detr_plan_compiles_for_v5e(name, direction, one_chip,
-                                    no_persistent_cache):
-    plan = _v5e_plan(name)
+def _compile(plan, direction, dtype, one_chip):
+    """AOT-compile ``plan``'s forward or gradient for the described chip,
+    value and attention weights in ``dtype``; it must hold the Pallas
+    kernels and fit the chip's HBM."""
     s = plan.spec
-    assert plan.backend == "pallas" and plan.tuning.interpret is False
     L, P, H, D = s.num_levels, s.num_points, s.num_heads, s.head_dim
     shape = lambda *dims, dt: jax.ShapeDtypeStruct(dims, dt,  # noqa: E731
                                                    sharding=one_chip)
-    args = (shape(1, s.total_pixels, H, D, dt=jnp.bfloat16),
+    args = (shape(1, s.total_pixels, H, D, dt=dtype),
             shape(1, s.num_queries, H, L, P, 2, dt=jnp.float32),
-            shape(1, s.num_queries, H, L, P, dt=jnp.bfloat16))
+            shape(1, s.num_queries, H, L, P, dt=dtype))
     fn = plan
     if direction == "grad":
         fn = jax.grad(lambda v, l, a: jnp.sum(plan(v, l, a).astype(
@@ -88,6 +90,41 @@ def test_detr_plan_compiles_for_v5e(name, direction, one_chip,
     total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
              + ma.output_size_in_bytes)
     assert total <= V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize("name", ["encoder", "decoder"])
+def test_detr_plan_compiles_for_v5e(name, direction, one_chip,
+                                    no_persistent_cache):
+    plan = _v5e_plan(name)
+    assert plan.backend == "pallas" and plan.tuning.interpret is False
+    _compile(plan, direction, jnp.bfloat16, one_chip)
+
+
+def _coco800_plan(name: str):
+    """The plan ``name`` of the ``deformable-detr-coco800`` training
+    cell, for the chip: the spec the model builds for float32 pyramids
+    in training, with the v5e VMEM budget."""
+    sys.path.insert(0, ROOT)
+    from chipbench import catalog
+
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "deformable-detr-coco800.json")) as f:
+        cfg = json.load(f)
+    mcfg = catalog.family(cfg).program_config(cfg)
+    spec = dt.msda_plans(mcfg, dtype="float32", train=True)[name].spec
+    spec = dataclasses.replace(
+        spec, vmem_budget=plan_mod.default_vmem_budget("TPU v5 lite"))
+    return plan_mod.msda_plan(spec, backend="pallas", interpret=False)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize("name", ["encoder", "decoder"])
+def test_coco800_train_plan_compiles_for_v5e(name, direction, one_chip,
+                                             no_persistent_cache):
+    plan = _coco800_plan(name)
+    assert plan.launches_per_call() == {"fwd": 1, "bwd": 1}
+    _compile(plan, direction, jnp.float32, one_chip)
 
 
 def _bevformer_plans():
@@ -121,18 +158,24 @@ def test_bevformer_plan_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# every kernel variant a plan can commit, at a small 3-level pyramid:
-# none is refused on a TPU, so each must compile there
+def test_bevformer_sca_grad_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The largest camera's cross-attention in training: saved corners
+    of 4 levels x 8 points, and the scatter over the packed pyramid."""
+    spec = dataclasses.replace(
+        _bevformer_plans()["sca.cam0"], train=True,
+        vmem_budget=plan_mod.default_vmem_budget("TPU v5 lite"))
+    plan = plan_mod.msda_plan(spec, backend="pallas", interpret=False)
+    assert plan.launches_per_call() == {"fwd": 1, "bwd": 1}
+    _compile(plan, "grad", jnp.float32, one_chip)
+
+
+# the kernel variants a plan can commit beside the cells' own, at a small
+# 3-level pyramid: none is refused on a TPU, so each must compile there
 SMALL_LEVELS = ((32, 32), (16, 16), (8, 8))
 VARIANTS = {
-    "fused-pyramid": dict(fuse_levels=True),
-    "fused-prefix-2": dict(fuse_levels=True, fuse_prefix=2),
-    "per-level": {},
-    "fuse-gather-off": dict(fuse_gather=False),
-    "fuse-scatter-off": dict(fuse_scatter=False),
-    "onehot-levels-1-2": dict(onehot_levels=(False, True, True)),
+    "fused-pyramid": {},
     "mixed-fp32-bf16-slabs": dict(
-        fuse_levels=True, slab_dtypes=("float32", "bfloat16", "bfloat16")),
+        slab_dtypes=("float32", "bfloat16", "bfloat16")),
     "regather": dict(save_sampled=False),
 }
 
@@ -145,7 +188,7 @@ def test_kernel_variant_compiles_for_v5e(variant, one_chip,
     L, P, H, D, Q = len(SMALL_LEVELS), 4, 8, 32, 256
     S = sum(h * w for h, w in SMALL_LEVELS)
     params = ops.MSDAParams(**{
-        **dict(spatial_shapes=SMALL_LEVELS, block_q=(64,) * L,
+        **dict(spatial_shapes=SMALL_LEVELS, block_q=64,
                interpret=False, save_sampled=True,
                vmem_limit=plan_mod.default_vmem_budget("TPU v5 lite")),
         **VARIANTS[variant]})

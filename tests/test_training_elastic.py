@@ -3,7 +3,7 @@
 The PlanStore mesh gate used to REJECT a store written on a different
 topology (restart boots cold, re-autotunes everything).  These tests
 pin the recover path: ``repro.training.elastic.recover_plans`` re-keys
-each entry's LOCAL winner (block/dtype/fuse axes stay cache hits — zero
+each entry's LOCAL winner (block/dtype axes stay cache hits — zero
 local timing runs) and re-races ONLY the mesh-keyed axes (sharding
 mode, grad_value reduction), then persists the new winners so the next
 restart on the new topology races nothing at all.

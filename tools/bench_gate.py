@@ -3,12 +3,12 @@
 
 Diffs a freshly emitted trajectory against the committed baseline:
 
-    python tools/bench_gate.py --baseline BENCH_kernels.json \
-        --fresh fresh/BENCH_kernels.json
+    python tools/bench_gate.py --baseline BENCH_sparsity.json \
+        --fresh fresh/BENCH_sparsity.json
 
     # several files at once (missing fresh files fail):
     python tools/bench_gate.py --baseline-dir . --fresh-dir fresh \
-        --files BENCH_kernels.json BENCH_sparsity.json
+        --files BENCH_sparsity.json BENCH_train.json
 
 Exit codes: 0 = no regressions, 2 = regression(s), 1 = usage/IO error.
 

@@ -57,9 +57,9 @@ def geometry(sca: bool, levels: int, block_q: int):
     shapes = shapes[:levels] if levels else shapes
     if not block_q:
         block_q = ops.plan_blocks(
-            shapes, points, HEAD_DIM, queries, train=False, fused=True,
+            shapes, points, HEAD_DIM, queries, train=False,
             vmem_budget=default_vmem_budget("TPU v5 lite"),
-            heads=msda_fwd.head_group(HEADS, HEAD_DIM))[0]
+            heads=msda_fwd.head_group(HEADS, HEAD_DIM))
     return shapes, points, queries, block_q
 
 
@@ -80,7 +80,7 @@ def compile_gather(sca: bool, levels: int, block_q: int, save: bool) -> None:
     dev = SingleDeviceSharding(topo.devices[0])
     shapes, points, queries, block_q = geometry(sca, levels, block_q)
     offs, rows = ops.pyramid_row_offsets(shapes)
-    geoms = tuple((o, w + 2, ops.slab_rows((h, w)), False)
+    geoms = tuple((o, w + 2, ops.slab_rows((h, w)))
                   for o, (h, w) in zip(offs, shapes))
     G = msda_fwd.head_group(HEADS, HEAD_DIM)
     NG, L = HEADS // G, len(shapes)
